@@ -103,12 +103,9 @@ def flops(*args, **kwargs):  # paddle.flops parity — model profiler hook
 def in_dynamic_mode() -> bool:
     """Eager-vs-traced probe (paddle.in_dynamic_mode parity). Returns False
     inside jit-traced code."""
-    import jax
+    from .jit import is_tracing
 
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - jax internal API drift  # pdlint: disable=silent-exception -- probe of a jax-internal API: outside a trace the True (eager) answer is correct, and there is nothing to log per-call on this hot predicate
-        return True
+    return not is_tracing()
 
 
 def get_flags(name=None):

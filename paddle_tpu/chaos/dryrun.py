@@ -128,7 +128,6 @@ def run_dryrun(plan: Optional[FaultPlan] = None, *, streams: int = 4,
                layers: int = 2, max_batch: int = 8, max_len: int = 128,
                page_size: int = 8, ttl: float = 1.5,
                handoff_wait_s: float = 3.0, max_retries: int = 5,
-               compile_cache: Optional[str] = None,
                stream_timeout: float = 420.0,
                load_qps: float = 0.0,
                load_duration_s: float = 4.0,
@@ -167,11 +166,9 @@ def run_dryrun(plan: Optional[FaultPlan] = None, *, streams: int = 4,
     from ..serving_cluster import launch_cluster
 
     plan = plan or default_plan()
-    cache = compile_cache or os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR", "/tmp/paddle_tpu_jax_cache")
     cfg = {
         "cluster": {"host": "127.0.0.1", "port": 0, "ttl": ttl,
-                    "platform": "cpu", "compile_cache": cache,
+                    "platform": "cpu",
                     "handoff_wait_s": handoff_wait_s,
                     "max_retries": max_retries,
                     "model_name": "tiny-llama-chaos",

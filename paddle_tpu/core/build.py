@@ -54,8 +54,13 @@ def build_native(verbose: bool = False) -> str:
     try:
         subprocess.run(cmd, check=True, capture_output=not verbose)
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
+        # the compiler's own words, not just its exit code: this runs on
+        # first use on every new machine, where they are the diagnosis
+        detail = getattr(e, "stderr", None)
+        detail = detail.decode(errors="replace").strip() if detail else ""
         raise RuntimeError(
-            f"native core build failed ({' '.join(cmd)}): {e}") from e
+            f"native core build failed ({' '.join(cmd)}): {e}"
+            + (f"\n{detail}" if detail else "")) from e
     os.replace(tmp, so)
     return so
 

@@ -40,13 +40,6 @@ class DistAttr:
         return f"DistAttr(mesh={self.mesh}, placements={self.placements})"
 
 
-def _in_trace() -> bool:
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover  # pdlint: disable=silent-exception -- probe of a jax-internal API: False (not tracing) is the safe answer, and this predicate runs per shard_tensor call
-        return False
-
-
 def shard_tensor(x, mesh: ProcessMesh, placements, dtype=None, place=None,
                  stop_gradient=None) -> Tensor:
     """Distribute ``x`` over ``mesh`` per ``placements``; returns a tensor
@@ -54,7 +47,7 @@ def shard_tensor(x, mesh: ProcessMesh, placements, dtype=None, place=None,
     t = x if isinstance(x, Tensor) else Tensor(x, dtype=dtype)
     arr = t._array
     sharding = mesh.sharding_for(placements, arr.ndim)
-    if _in_trace():
+    if isinstance(arr, jax.core.Tracer):
         arr = jax.lax.with_sharding_constraint(arr, sharding)
     else:
         arr = jax.device_put(arr, sharding)
@@ -101,7 +94,7 @@ def reshard(x: Tensor, mesh: ProcessMesh, placements) -> Tensor:
                         in_specs=(src_spec,), out_specs=src_spec)(arr)
 
     sharding = mesh.sharding_for(placements, arr.ndim)
-    if _in_trace():
+    if isinstance(arr, jax.core.Tracer):
         arr = jax.lax.with_sharding_constraint(arr, sharding)
     else:
         arr = jax.device_put(arr, sharding)
@@ -259,19 +252,38 @@ class ShardingStage3:
             for pname, p in list(sub._parameters.items()):
                 if p is None or p.ndim == 0:
                     continue
-                placements = _first_dim_shardable(p._array, self.mesh, self.axis_name)
+                placements = _first_dim_shardable(p, self.mesh, self.axis_name)
                 if placements is not None:
                     sub._parameters[pname] = shard_tensor(p, self.mesh, placements)
         return layer
 
 
-def _first_dim_shardable(arr, mesh: ProcessMesh, axis_name: str):
-    """Placements sharding the first divisible dim on ``axis_name``, else None."""
+def _first_dim_shardable(p, mesh: ProcessMesh, axis_name: str):
+    """Placements adding a shard of ``axis_name`` to ``p``, else None.
+    Placements the parameter already carries on this mesh are KEPT — a
+    tensor-parallel weight stays sharded over ``mp`` when ZeRO-3 shards
+    it over ``sharding`` as well (starting from all-Replicate silently
+    un-sharded it). The new axis goes on a dim another axis already
+    holds when that still divides (one dim split ``mp x sharding`` ways —
+    the layout the TPU compiler accepts at Llama-3-8B widths; splitting
+    the two dims of one matrix over the two axes trips an internal
+    scheduler check there), else on the first free divisible dim."""
     axis_size = mesh.get_dim_size(axis_name)
     mesh_dim = mesh.dim_names.index(axis_name)
-    for d, s in enumerate(arr.shape):
-        if s % axis_size == 0:
-            placements: List[Placement] = [Replicate()] * mesh.ndim
+    attr = getattr(p, "_dist_attr", None)
+    placements: List[Placement] = (
+        list(attr.placements) if attr is not None and attr.mesh == mesh
+        else [Replicate()] * mesh.ndim)
+    held = {}                      # tensor dim -> ways other axes split it
+    for i, pl in enumerate(placements):
+        if isinstance(pl, Shard) and i != mesh_dim:
+            held[pl.dim] = held.get(pl.dim, 1) * mesh.shape[i]
+    for d, ways in held.items():
+        if p.shape[d] % (ways * axis_size) == 0:
+            placements[mesh_dim] = Shard(d)
+            return placements
+    for d, s in enumerate(p.shape):
+        if d not in held and s % axis_size == 0:
             placements[mesh_dim] = Shard(d)
             return placements
     return None

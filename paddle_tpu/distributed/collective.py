@@ -30,18 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:
-    from jax import shard_map
-except ImportError:
-    # older jax ships shard_map under experimental, with the vma checker
-    # spelled check_rep — ONE compat shim here; every in-repo site imports
-    # shard_map from this module instead of guessing the jax version
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _exp_shard_map(*args, **kwargs)
+from jax import shard_map  # re-exported: in-repo sites import it from here
 
 from ..observability import flightrecorder as _frec
 from ..tensor_class import Tensor, unwrap, wrap

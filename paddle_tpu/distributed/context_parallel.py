@@ -242,7 +242,7 @@ def _ring_splash_vjp_bwd(axis_name, causal, scale, window, interpret,
     # (save_residuals=True raises under AD), so the backward recomputes
     # through the einsum ring — mathematically the same function, O(S_local)
     # memory, fully collective-transposable. Fwd rides the MXU kernel;
-    # bwd costs einsum-path FLOPs (documented in BASELINE.md).
+    # bwd costs einsum-path FLOPs.
     q, k, v = res
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _ring_einsum(q_, k_, v_, axis_name, causal,

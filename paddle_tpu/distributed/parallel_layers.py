@@ -38,14 +38,22 @@ def _mp_mesh():
     return hcg.mesh
 
 
-def _constraint(arr, mesh: ProcessMesh, spec: PartitionSpec):
-    """Sharding constraint that is a no-op outside traces."""
-    try:
-        if not jax.core.trace_state_clean():
-            return jax.lax.with_sharding_constraint(arr, NamedSharding(mesh.jax_mesh(), spec))
-    except Exception:  # pragma: no cover  # pdlint: disable=silent-exception -- trace-state probe: outside a trace the constraint is a deliberate no-op, and this sits on the per-layer forward path
-        pass
-    return arr
+def _constraint(arr, mesh: ProcessMesh, **dims):
+    """Constrain a traced activation on the dims a tensor-parallel layer
+    owns — ``last=`` the feature dim, ``seq=`` dim 1 — to a mesh axis
+    name or ``None`` (gathered). Every other dim stays UNCONSTRAINED:
+    the batch dim belongs to the dp/sharding axes, and a ``None`` there
+    would all-gather it on every linear. Eager arrays pass through
+    (their layout is whatever the eager ops produced)."""
+    if not isinstance(arr, jax.core.Tracer):
+        return arr
+    spec = [PartitionSpec.UNCONSTRAINED] * arr.ndim
+    if "last" in dims:
+        spec[-1] = dims["last"]
+    if "seq" in dims:
+        spec[1] = dims["seq"]
+    return jax.lax.with_sharding_constraint(
+        arr, NamedSharding(mesh.jax_mesh(), PartitionSpec(*spec)))
 
 
 class VocabParallelEmbedding(Layer):
@@ -101,10 +109,9 @@ class ColumnParallelLinear(Layer):
             if b:
                 out = out + b[0]
             if mesh is not None:
-                spec = PartitionSpec(*([None] * (out.ndim - 1)), "mp")
-                out = _constraint(out, mesh, spec)
+                out = _constraint(out, mesh, last="mp")
                 if self.gather_output:
-                    out = _constraint(out, mesh, PartitionSpec(*([None] * out.ndim)))
+                    out = _constraint(out, mesh, last=None)
             return out
 
         args = [x, self.weight] + ([self.bias] if self.bias is not None else [])
@@ -135,11 +142,10 @@ class RowParallelLinear(Layer):
 
         def fn(a, w, *b):
             if mesh is not None:
-                in_spec = PartitionSpec(*([None] * (a.ndim - 1)), "mp")
-                a = _constraint(a, mesh, in_spec)
+                a = _constraint(a, mesh, last="mp")
             out = a @ w
             if mesh is not None:
-                out = _constraint(out, mesh, PartitionSpec(*([None] * out.ndim)))
+                out = _constraint(out, mesh, last=None)
             if b:
                 out = out + b[0]
             return out
@@ -160,14 +166,13 @@ class ColumnSequenceParallelLinear(ColumnParallelLinear):
         def fn(a, w, *b):
             if mesh is not None:
                 # sequence-sharded input → gather to full sequence
-                seq_spec = PartitionSpec(None, "mp", *([None] * (a.ndim - 2)))
-                a = _constraint(a, mesh, seq_spec)
-                a = _constraint(a, mesh, PartitionSpec(*([None] * a.ndim)))
+                a = _constraint(a, mesh, seq="mp", last=None)
+                a = _constraint(a, mesh, seq=None, last=None)
             out = a @ w
             if b:
                 out = out + b[0]
             if mesh is not None:
-                out = _constraint(out, mesh, PartitionSpec(*([None] * (out.ndim - 1)), "mp"))
+                out = _constraint(out, mesh, last="mp")
             return out
 
         args = [x, self.weight] + ([self.bias] if self.bias is not None else [])
@@ -183,11 +188,11 @@ class RowSequenceParallelLinear(RowParallelLinear):
 
         def fn(a, w, *b):
             if mesh is not None:
-                a = _constraint(a, mesh, PartitionSpec(*([None] * (a.ndim - 1)), "mp"))
+                a = _constraint(a, mesh, last="mp")
             out = a @ w
             if mesh is not None:
                 # reduce_scatter onto the sequence dim
-                out = _constraint(out, mesh, PartitionSpec(None, "mp", *([None] * (out.ndim - 2))))
+                out = _constraint(out, mesh, seq="mp", last=None)
             if b:
                 out = out + b[0]
             return out
@@ -213,7 +218,7 @@ class ParallelCrossEntropy(Layer):
         mesh = self._mesh
         if mesh is not None:
             def fn(a):
-                return _constraint(a, mesh, PartitionSpec(*([None] * (a.ndim - 1)), "mp"))
+                return _constraint(a, mesh, last="mp")
 
             input = apply("vocab_shard_constraint", fn, input)
         return cross_entropy(input, label, reduction="none", ignore_index=self.ignore_index)

@@ -28,6 +28,7 @@ import numpy as np
 
 from .tensor_class import Tensor, unwrap, wrap
 from .ops.registry import apply
+from .ops.pallas import backend as _pallas_backend
 from .autograd import tape as _tape
 from .framework import random as _random
 from .nn.layer import functional_weights as _functional_weights
@@ -262,6 +263,8 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
         if window < cache_positions:
             # gather ONLY the pages the band can touch: O(window) work
             # regardless of max_len — the win windowed serving exists for
+            _pallas_backend.took("paged_attention", _pallas_backend.XLA,
+                                 "sliding window below the cache length")
             return _paged_window_attention(q, k_pages, v_pages, lengths,
                                            page_indices, window,
                                            softcap=softcap)
@@ -272,22 +275,29 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
         # the bundled Pallas kernel computes uncapped scores; the exact
         # gather reference (O(cache) reads) keeps softcapped models
         # (Gemma2) servable through the paged engine
+        _pallas_backend.took("paged_attention", _pallas_backend.XLA,
+                             "attention softcap")
         return _paged_attention_ref(q, k_pages, v_pages, lengths,
                                     page_indices, softcap=softcap)
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: jax.devices() raising (no backend initialised) means 'not on TPU'; the reference path below is the designed fallback
-        on_tpu = False
-    if on_tpu:
+    # the bundled kernel has no interpret-mode entry: TPU only
+    if _pallas_backend.gate("paged_attention", interpret=False):
+        # the package re-exports the FUNCTION under the kernel module's
+        # old name, so ``import paged_attention as pa; pa.paged_attention``
+        # is an AttributeError on this jax
         from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention as pa)
+            paged_attention)
 
         if pages_per_compute_block is None:
             pages_per_seq = page_indices.shape[1]
             pages_per_compute_block = next(
                 b for b in (8, 4, 2, 1) if pages_per_seq % b == 0)
-        return pa.paged_attention(
-            q, k_pages, v_pages, lengths, page_indices,
+        # the bundled kernel applies NO softmax scale (the maxtext
+        # convention, like splash): q goes in pre-scaled. Unscaled, the
+        # chip's decode disagreed with every reference from the second
+        # token on — a fault only a chip run could show.
+        scale = jnp.asarray(1.0 / math.sqrt(q.shape[-1]), q.dtype)
+        return paged_attention(
+            q * scale, k_pages, v_pages, lengths, page_indices,
             pages_per_compute_block=pages_per_compute_block)
     return _paged_attention_ref(q, k_pages, v_pages, lengths, page_indices)
 

@@ -382,10 +382,8 @@ def enable_to_static(flag: bool):
 
 
 def is_tracing() -> bool:
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover  # pdlint: disable=silent-exception -- probes a private jax API that moved across versions; absent means "not tracing", and logging per call would spam every eager op
-        return False
+    """True inside any jax trace (jit, grad, vmap, scan body)."""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 # ---- flight-recorder compile events -----------------------------------------

@@ -495,12 +495,11 @@ class DeepseekV2Model(LlamaModel):
         decode kernel consumes it zero-copy every step; writers write the
         true width at offset 0 and einsum readers slice it back."""
         cfg = self.config
+        from ..ops.pallas import backend
+
         dr = cfg.qk_rope_head_dim
-        try:
-            if jax.default_backend() == "tpu":
-                dr = -(-dr // 128) * 128
-        except RuntimeError:  # pragma: no cover - backend init failed: the un-padded width is correct on every non-TPU path
-            pass
+        if backend.on_tpu():
+            dr = -(-dr // 128) * 128
         return {"c_kv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype),
                 "k_pe": jnp.zeros((batch, max_len, dr), dtype)}
 

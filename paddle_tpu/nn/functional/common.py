@@ -186,14 +186,15 @@ def batch_norm(
         args = [t for t in (weight, bias) if t is not None]
         out = apply("batch_norm", fn, x, *args)
 
-        # Running-stat update: eager only (under a jit trace this would leak
-        # tracers into the buffers; compiled training uses functional state
-        # or use_global_stats, as in other XLA frameworks).
+        # Running-stat update. A TRACED batch may only update a buffer that
+        # is itself traced — functional state (pipeline stages, TrainStep)
+        # swaps tracers in and threads the new values out. Writing a tracer
+        # into a CONCRETE buffer (a plain jax.jit closing over the layer)
+        # would leak it into every later eager call, so that case skips.
         if running_mean is not None:
-            from ...jit import is_tracing
-
-            if not is_tracing():
-                arr = unwrap(x)
+            arr = unwrap(x)
+            if (not isinstance(arr, jax.core.Tracer)
+                    or isinstance(running_mean._array, jax.core.Tracer)):
                 axes = tuple(i for i in range(arr.ndim) if i != ch_axis % arr.ndim)
                 batch_mean = jnp.mean(arr.astype(jnp.float32), axis=axes)
                 batch_var = jnp.var(arr.astype(jnp.float32), axis=axes)

@@ -33,40 +33,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: jax.devices() raising (no backend initialised) means 'not on TPU', and logging here would fire on every CPU-test kernel call
-        return False
+from . import backend
 
 
 _VMEM_BUDGET = 10 * 1024 * 1024  # bytes for k+v residency per grid cell
 
 
-def supported(q, k_buf, interpret: bool = False) -> bool:
-    """Gate: TPU (or interpret-mode test), MXU-tileable dims, whole-buffer
-    KV fits the VMEM budget, and GQA groups divide evenly."""
-    if not interpret and not _on_tpu():
-        return False
+def _shape_refusal(q, k_buf):
     if q.ndim != 4 or k_buf.ndim != 4:
-        return False
+        return "q and the KV buffer must be 4-D"
     B, S, H, D = q.shape
     T, hk = k_buf.shape[1], k_buf.shape[2]
     if D % 128 != 0 or T % 128 != 0:
-        return False
+        return f"head_dim {D} / buffer length {T} is not a multiple of 128"
     if H % hk != 0:
-        return False
+        return f"{H} query heads do not group over {hk} KV heads"
     g = H // hk
     if (g * S) % 8 != 0:  # f32 sublane tile for the scores block
-        return False
+        return f"{g} x {S} score rows are not a sublane multiple"
     kv_bytes = 2 * T * D * jnp.dtype(k_buf.dtype).itemsize
     if kv_bytes > _VMEM_BUDGET:
-        return False
+        return f"one head's KV ({kv_bytes} B) exceeds the VMEM budget"
     # streaming block: [g*S, bkv] f32 scores must stay modest
     if g * S > 2048:
-        return False
-    return True
+        return f"{g} x {S} score rows exceed 2048"
+    return None
+
+
+def supported(q, k_buf, interpret: bool = False) -> bool:
+    """Gate: TPU (or interpret-mode test), MXU-tileable dims, whole-buffer
+    KV fits the VMEM budget, and GQA groups divide evenly."""
+    return backend.gate("append_attention", _shape_refusal(q, k_buf),
+                        interpret)
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, allowed_ref, o_ref, *,
@@ -84,14 +82,14 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, allowed_ref, o_ref, *,
 
         def compute(carry):
             m, l, acc = carry
-            kblk = k_ref[0, pl.ds(i * bkv, bkv), 0, :].astype(jnp.float32)
-            vblk = v_ref[0, pl.ds(i * bkv, bkv), 0, :].astype(jnp.float32)
+            kblk = k_ref[0, pl.ds(i * bkv, bkv), :].astype(jnp.float32)
+            vblk = v_ref[0, pl.ds(i * bkv, bkv), :].astype(jnp.float32)
             s_blk = qf @ kblk.T                # [gS, bkv]
             col = (i * bkv
                    + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1))
             mask = col <= limit
             if have_allowed:
-                ab = allowed_ref[0, pl.ds(i * bkv, bkv)].reshape(1, bkv)
+                ab = allowed_ref[0, :, pl.ds(i * bkv, bkv)]   # [1, bkv]
                 mask = mask & (ab != 0)
             s_blk = jnp.where(mask, s_blk, -1e30)
             m_new = jnp.maximum(m, s_blk.max(axis=1, keepdims=True))
@@ -122,12 +120,17 @@ def _append_jit(q, k_buf, v_buf, pos, allowed, interpret):
     bkv = next(b for b in (512, 256, 128) if T % b == 0)
     scale = 1.0 / math.sqrt(D)
     have_allowed = allowed is not None
-    if not have_allowed:
-        allowed = jnp.ones((B, T), jnp.int8)
-    else:
-        allowed = allowed.astype(jnp.int8)
+    # [B, 1, T]: a (1, T) block over [B, T] would put a block of 1 on a
+    # second-to-last dim of B, which Mosaic refuses
+    allowed = (jnp.ones((B, 1, T), jnp.int32) if not have_allowed
+               else allowed.astype(jnp.int32).reshape(B, 1, T))
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
     q5 = q.reshape(B, S, hk, g, D)
+    # heads folded into the lane dim: one KV head is then a (T, D) block
+    # whose last two dims tile, where (1, T, 1, D) over [B, T, hk, D]
+    # put a block of 1 on the hk dim
+    k3 = k_buf.reshape(B, T, hk * D)
+    v3 = v_buf.reshape(B, T, hk * D)
 
     kern = functools.partial(
         _kernel, S=S, g=g, D=D, T=T, bkv=bkv, scale=scale,
@@ -140,16 +143,16 @@ def _append_jit(q, k_buf, v_buf, pos, allowed, interpret):
             in_specs=[
                 pl.BlockSpec((1, S, 1, g, D),
                              lambda b, k, pos: (b, 0, k, 0, 0)),
-                pl.BlockSpec((1, T, 1, D), lambda b, k, pos: (b, 0, k, 0)),
-                pl.BlockSpec((1, T, 1, D), lambda b, k, pos: (b, 0, k, 0)),
-                pl.BlockSpec((1, T), lambda b, k, pos: (b, 0)),
+                pl.BlockSpec((1, T, D), lambda b, k, pos: (b, 0, k)),
+                pl.BlockSpec((1, T, D), lambda b, k, pos: (b, 0, k)),
+                pl.BlockSpec((1, 1, T), lambda b, k, pos: (b, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, S, 1, g, D),
                                    lambda b, k, pos: (b, 0, k, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((B, S, hk, g, D), q.dtype),
         interpret=interpret,
-    )(pos_arr, q5, k_buf, v_buf, allowed)
+    )(pos_arr, q5, k3, v3, allowed)
     return out.reshape(B, S, H, D)
 
 
@@ -157,5 +160,6 @@ def append_attention(q, k_buf, v_buf, pos, allowed=None, interpret=False):
     """q [B,S,H,D] (already RoPE'd), k_buf/v_buf [B,T,hk,D] (chunk already
     written at ``pos``), pos scalar, allowed optional [B,T] column mask.
     Returns [B,S,H,D] — same math as generation.cached_attention's dense
-    branch."""
-    return _append_jit(q, k_buf, v_buf, pos, allowed, interpret)
+    branch. ``interpret`` is honoured only off-TPU."""
+    return _append_jit(q, k_buf, v_buf, pos, allowed,
+                       interpret and backend.interpret_mode())

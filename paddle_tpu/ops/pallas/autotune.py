@@ -79,9 +79,12 @@ def _logger():
 # roofline device model
 # ---------------------------------------------------------------------------
 
-#: device-kind substring → (HBM bytes/s, peak bf16 FLOP/s). Matched
-#: against jax's ``device_kind`` lowercased; first hit wins, unknown
-#: kinds fall back to the v5e numbers (ranking only needs consistency).
+#: device-kind substring → (HBM bytes/s, peak bf16 FLOP/s): the repo's ONE
+#: table of chip peaks (bench.py's MFU reads it too). Matched against
+#: jax's ``device_kind`` lowercased; first hit wins. Sources: Google Cloud
+#: TPU documentation, "TPU v6e" / "TPU v5p" / "TPU v5e" / "TPU v4" system
+#: architecture pages. The ``cpu`` row is a nominal host figure that only
+#: ranks candidates in CPU tests — never a device metric.
 _ROOFLINE_CAPS: List[Tuple[str, Tuple[float, float]]] = [
     ("v6e", (1.64e12, 918e12)),
     ("v5p", (2.765e12, 459e12)),
@@ -89,15 +92,19 @@ _ROOFLINE_CAPS: List[Tuple[str, Tuple[float, float]]] = [
     ("v4", (1.228e12, 275e12)),
     ("cpu", (5e10, 1e11)),
 ]
-_DEFAULT_CAPS = (8.19e11, 197e12)
 
 
 def roofline_caps(device: Optional[str] = None) -> Tuple[float, float]:
+    """(HBM bytes/s, peak bf16 FLOP/s) of ``device`` (default: the first
+    jax device). A kind the table does not list is an error, not a
+    default: a utilization against somebody else's peak is not a number."""
     kind = (device or device_kind()).lower()
     for sub, caps in _ROOFLINE_CAPS:
         if sub in kind:
             return caps
-    return _DEFAULT_CAPS
+    raise ValueError(
+        f"no peak bandwidth/FLOP rate on file for device kind {kind!r}; "
+        "add it, with its source, to autotune._ROOFLINE_CAPS")
 
 
 def roofline_ms(bytes_hbm: float, flops: float,
@@ -312,10 +319,7 @@ def device_kind() -> str:
     """Hardware identity baked into every cache key: block winners are a
     property of the chip generation (v5e vs v6e tile timings differ), and
     the cache file travels with the repo."""
-    try:
-        return jax.devices()[0].device_kind.replace(" ", "_")
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: no initialised backend means the generic 'unknown' cache bucket, which is the designed fallback
-        return "unknown"
+    return jax.devices()[0].device_kind.replace(" ", "_")
 
 
 def full_key(key: str) -> str:

@@ -43,26 +43,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-only import guard: keeps CPU test env importable
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from . import backend
 
 #: VMEM ceiling for the per-cell working set at the smallest block —
 #: beyond this the discrete path is the right call anyway
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 _MIN_BLOCK_K = 128
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: jax.devices() raising (no backend initialised) means 'not on TPU', and logging here would fire on every CPU-test kernel call
-        return False
 
 
 def enabled() -> bool:
@@ -157,7 +146,7 @@ def _block_k(kernel: str, contraction: int, params: dict, runner,
     cands = [(b,) for b in (1024, 512, 256, 128) if contraction % b == 0]
     default = next((b for (b,) in cands if b <= 512), (cands[-1][0]
                                                        if cands else 128))
-    can = _on_tpu() and autotune.is_concrete(*arrays)
+    can = backend.on_tpu() and autotune.is_concrete(*arrays)
     sig = " ".join(f"{k}{v}" for k, v in sorted(params.items()))
     (bk,) = autotune.search(
         kernel, sig, (default,), cands, runner, can, params=params,
@@ -179,10 +168,12 @@ def _rope_rotate(flat, cs, n_heads, d):
     cos = cs[:, :d]
     sin = cs[:, d:]
     if n_heads > 1:
-        cos = jnp.broadcast_to(cs[:, None, :d], (b, n_heads, d)).reshape(
-            b * n_heads, d)
-        sin = jnp.broadcast_to(cs[:, None, d:], (b, n_heads, d)).reshape(
-            b * n_heads, d)
+        # reshape-then-broadcast: ``cs[:, None, :d]`` lowers to a gather,
+        # which Mosaic refuses
+        cos = jnp.broadcast_to(cos.reshape(b, 1, d),
+                               (b, n_heads, d)).reshape(b * n_heads, d)
+        sin = jnp.broadcast_to(sin.reshape(b, 1, d),
+                               (b, n_heads, d)).reshape(b * n_heads, d)
     x1, x2 = x[:, : d // 2], x[:, d // 2:]
     rot = jnp.concatenate([-x2, x1], axis=-1)
     out = (x.astype(jnp.float32) * cos + rot.astype(jnp.float32) * sin
@@ -225,8 +216,7 @@ def _qkv_kernel(x_ref, wn_ref, wq_ref, wk_ref, wv_ref, cs_ref,
 
 
 def fused_qkv_rope(x, w_norm, wq, wk, wv, cos_row, sin_row, eps,
-                   n_heads: int, n_kv: int, d: int,
-                   interpret: bool = False):
+                   n_heads: int, n_kv: int, d: int):
     """x [B, hidden] → (q [B, H*D], k [B, hk*D], v [B, hk*D]), q/k
     roped at each row's position (``cos_row``/``sin_row`` [B, D] f32
     gathered by the caller — scalar pos broadcasts, per-row positions
@@ -237,11 +227,13 @@ def fused_qkv_rope(x, w_norm, wq, wk, wv, cos_row, sin_row, eps,
     params = {"batch": b, "hidden": hidden,
               "wtot": (n_heads + 2 * n_kv) * d, "dtype": str(x.dtype)}
 
+    interpret = backend.interpret_mode()
+
     def runner(cfg):
         return lambda: _qkv_call(x, w_norm, wq, wk, wv, cs, eps, n_heads,
                                  n_kv, d, interpret, cfg[0])
 
-    bk = (128 if interpret and not _on_tpu()
+    bk = (128 if interpret
           else _block_k("fused_qkv_rope", hidden, params, runner,
                         x, wq, cos_row))
     return _qkv_call(x, w_norm, wq, wk, wv, cs, eps, n_heads, n_kv, d,
@@ -283,7 +275,7 @@ def _qkv_call(x, w_norm, wq, wk, wv, cs, eps, n_heads, n_kv, d,
             pltpu.VMEM((b, wid_kv), jnp.float32),
             pltpu.VMEM((b, wid_kv), jnp.float32),
         ],
-        interpret=interpret or not _on_tpu(),
+        interpret=interpret,
     )(x, w_norm.reshape(1, hidden), wq, wk, wv, cs)
 
 
@@ -316,8 +308,7 @@ def _epilogue_kernel(a_ref, wo_ref, r_ref, wn_ref, on_ref, os_ref, acc, *,
         on_ref[:] = (h * rms).astype(on_ref.dtype) * wn_ref[:]
 
 
-def fused_epilogue(attn, wo, residual, w_norm, eps,
-                   interpret: bool = False):
+def fused_epilogue(attn, wo, residual, w_norm, eps):
     """attn [B, H*D] (pre-o_proj attention output), wo [H*D, hidden],
     residual [B, hidden] → (normed [B, hidden], new_residual
     [B, hidden]) — ``add_rms_norm(o_proj(attn), residual, w)`` in one
@@ -327,11 +318,13 @@ def fused_epilogue(attn, wo, residual, w_norm, eps,
     params = {"batch": b, "width": width, "hidden": hidden,
               "dtype": str(attn.dtype)}
 
+    interpret = backend.interpret_mode()
+
     def runner(cfg):
         return lambda: _epilogue_call(attn, wo, residual, w_norm, eps,
                                       interpret, cfg[0])
 
-    bk = (128 if interpret and not _on_tpu()
+    bk = (128 if interpret
           else _block_k("fused_epilogue", width, params, runner,
                         attn, wo, residual))
     return _epilogue_call(attn, wo, residual, w_norm, eps, interpret, bk)
@@ -362,7 +355,7 @@ def _epilogue_call(attn, wo, residual, w_norm, eps, interpret, bk):
             jax.ShapeDtypeStruct((b, hidden), attn.dtype),
         ),
         scratch_shapes=[pltpu.VMEM((b, hidden), jnp.float32)],
-        interpret=interpret or not _on_tpu(),
+        interpret=interpret,
     )(attn, wo, residual.astype(attn.dtype), w_norm.reshape(1, hidden))
 
 
@@ -377,8 +370,6 @@ def supported(b: int, hidden: int, n_heads: int, n_kv: int, d: int,
     (no qk-norm, no q pre-multiplier, no projection bias). Off-TPU the
     kernels run interpret mode like every Pallas op here — the flag
     (default off) is the opt-in, the gate is about shapes."""
-    if not _HAS_PLTPU:
-        return False
     if d % 128 != 0 or hidden % _MIN_BLOCK_K != 0:
         return False
     if rope_width != d:

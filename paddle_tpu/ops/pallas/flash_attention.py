@@ -22,31 +22,31 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import backend
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: jax.devices() raising (no backend initialised) means 'not on TPU', and logging here would fire on every CPU-test kernel call
-        return False
+
+def _shape_refusal(q, k, dropout):
+    if dropout != 0.0:
+        return "attention dropout"
+    if q.ndim != 4:
+        return "q must be [B, S, H, D]"
+    b, s_q, h, d = q.shape
+    s_k, h_kv = k.shape[1], k.shape[2]
+    if d % 128 != 0:
+        return f"head_dim {d} is not a lane multiple"
+    if s_q % 128 != 0 or s_k % 128 != 0:
+        return f"sequence {s_q}/{s_k} is not a multiple of 128"
+    if h % h_kv != 0:  # GQA groups must divide evenly
+        return f"{h} query heads do not group over {h_kv} KV heads"
+    return None
 
 
 def supported(q, k, v, dropout: float = 0.0, interpret: bool = False) -> bool:
     """Gate for the Pallas path: TPU backend (or explicit interpret mode for
     CPU parity tests), no dropout (fall back instead), 4D BSHD, MXU-tileable
     head_dim/seq, and a whole number of Q heads per KV head."""
-    if dropout != 0.0 or q.ndim != 4:
-        return False
-    if not interpret and not _on_tpu():
-        return False
-    b, s_q, h, d = q.shape
-    s_k, h_kv = k.shape[1], k.shape[2]
-    if d % 128 != 0:
-        return False
-    if s_q % 128 != 0 or s_k % 128 != 0:
-        return False
-    if h % h_kv != 0:  # GQA groups must divide evenly
-        return False
-    return True
+    return backend.gate("flash_attention", _shape_refusal(q, k, dropout),
+                        interpret)
 
 
 def _block_override(env: str, seq: int):
@@ -184,7 +184,8 @@ def splash_hop(q, k, v, kind: str, offset: int = 0,
     bkv = (_block_override("PD_SPLASH_BLOCK_KV", s_kv)
            or _largest_dividing_block(s_kv))
     kernel = _splash_hop_kernel(h, s_q, s_kv, kind, offset, window,
-                                interpret, bq, bkv)
+                                interpret and backend.interpret_mode(),
+                                bq, bkv)
     out, (lse,) = jax.vmap(kernel)(q, k, v)
     return out, lse
 
@@ -219,6 +220,7 @@ def flash_attention_bshd(q, k, v, causal: bool = False,
     s_q, s_kv = q.shape[1], k.shape[1]
     if window is not None and (window <= 0 or not causal):
         raise ValueError("window requires causal=True and window > 0")
+    interpret = interpret and backend.interpret_mode()
     bq_env = _block_override("PD_SPLASH_BLOCK_Q", s_q)
     bkv_env = _block_override("PD_SPLASH_BLOCK_KV", s_kv)
     bq = bq_env or _largest_dividing_block(s_q)
@@ -233,8 +235,7 @@ def flash_attention_bshd(q, k, v, causal: bool = False,
                f"causal={causal} win={window}")
         cands = [(a, b) for a in (512, 384, 256, 128) if s_q % a == 0
                  for b in (512, 384, 256, 128) if s_kv % b == 0]
-        can = (not interpret and _on_tpu()
-               and autotune.is_concrete(q, k, v))
+        can = backend.on_tpu() and autotune.is_concrete(q, k, v)
 
         def runner(cfg):
             # rank candidates by fwd+bwd: the winning (bq, bkv) also fixes

@@ -3,8 +3,9 @@
 Reference parity: paddle/phi/kernels/fusion/gpu/rms_norm* and
 fused_rope (paddle/phi/infermeta/spmd_rules/fused_rope.cc for the dist rule).
 These are HBM-bandwidth-bound elementwise+reduce ops — one VMEM round trip
-instead of several. Custom VJPs keep them differentiable; on non-TPU backends
-they run in interpret mode (tests) or fall back to the XLA composite.
+instead of several. Custom VJPs keep them differentiable; off-TPU they run
+in interpret mode (tests), and shapes that do not tile take the XLA
+composite (recorded by ``backend.gate``).
 """
 from __future__ import annotations
 
@@ -14,19 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-only import guard: keeps CPU test env importable
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: jax.devices() raising (no backend initialised) means 'not on TPU', and logging here would fire on every CPU-test kernel call
-        return False
+from . import backend
 
 
 def _pick_block_rows(rows: int, d: int) -> int:
@@ -75,7 +64,7 @@ def _tuned_block_rows(kernel: str, rows: int, d: int, dtype, runner,
     from . import autotune
 
     default = _pick_block_rows(rows, d)
-    can_measure = _on_tpu() and autotune.is_concrete(*arrays)
+    can_measure = backend.on_tpu() and autotune.is_concrete(*arrays)
     params = {"rows": rows, "d": d, "dtype": str(jnp.dtype(dtype))}
     (block,) = autotune.search(
         kernel, f"rows{rows} d{d} {jnp.dtype(dtype)}", (default,),
@@ -83,6 +72,13 @@ def _tuned_block_rows(kernel: str, rows: int, d: int, dtype, runner,
         cost_model=lambda cfg: autotune.analytical_cost(kernel, params,
                                                         cfg))
     return block
+
+
+def _row_gate(site: str, rows: int, d: int) -> bool:
+    """Row-blocked kernels need a lane-multiple width and a sublane
+    multiple of rows; off-TPU they run interpreted (tests)."""
+    return backend.gate(site, None if d % 128 == 0 and rows % 8 == 0
+                        else f"[{rows}, {d}] is not (8, 128)-tileable")
 
 
 # ---------------- fused RMSNorm ----------------------------------------------
@@ -105,7 +101,7 @@ def _rmsnorm_pallas(x2d, w, eps, block_rows):
             pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        interpret=not _on_tpu(),
+        interpret=backend.interpret_mode(),
     )(x2d, w.reshape(1, d))
 
 
@@ -122,7 +118,7 @@ def rms_norm(x, weight, eps=1e-6):
     rows = 1
     for s in x.shape[:-1]:
         rows *= s
-    if d % 128 == 0 and rows % 8 == 0 and _HAS_PLTPU:
+    if _row_gate("rms_norm", rows, d):
         # runner jits each candidate so the sweep times the KERNEL, not
         # eager pallas_call dispatch/retrace overhead
         jit_norm = jax.jit(_rmsnorm_pallas, static_argnums=(2, 3))
@@ -172,7 +168,7 @@ def add_rms_norm(x, residual, weight, eps=1e-6):
     rows = 1
     for s in x.shape[:-1]:
         rows *= s
-    if d % 128 == 0 and rows % 8 == 0 and _HAS_PLTPU:
+    if _row_gate("add_rms_norm", rows, d):
         jit_norm = jax.jit(_add_rms_pallas, static_argnums=(3, 4))
         block = _tuned_block_rows(
             "add_rms_norm", rows, d, x.dtype,
@@ -206,7 +202,7 @@ def _add_rms_pallas(x2d, r2d, w, eps, block):
             pl.BlockSpec((block, d), lambda i: (i, 0)),
             pl.BlockSpec((block, d), lambda i: (i, 0)),
         ),
-        interpret=not _on_tpu(),
+        interpret=backend.interpret_mode(),
     )(x2d, r2d, w.reshape(1, d))
 
 
@@ -280,7 +276,8 @@ def apply_rope(x, cos, sin):
 def fused_rope(x, cos, sin):
     """Fused rotary embedding: x [B,S,H,D], cos/sin [S,D]."""
     b, s, h, d = x.shape
-    if d % 128 != 0 or not _HAS_PLTPU:
+    if not backend.gate("fused_rope", None if d % 128 == 0 else
+                        f"head_dim {d} is not a lane multiple"):
         return rope_ref(x, cos, sin)
     cs = jnp.concatenate([cos.astype(jnp.float32), sin.astype(jnp.float32)], axis=-1)  # [S, 2D]
     xt = jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)  # rows grouped by sequence
@@ -295,7 +292,7 @@ def fused_rope(x, cos, sin):
                 pl.BlockSpec((s, 2 * d), lambda i: (0, 0)),
             ],
             out_specs=pl.BlockSpec((s, d), lambda i: (0, 0)),
-            interpret=not _on_tpu(),
+            interpret=backend.interpret_mode(),
         )(x3, cs)
 
     out = jax.vmap(run)(xt)

@@ -39,34 +39,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: jax.devices() raising (no backend initialised) means 'not on TPU', and logging here would fire on every CPU-test kernel call
-        return False
+from . import backend
 
 
 _VMEM_BUDGET = 10 * 1024 * 1024  # bytes for c_kv + k_pe residency per row
+
+
+def _shape_refusal(q_lat, ckv_buf, kpe_buf):
+    if q_lat.ndim != 3 or ckv_buf.ndim != 3 or kpe_buf.ndim != 3:
+        return "q_lat and the latent buffers must be 3-D"
+    B, H, r = q_lat.shape
+    T = ckv_buf.shape[1]
+    if r % 128 != 0 or T % 128 != 0 or H % 8 != 0:
+        return (f"latent width {r} / buffer length {T} / {H} heads do "
+                "not tile")
+    dr_pad = -(-kpe_buf.shape[-1] // 128) * 128
+    resident = T * (r + dr_pad) * jnp.dtype(ckv_buf.dtype).itemsize
+    if resident > _VMEM_BUDGET:
+        return f"one row's latents ({resident} B) exceed the VMEM budget"
+    return None
 
 
 def supported(q_lat, ckv_buf, kpe_buf, interpret: bool = False) -> bool:
     """Gate: TPU (or interpret-mode test), lane-tileable latent width,
     tileable buffer length, sublane-tileable head count, and whole-buffer
     latent residency under the VMEM budget."""
-    if not interpret and not _on_tpu():
-        return False
-    if q_lat.ndim != 3 or ckv_buf.ndim != 3 or kpe_buf.ndim != 3:
-        return False
-    B, H, r = q_lat.shape
-    T = ckv_buf.shape[1]
-    if r % 128 != 0 or T % 128 != 0 or H % 8 != 0:
-        return False
-    dr_pad = -(-kpe_buf.shape[-1] // 128) * 128
-    itemsize = jnp.dtype(ckv_buf.dtype).itemsize
-    if T * (r + dr_pad) * itemsize > _VMEM_BUDGET:
-        return False
-    return True
+    return backend.gate("mla_decode",
+                        _shape_refusal(q_lat, ckv_buf, kpe_buf), interpret)
 
 
 def _kernel(pos_ref, qlat_ref, qpe_ref, ckv_ref, kpe_ref, allowed_ref,
@@ -88,7 +87,7 @@ def _kernel(pos_ref, qlat_ref, qpe_ref, ckv_ref, kpe_ref, allowed_ref,
                    + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1))
             mask = col <= pos                      # S=1: limit is pos
             if have_allowed:
-                ab = allowed_ref[0, pl.ds(i * bkv, bkv)].reshape(1, bkv)
+                ab = allowed_ref[0, :, pl.ds(i * bkv, bkv)]   # [1, bkv]
                 mask = mask & (ab != 0)
             s_blk = jnp.where(mask, s_blk, -1e30)
             m_new = jnp.maximum(m, s_blk.max(axis=1, keepdims=True))
@@ -133,10 +132,10 @@ def _decode_jit(q_lat, q_pe, ckv_buf, kpe_buf, pos, allowed, interpret,
     if bkv is None:
         bkv = next(b for b in (512, 256, 128) if T % b == 0)
     have_allowed = allowed is not None
-    if not have_allowed:
-        allowed = jnp.ones((B, T), jnp.int8)
-    else:
-        allowed = allowed.astype(jnp.int8)
+    # [B, 1, T]: a (1, T) block over [B, T] would put a block of 1 on a
+    # second-to-last dim of B, which Mosaic refuses
+    allowed = (jnp.ones((B, 1, T), jnp.int32) if not have_allowed
+               else allowed.astype(jnp.int32).reshape(B, 1, T))
     # pos: scalar (shared decode offset) or [B] (per-row serving slots) —
     # the kernel always reads pos_ref[row]
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1),
@@ -154,7 +153,7 @@ def _decode_jit(q_lat, q_pe, ckv_buf, kpe_buf, pos, allowed, interpret,
                 pl.BlockSpec((1, H, dp), lambda b, pos: (b, 0, 0)),
                 pl.BlockSpec((1, T, r), lambda b, pos: (b, 0, 0)),
                 pl.BlockSpec((1, T, dp), lambda b, pos: (b, 0, 0)),
-                pl.BlockSpec((1, T), lambda b, pos: (b, 0)),
+                pl.BlockSpec((1, 1, T), lambda b, pos: (b, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, H, r), lambda b, pos: (b, 0, 0)),
         ),
@@ -170,9 +169,11 @@ def mla_decode_attention(q_lat, q_pe, ckv_buf, kpe_buf, pos, allowed=None,
     written at ``pos``), pos scalar OR [B] per-row limits (serving slots
     at different lengths), allowed optional [B,T] column mask.
     Returns the latent-space context [B,H,r] — same math as the absorbed
-    einsum branch of models.deepseek.mla_cached_attention at S=1."""
+    einsum branch of models.deepseek.mla_cached_attention at S=1.
+    ``interpret`` is honoured only off-TPU."""
     T = ckv_buf.shape[1]
     bkv = next(b for b in (512, 256, 128) if T % b == 0)
+    interpret = interpret and backend.interpret_mode()
     if not interpret:
         # FLAGS_use_autotune: eager TPU calls measure the T-block grid
         # once per (shape, dtype, device) and persist the winner; traced
@@ -182,7 +183,7 @@ def mla_decode_attention(q_lat, q_pe, ckv_buf, kpe_buf, pos, allowed=None,
         key = (f"B{q_lat.shape[0]}xH{q_lat.shape[1]}xr{q_lat.shape[2]}"
                f"xT{T} {ckv_buf.dtype}")
         cands = [(b,) for b in (1024, 512, 256, 128) if T % b == 0]
-        can = _on_tpu() and autotune.is_concrete(q_lat, ckv_buf, pos)
+        can = backend.on_tpu() and autotune.is_concrete(q_lat, ckv_buf, pos)
 
         def runner(cfg):
             return lambda: _decode_jit(q_lat, q_pe, ckv_buf, kpe_buf, pos,

@@ -851,11 +851,9 @@ def _resolve_spec_k(model, max_batch: int, max_len: int,
         return default  # non-llama-shaped config: the heuristic default
     sig = " ".join(f"{k_}{v}" for k_, v in sorted(params.items()))
     cands = [(c,) for c in (2, 3, 4, 6, 8) if c <= max_len]
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:  # pdlint: disable=silent-exception -- backend probe: no initialised backend means 'not on TPU', the designed measure-nothing fallback
-        on_tpu = False
-    can = on_tpu and max_len % page_size == 0
+    from .ops.pallas import backend
+
+    can = backend.on_tpu() and max_len % page_size == 0
 
     def runner(choice):
         (kk,) = choice

@@ -65,7 +65,7 @@ from ..distributed.log_utils import get_logger
 from ..distributed.store import TCPStore
 from .pool import WorkerPool
 from .router import RouterServer
-from .supervisor import WorkerSupervisor
+from .supervisor import EXIT_NO_DEVICE, WorkerSupervisor
 
 __all__ = ["Cluster", "launch_cluster", "load_config", "expand_workers"]
 
@@ -87,8 +87,9 @@ def load_config(path: str) -> dict:
 
 def expand_workers(cfg: dict) -> List[dict]:
     """The ``workers`` section expanded to one role entry per process
-    (``count`` multiplies); defaults to two unified workers."""
-    specs = cfg.get("workers") or [{"role": "unified", "count": 2}]
+    (``count`` multiplies); defaults to ONE unified worker — a worker
+    needs an accelerator of its own, and one is what any host has."""
+    specs = cfg.get("workers") or [{"role": "unified", "count": 1}]
     out = []
     for spec in specs:
         for _ in range(int(spec.get("count", 1))):
@@ -144,7 +145,6 @@ class Cluster:
                 "engine": cfg.get("engine") or {},
                 "model_name": cluster.get("model_name", "paddle-tpu"),
                 "platform": cluster.get("platform"),
-                "compile_cache": cluster.get("compile_cache"),
                 "incident_dir": cluster.get("incident_dir"),
                 "handoff_wait_s": cluster.get("handoff_wait_s", 30.0),
             }
@@ -174,12 +174,8 @@ class Cluster:
         if install_signal_handlers:
             self._install_signals()
         try:
-            if wait and not self.pool.wait_for_workers(
-                    len(worker_specs), timeout=wait_timeout):
-                raise RuntimeError(
-                    f"cluster: only {self.pool.alive_count()} of "
-                    f"{len(worker_specs)} workers joined within "
-                    f"{wait_timeout}s")
+            if wait:
+                self._wait_for_launch(len(worker_specs), wait_timeout)
             self.pool.start()
             self.router = RouterServer(
                 self.pool, host=host, port=int(cluster.get("port", 0)),
@@ -201,6 +197,30 @@ class Cluster:
         except BaseException:
             self.close()
             raise
+
+    def _wait_for_launch(self, n: int, timeout: float):
+        """Block until all ``n`` workers joined. A worker that EXITS
+        before joining fails the launch at once — nothing restarts it
+        yet (supervision starts after the join), so waiting out the
+        timeout would only hide why."""
+        deadline = time.monotonic() + timeout
+        while not self.pool.wait_for_workers(n, timeout=0.5):
+            for replica_id, p in self._replica_pids.items():
+                code = p.poll()
+                if code == EXIT_NO_DEVICE:
+                    raise RuntimeError(
+                        f"cluster: worker {replica_id} could not acquire "
+                        f"a JAX device (exit {code}; its message is on "
+                        f"stderr). An accelerator belongs to one process: "
+                        f"{n} workers need {n} free chips on this host.")
+                if code is not None:
+                    raise RuntimeError(
+                        f"cluster: worker {replica_id} exited with code "
+                        f"{code} before joining the pool")
+            if time.monotonic() >= deadline:
+                raise RuntimeError(
+                    f"cluster: only {self.pool.alive_count()} of {n} "
+                    f"workers joined within {timeout}s")
 
     def _make_spawn(self, wcfg: dict, env: dict, repo_root: str):
         """One worker's spawn closure — re-invoked by the supervisor on

@@ -51,7 +51,14 @@ from ..observability import flightrecorder as _frec
 from ..observability.catalog import REQUESTS_QUARANTINED, WORKER_RESTARTS
 
 __all__ = ["RestartBackoff", "CircuitBreaker", "QuarantineLedger",
-           "Deathnote", "WorkerSupervisor", "QUARANTINE_THRESHOLD"]
+           "Deathnote", "WorkerSupervisor", "QUARANTINE_THRESHOLD",
+           "EXIT_NO_DEVICE"]
+
+#: exit code of a worker that could not acquire a JAX device (sysexits'
+#: EX_UNAVAILABLE). The launcher fails the launch at once on it, and
+#: the supervisor holds the worker instead of restarting it: the next
+#: incarnation would meet the same busy chip.
+EXIT_NO_DEVICE = 69
 
 #: distinct worker deaths that quarantine a request id. Two is the
 #: containment bound the chaos gate pins: a poison request costs the
@@ -475,6 +482,20 @@ class WorkerSupervisor:
 
     def _handle_death(self, sup: _Supervised, proc):
         code = proc.poll()
+        if code == EXIT_NO_DEVICE:
+            # not a crash: the worker found no device it may use (the
+            # chip belongs to another process). The next incarnation
+            # would meet the same busy chip — hold, do not loop.
+            with self._lock:
+                sup.held_open = True
+                sup.proc = None
+                sup.last_exit = code
+            get_logger().error(
+                "supervisor: worker %s could not acquire a JAX device "
+                "(exit %s) — holding it, not restarting (free the chip, "
+                "then WorkerSupervisor.reset_breaker)", sup.replica_id,
+                code)
+            return
         self._blame(sup, proc)
         self.sweep_incidents()
         now = time.monotonic()

@@ -491,33 +491,35 @@ def build_model(spec: dict):
 
 # ---- process entry ----------------------------------------------------------
 
+class NoDevice(RuntimeError):
+    """JAX found no device this process may use."""
+
+
 def run_worker(cfg: dict):
     """Build the engine, join the pool, serve until SIGTERM.
 
     Config keys: ``replica_id``, ``role``, ``store`` (TCPStore
     host:port), ``world_size``, ``job_id``, ``ttl``, ``host``/``port``,
     ``model`` (builder spec), ``engine`` (ContinuousBatchEngine kwargs),
-    ``platform`` (jax platform override), ``compile_cache`` (persistent
-    XLA cache dir), ``kv_capacity_mb``, ``incident_dir``.
+    ``platform`` (jax platform override), ``kv_capacity_mb``,
+    ``incident_dir``. The persistent compile cache is where
+    ``utils.compile_cache`` puts it for every process of this checkout.
+
+    Raises :class:`NoDevice` before any model is built when JAX cannot
+    acquire a device (on a TPU host: another process holds the chip).
     """
+    import jax
+
+    from ..utils import compile_cache
+
     platform = cfg.get("platform")
     if platform:
-        import jax
-
         jax.config.update("jax_platforms", platform)
-    cache_dir = cfg.get("compile_cache")
-    if cache_dir:
-        import jax
-
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception as e:  # older jax without the knobs: run uncached
-            get_logger().debug("worker: compile cache unavailable "
-                               "(%s: %s)", type(e).__name__, e)
+    compile_cache.enable()
+    try:
+        jax.devices()
+    except RuntimeError as e:
+        raise NoDevice(str(e)) from e
     from ..serving import ContinuousBatchEngine
 
     replica_id = int(cfg.get("replica_id", 0))
@@ -631,7 +633,16 @@ def main(argv=None):
     else:
         with open(raw, encoding="utf-8") as f:
             cfg = json.load(f)
-    run_worker(cfg)
+    try:
+        run_worker(cfg)
+    except NoDevice as e:
+        print(f"cluster worker {cfg.get('replica_id', 0)}: no JAX device "
+              f"could be acquired — an accelerator belongs to one process "
+              f"at a time, so run at most one worker per chip.\n{e}",
+              file=sys.stderr, flush=True)
+        from .supervisor import EXIT_NO_DEVICE
+
+        return EXIT_NO_DEVICE
     return 0
 
 

@@ -76,11 +76,20 @@ from .observability import perf as _perf
 from .observability import sentinel as _sentinel
 from .observability import tracing as _tracing
 from .observability.catalog import HTTP_REQUESTS
+from .ops.pallas import backend as _pallas_backend
 from .serving import DeadlineExceeded, QueueFull
 
 __all__ = ["CompletionServer", "ServingHandlerBase", "serve",
            "DEADLINE_HEADER", "AUDIT_HEADER", "timeseries_payload",
            "alerts_payload", "profile_payload", "kvstate_payload"]
+
+def _device_payload() -> dict:
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
 
 #: end-to-end deadline propagation: the cluster router stamps each
 #: upstream hop with the request's REMAINING budget in milliseconds, so
@@ -757,6 +766,13 @@ class CompletionServer:
                                           eng.max_batch),
             "max_len": eng.max_len,
             "stats": stats,
+            # which implementation each kernel call site took in this
+            # process (pallas / pallas-interpret / xla) and why a gate
+            # refused — a worker on the composites says so here
+            "kernels": {"paths": _pallas_backend.paths(),
+                        "refusals": _pallas_backend.refusals()},
+            # the device this engine's programs run on, as JAX reports it
+            "device": _device_payload(),
         }
         payload.update(self.health_extra())
         return payload

@@ -51,8 +51,9 @@ def main(argv=None):
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8000,
                     help="router port (0 = ephemeral)")
-    ap.add_argument("--workers", type=int, default=2,
-                    help="unified worker count (ignored with --config)")
+    ap.add_argument("--workers", type=int, default=1,
+                    help="unified worker count, at most one per chip "
+                         "(ignored with --config)")
     ap.add_argument("--role", default="unified",
                     choices=("unified", "decode"))
     ap.add_argument("--prefill", type=int, default=0,

@@ -66,7 +66,7 @@ def test_fused_qkv_rope_matches_discrete():
     sin = jnp.asarray(rng.randn(B, D), jnp.float32)
 
     q, k, v = decode_tail.fused_qkv_rope(x, wn, wq, wk, wv, cos, sin,
-                                         eps, H, hk, D, interpret=True)
+                                         eps, H, hk, D)
 
     normed = fused_norm._rmsnorm_ref(x, wn, eps)
     qr = _rope_ref_rows((normed @ wq).reshape(B, H, D), cos, sin)
@@ -88,8 +88,7 @@ def test_fused_epilogue_matches_discrete():
     wo = jnp.asarray(rng.randn(width, hidden) * 0.05, jnp.float32)
     res = jnp.asarray(rng.randn(B, hidden), jnp.float32)
     wn = jnp.asarray(rng.randn(hidden), jnp.float32)
-    normed, new_res = decode_tail.fused_epilogue(attn, wo, res, wn, eps,
-                                                 interpret=True)
+    normed, new_res = decode_tail.fused_epilogue(attn, wo, res, wn, eps)
     h_ref = attn @ wo + res
     np.testing.assert_allclose(np.asarray(new_res), np.asarray(h_ref),
                                rtol=1e-5, atol=1e-5)

@@ -81,3 +81,33 @@ def test_save_group_sharded_model(tmp_path):
         assert set(sd) == set(model.state_dict())
     finally:
         dist.set_hybrid_communicate_group(None)
+
+
+def test_zero3_keeps_tensor_parallel_placements():
+    """ZeRO-3 over ``sharding`` must ADD a shard to a tensor-parallel
+    weight, not replace its ``mp`` shard: under mp2 x sharding2 a device
+    holds a quarter of each parallel matrix (the rewrite used to start
+    from all-Replicate and left every weight whole across ``mp``)."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.distributed.parallel_layers import (ColumnParallelLinear,
+                                                        RowParallelLinear)
+
+    s = dist.DistributedStrategy()
+    s.hybrid_configs = {"dp_degree": 1, "mp_degree": 2, "sharding_degree": 2}
+    s.sharding_configs = {"stage": 3}
+    dist.fleet.init(is_collective=True, strategy=s)
+    try:
+        model = nn.Sequential(ColumnParallelLinear(16, 32, has_bias=False,
+                                                   gather_output=False),
+                              RowParallelLinear(32, 16, has_bias=False,
+                                                input_is_parallel=True))
+        model = dist.fleet.distributed_model(model)
+        col, row = (p._array for p in model.parameters())
+        assert col.sharding.spec == P(None, ("sharding", "mp"))
+        assert row.sharding.spec == P(("sharding", "mp"))
+        for arr in (col, row):
+            assert len(arr.sharding.device_set) == 4
+            assert arr.addressable_shards[0].data.nbytes * 4 == arr.nbytes
+    finally:
+        dist.set_hybrid_communicate_group(None)

@@ -19,15 +19,12 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import ContinuousBatchEngine
 from paddle_tpu.observability import flightrecorder as frec
 
-_CACHE = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                        "/tmp/paddle_tpu_jax_cache")
-
 
 def _cluster_cfg(workers, max_batch=8, max_len=128, page_size=8,
                  ttl=2.0, layers=2):
     return {
         "cluster": {"host": "127.0.0.1", "port": 0, "ttl": ttl,
-                    "platform": "cpu", "compile_cache": _CACHE,
+                    "platform": "cpu",
                     "model_name": "tiny-llama-cluster",
                     # watchtower at test speed: fast sampling + short
                     # alert windows (restart window 6s, lost window
@@ -1105,8 +1102,9 @@ def test_launcher_config_loading(tmp_path):
     assert loaded["engine"]["max_batch"] == 8
     roles = [w["role"] for w in expand_workers(loaded)]
     assert roles == ["prefill", "prefill", "decode"]
-    # no workers section -> two unified workers, count stripped
-    assert [w["role"] for w in expand_workers({})] == ["unified"] * 2
+    # no workers section -> ONE unified worker (a worker needs a chip of
+    # its own, and one is what any host has), count stripped
+    assert [w["role"] for w in expand_workers({})] == ["unified"]
     assert all("count" not in w for w in expand_workers(loaded))
 
 
