@@ -1,0 +1,285 @@
+"""What the two serving kinds share: the process that holds the chip
+(model, ``ContinuousBatchEngine``, ``CompletionServer`` on a loopback
+port, profiler), the warm-up of the cell's own shapes, the comparison
+with the plain reference, and the window around the load generator's
+child process."""
+from __future__ import annotations
+
+import http.client
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+import numpy as np
+
+from . import build
+from .loadgen import schedule
+from .common import (BENCH, OUT, Cell, SetupClock, file_digest, note,
+                     peaks_for, rehearsed, start_jax)
+from .tracing import TraceSlice
+
+CHECK_TOKENS = 8
+#: |engine logprob - reference logprob| of a chosen token, in nats. The
+#: engine computes in bf16 through the kernels, the reference in float32
+#: from the same bf16 weights. Measured on the v5e at the published
+#: widths, depth 20 (my chip runs, PR 25): worst 0.020-0.037 over 4
+#: prompts x 8 tokens in seven runs; PR 24 measured 0.0107 of the logit
+#: range between kernel path and composites. 0.15 is four times the worst
+#: seen and a quarter of what an 8-bit float path (3 mantissa bits against
+#: bf16's 7: errors 16 times larger) would show.
+LOGPROB_TOL = 0.15
+
+
+def complete(addr, ids, max_tokens: int) -> dict:
+    """One non-streamed completion with the chosen tokens' logprobs."""
+    conn = http.client.HTTPConnection(*addr, timeout=900)
+    try:
+        conn.request("POST", "/v1/completions", json.dumps(
+            {"prompt_token_ids": [int(t) for t in ids],
+             "max_tokens": max_tokens, "logprobs": 0}),
+            {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        raw = resp.read().decode()
+    finally:
+        conn.close()
+    if resp.status != 200:
+        raise RuntimeError(f"POST /v1/completions -> {resp.status}: "
+                           f"{raw[:300]}")
+    choice = json.loads(raw)["choices"][0]
+    return {"token_ids": choice["token_ids"],
+            "logprobs": choice["logprobs"]["token_logprobs"]}
+
+
+def get(addr, route: str) -> str:
+    with urllib.request.urlopen(f"http://{addr[0]}:{addr[1]}{route}",
+                                timeout=60) as resp:
+        return resp.read().decode()
+
+
+def parse_metrics(text: str) -> dict:
+    """Prometheus text -> {series with labels: value}."""
+    out = {}
+    for line in text.splitlines():
+        if line and not line.startswith("#"):
+            series, _, value = line.rpartition(" ")
+            try:
+                out[series] = float(value)
+            except ValueError:
+                pass
+    return out
+
+
+class ServeRun:
+    """One serving cell's run, from the process's start to its outcomes."""
+
+    def __init__(self, cell: Cell, args, t_start: float):
+        self.cell, self.args = cell, args
+        self.rehearse = bool(args.rehearse)
+        self.clock = SetupClock(t_start)
+        self.cfg = rehearsed(cell.config, self.rehearse)
+        self.traffic = rehearsed(cell.traffic, self.rehearse)
+        self.engine_args = dict(self.cfg["recipe"]["engine"])
+        self.server = None
+
+    # ---- set-up ---------------------------------------------------------
+    def setup(self) -> None:
+        cell = self.cell
+        self.device, self.meter, cache_dir = start_jax(cell, self.rehearse)
+        self.peaks = None if self.rehearse else peaks_for(
+            self.device["kind"])
+        self.clock.lap("backend_s")
+        from paddle_tpu.ops.pallas import autotune
+        from paddle_tpu.serving import ContinuousBatchEngine
+        from paddle_tpu.serving_http import CompletionServer
+
+        self.autotune_path = autotune.cache_path()
+        self.model = build.build_model(self.cfg, self.args.seed)
+        self.clock.lap("weights_s")
+        self.engine = ContinuousBatchEngine(self.model, **self.engine_args)
+        self.server = CompletionServer(self.engine,
+                                       model_name=cell.entry["config"])
+        self.server.start()
+        self.addr = self.server.address
+        self.clock.lap("engine_s")
+        note("setup", cell=cell.name, compile_cache=cache_dir,
+             autotune_table=self.autotune_path, device=self.device)
+
+    def warm_up(self) -> None:
+        """Every shape the window will use, and no other: one prompt of
+        every length on the traffic's grid (the engine builds a prefill
+        and a scatter program per bucket AND a small mask program per
+        distinct prompt length), all at once as the window sends them;
+        again until a pass builds no program and leaves the autotune
+        table as it was."""
+        rng = np.random.RandomState(self.args.seed)
+        vocab = int(self.cfg["vocab_size"])
+        self.warm_prompts = [rng.randint(1, vocab, n).tolist()
+                             for n in schedule.prompt_grid(self.traffic)]
+        self.warm_answers = [None] * len(self.warm_prompts)
+
+        def ask(i):
+            self.warm_answers[i] = complete(self.addr, self.warm_prompts[i],
+                                            CHECK_TOKENS)
+
+        passes = 0
+        for _ in range(4):
+            before = (self.meter.mark(), file_digest(self.autotune_path))
+            threads = [threading.Thread(target=ask, args=(i,))
+                       for i in range(len(self.warm_prompts))]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join()
+            passes += 1
+            if (self.meter.mark(),
+                    file_digest(self.autotune_path)) == before:
+                break
+        if any(a is None for a in self.warm_answers):
+            raise RuntimeError("a warm-up request was not answered")
+        self.clock.lap("warmup_s")
+        note("warmup", prompt_lengths=[len(p) for p in self.warm_prompts],
+             passes=passes, **self.meter.report())
+
+    def check(self) -> bool:
+        """The engine's logprobs for up to four of the warm-up prompts
+        (spread over the buckets) x 8 tokens against the plain float32
+        reference, teacher-forced on the engine's own tokens: logprobs,
+        not tokens, since with random weights the top logit changes on
+        rounding."""
+        from ..reference import decoder
+
+        spec = decoder.Spec.from_config(self.cfg)
+        state = build.plain_state(self.model)
+        n = len(self.warm_prompts)
+        picks = sorted({round(i * (n - 1) / 3) for i in range(4)} if n > 4
+                       else set(range(n)))
+        rows, worst = [], 0.0
+        for i in picks:
+            prompt, ans = self.warm_prompts[i], self.warm_answers[i]
+            toks = ans["token_ids"]
+            lp = np.asarray(decoder.forward_logprobs(
+                spec, state, prompt + toks[:-1], last=len(toks)))
+            ref = lp[np.arange(len(toks)), np.asarray(toks)]
+            err = float(np.max(np.abs(ref - np.asarray(ans["logprobs"]))))
+            ok = (len(toks) == CHECK_TOKENS and np.isfinite(err)
+                  and err <= LOGPROB_TOL)
+            rows.append({"prompt_tokens": len(prompt), "ok": bool(ok),
+                         "max_abs_logprob_err": err,
+                         "engine_logprobs": [round(float(v), 4)
+                                             for v in ans["logprobs"]],
+                         "reference_logprobs": [round(float(v), 4)
+                                                for v in ref]})
+            worst = max(worst, err)
+        self.clock.lap("reference_check_s")
+        note("reference_check", tolerance=LOGPROB_TOL, worst=worst,
+             prompts=rows)
+        return all(r["ok"] for r in rows)
+
+    # ---- the window -----------------------------------------------------
+    def snapshot(self) -> dict:
+        return {"metrics": parse_metrics(get(self.addr, "/metrics")),
+                "stats": self.engine.stats(),
+                "compiles": self.meter.mark()}
+
+    def run_window(self) -> dict:
+        """Start the load generator's process, open the window after its
+        lead-in, take the program's counters at both ends, trace a slice
+        if asked, wait for the generator to end. Returns the context the
+        kind and the readers work from."""
+        cell, args, traffic = self.cell, self.args, self.traffic
+        health = json.loads(get(self.addr, "/health"))
+        note("kernels", **(health.get("kernels") or {}))
+        lead_in = float(traffic.get("lead_in_s", 0.0))
+        seconds = float(args.seconds)
+        plan_path = os.path.join(OUT, f"plan_{cell.name}.json")
+        out_path = os.path.join(OUT, f"outcomes_{cell.name}.json")
+        if os.path.exists(out_path):
+            os.remove(out_path)
+        t_open = time.time() + lead_in + 1.5
+        with open(plan_path, "w", encoding="utf-8") as f:
+            json.dump({"url": f"http://{self.addr[0]}:{self.addr[1]}",
+                       "kind": traffic["kind"], "params": traffic,
+                       "seed": args.seed, "seconds": seconds,
+                       "vocab_size": int(self.cfg["vocab_size"]),
+                       "t_open_epoch": t_open}, f)
+        child = subprocess.Popen(
+            [sys.executable, os.path.join(BENCH, "lib", "loadgen",
+                                          "client.py"), plan_path, out_path],
+            stdout=sys.stderr, start_new_session=True)
+        tracer = TraceSlice(cell.name) if args.trace else None
+        try:
+            self._sleep_until(t_open)
+            self.setup_s = t_open - self.clock.t_start
+            self.clock.lap("lead_in_s")
+            before = self.snapshot()
+            if tracer is not None:
+                self._sleep_until(t_open + seconds / 4.0)
+                tracer.start()
+                self._sleep_until(tracer.t_start + min(
+                    float(traffic.get("trace_slice_s", 5.0)), seconds / 2.0))
+                tracer.stop()
+            self._sleep_until(t_open + seconds)
+            after = self.snapshot()
+            limit = float(traffic.get("drain_s", 20.0)) + 20.0
+            try:
+                child.wait(timeout=limit)
+            except subprocess.TimeoutExpired:
+                note("loadgen_killed", after_s=limit)
+        finally:
+            if child.poll() is None:
+                child.kill()
+            child.wait()
+        if tracer is not None:
+            tracer.reduce()
+        result = {"digest": None, "outcomes": []}
+        if os.path.exists(out_path):
+            with open(out_path, encoding="utf-8") as f:
+                result = json.load(f)
+        note("setup_split", setup_s=self.setup_s, **self.clock.parts,
+             **self.meter.report())
+        compiles = after["compiles"][0] - before["compiles"][0] + (
+            after["compiles"][1] - before["compiles"][1])
+        return {
+            "cell": cell.name, "kind": traffic["kind"], "seconds": seconds,
+            "chips": cell.chips, "config": self.cfg, "traffic": traffic,
+            "engine_args": self.engine_args, "peaks": self.peaks,
+            "digest": result["digest"], "outcomes": result["outcomes"],
+            "before": before, "after": after, "health": health,
+            "compiles_in_window": compiles,
+            "trace": tracer.reduced if tracer else None,
+            "trace_window": ((tracer.t_start - t_open, tracer.t_stop - t_open)
+                             if tracer else None),
+        }
+
+    @staticmethod
+    def _sleep_until(t_epoch: float) -> None:
+        delay = t_epoch - time.time()
+        if delay > 0:
+            time.sleep(delay)
+
+    def close(self) -> None:
+        if self.server is not None:
+            self.server.close()
+            self.server = None
+
+    def run(self) -> tuple:
+        """Set-up, warm-up, check, window: (correct, context)."""
+        try:
+            self.setup()
+            self.warm_up()
+            correct = self.check()
+            return correct, self.run_window()
+        finally:
+            self.close()
+
+
+def by_status(outcomes: list) -> dict:
+    counts = {}
+    for o in outcomes:
+        key = "ok" if o["ok"] else f"{o['status']}:{(o['error'] or '')[:60]}"
+        counts[key] = counts.get(key, 0) + 1
+    return counts
