@@ -911,8 +911,10 @@ class PipelineParallel(Layer):
         # (dropout masks, BN statistics choice)
         key = (part, self._layers.training)
         if key not in self._fwd_jit:
-            pure = self._make_pure(part)
-            self._fwd_jit[key] = self._wrap_stage_call(part, jax.jit(pure))
+            stage_forward = self._make_pure(part)
+            stage_forward.__name__ = f"pipeline_stage{part}_forward"
+            self._fwd_jit[key] = self._wrap_stage_call(
+                part, jax.jit(stage_forward))
         return self._fwd_jit[key]
 
     def _bwd_fn(self, part: int):

@@ -646,10 +646,10 @@ class _DecodeStep:
     def __init__(self, model, max_len):
         self._model = model
 
-        def pure(state, token, bufs, aux):
+        def decode_step_solo(state, token, bufs, aux):
             return _cached_forward(model, max_len, state, token, bufs, aux)
 
-        self._jitted = jax.jit(pure, donate_argnums=(2,))
+        self._jitted = jax.jit(decode_step_solo, donate_argnums=(2,))
         self._state = dict(model.functional_state())
 
     def __call__(self, token, caches):
@@ -677,7 +677,8 @@ class _EncDecBeamStep:
         from .autograd import tape as _tape
         from .nn.layer import functional_weights
 
-        def pure(state, token, row_idx, self_caches, cross_caches):
+        def seq2seq_beam_step(state, token, row_idx, self_caches,
+                              cross_caches):
             n = row_idx.shape[0]
             take = lambda a: (jnp.take(a, row_idx, axis=0)
                               if _rows_match(a, n) else a)
@@ -692,7 +693,7 @@ class _EncDecBeamStep:
                 {k: (unwrap(v) if isinstance(v, Tensor) else v)
                  for k, v in c.items()} for c in new_self]
 
-        self._jitted = jax.jit(pure, donate_argnums=(3,))
+        self._jitted = jax.jit(seq2seq_beam_step, donate_argnums=(3,))
         self._state = dict(model.functional_state())
 
     def __call__(self, token, row_idx, self_caches, cross_caches):
@@ -751,7 +752,7 @@ class _BeamStep:
     def __init__(self, model, max_len):
         self._model = model
 
-        def pure(state, token, row_idx, bufs, aux):
+        def beam_step(state, token, row_idx, bufs, aux):
             take = lambda a: (jnp.take(a, row_idx, axis=0)
                               if _rows_match(a, row_idx.shape[0]) else a)
             bufs = jax.tree.map(take, bufs)
@@ -762,7 +763,7 @@ class _BeamStep:
                 logits[:, -1, :].astype(jnp.float32), axis=-1)
             return logp, nb, na
 
-        self._jitted = jax.jit(pure, donate_argnums=(3,))
+        self._jitted = jax.jit(beam_step, donate_argnums=(3,))
         self._state = dict(model.functional_state())
 
     def __call__(self, token, row_idx, caches):
@@ -978,7 +979,7 @@ class _PrefillStep:
         rope_len = max_len if rope_len is None else rope_len
         self._model = model
 
-        def pure(state, ids_or_embeds, lengths, pad_mask):
+        def prefill(state, ids_or_embeds, lengths, pad_mask):
             with _functional_weights(model, state), _tape.no_grad():
                 B = ids_or_embeds.shape[0]
                 caches = _empty_caches(
@@ -997,11 +998,25 @@ class _PrefillStep:
                 last = unwrap(model.lm_head_logits(wrap(h_last)))[:, 0, :]
             return last, _unwrap_caches(caches)
 
-        self._jitted = jax.jit(pure)
+        # the program's name says which variant ran: prefill,
+        # prefill_ragged, prefill_embeds, prefill_embeds_ragged
+        prefill.__name__ = ("prefill" + ("_embeds" if embeds_input else "")
+                            + ("_ragged" if ragged else ""))
+        self._jitted = jax.jit(prefill)
         self._state = dict(model.functional_state())
 
     def __call__(self, ids, lengths, pad_mask):
         return self._jitted(self._state, ids, lengths, pad_mask)
+
+
+def prefill_mask(lengths, bucket):
+    """[B, bucket] bool, True on each row's real tokens: the pad mask of
+    a ragged admission prefill. The lengths are a traced operand, so one
+    program serves every prompt length of a bucket."""
+    return jnp.arange(bucket, dtype=jnp.int32)[None, :] < lengths[:, None]
+
+
+_prefill_mask = jax.jit(prefill_mask, static_argnums=(1,))
 
 
 def _trace_flags_key() -> tuple:
@@ -1093,7 +1108,7 @@ class _ChunkedPrefillStep:
         self._model = model
         C, n = int(chunk), int(n_chunks)
 
-        def pure(state, ids_pad, lengths, allowed):
+        def prefill_scan(state, ids_pad, lengths, allowed):
             B = ids_pad.shape[0]
             with _functional_weights(model, state), _tape.no_grad():
                 caches = _empty_caches(model, B, max_len, allowed=allowed)
@@ -1131,7 +1146,7 @@ class _ChunkedPrefillStep:
                     wrap(h_last[:, None, :])))[:, 0, :]
             return last, bufs, aux
 
-        self._jitted = jax.jit(pure)
+        self._jitted = jax.jit(prefill_scan)
         self._state = dict(model.functional_state())
 
     def __call__(self, ids_pad, lengths, allowed):
@@ -1185,7 +1200,7 @@ class _ScanDecodeStep:
                  top_k, top_p):
         self._model = model
 
-        def pure(state, last, base_key, bufs, aux):
+        def decode_scan(state, last, base_key, bufs, aux):
             with _functional_weights(model, state):
                 def body(carry, t):
                     last_t, bufs_t, aux_t = carry
@@ -1199,7 +1214,7 @@ class _ScanDecodeStep:
                     body, (last, bufs, aux), jnp.arange(steps))
             return toks, last_f, bufs_f, aux_f
 
-        self._jitted = jax.jit(pure, donate_argnums=(3,))
+        self._jitted = jax.jit(decode_scan, donate_argnums=(3,))
         self._state = dict(model.functional_state())
 
     def __call__(self, last, base_key, caches):
@@ -1222,14 +1237,14 @@ class _SelectDecodeStep:
     def __init__(self, model, max_len, do_sample, temperature, top_k, top_p):
         self._model = model
 
-        def pure(state, last, key, bufs, aux):
+        def decode_step(state, last, key, bufs, aux):
             with _functional_weights(model, state):
                 nxt, lp, last_n, nb, na = _sample_and_forward(
                     model, max_len, last, key, bufs, aux,
                     do_sample, temperature, top_k, top_p)
             return nxt, lp, last_n.astype(jnp.float32), nb, na
 
-        self._jitted = jax.jit(pure, donate_argnums=(3,))
+        self._jitted = jax.jit(decode_step, donate_argnums=(3,))
         self._state = dict(model.functional_state())
 
     def __call__(self, last, key, caches):
@@ -1247,7 +1262,8 @@ class _SelectDecodeRowsStep:
     def __init__(self, model, max_len):
         self._model = model
 
-        def pure(state, last, key, do_s, temp, tk, tp, bufs, aux):
+        def decode_step_rows(state, last, key, do_s, temp, tk, tp, bufs,
+                             aux):
             with _functional_weights(model, state):
                 nxt, lp, last_n, nb, na = _sample_and_forward(
                     model, max_len, last, key, bufs, aux,
@@ -1256,7 +1272,7 @@ class _SelectDecodeRowsStep:
                         lg, k, do_s, temp, tk, tp))
             return nxt, lp, last_n.astype(jnp.float32), nb, na
 
-        self._jitted = jax.jit(pure, donate_argnums=(7,))
+        self._jitted = jax.jit(decode_step_rows, donate_argnums=(7,))
         self._state = dict(model.functional_state())
 
     def __call__(self, last, key, do_s, temp, tk, tp, caches):
@@ -1289,7 +1305,7 @@ class _SpecDecodeStep:
         self._model = model
         k = int(k)
 
-        def pure(state, last, drafts, bufs, aux):
+        def spec_verify(state, last, drafts, bufs, aux):
             B = last.shape[0]
             with _functional_weights(model, state), _tape.no_grad():
                 g0 = jnp.argmax(last, axis=-1).astype(jnp.int32)   # [B]
@@ -1324,7 +1340,7 @@ class _SpecDecodeStep:
             nb, na = _split_caches(_unwrap_caches(new_caches))
             return chunk, n_acc + 1, lps, new_last, nb, na
 
-        self._jitted = jax.jit(pure, donate_argnums=(3,))
+        self._jitted = jax.jit(spec_verify, donate_argnums=(3,))
         self._state = dict(model.functional_state())
 
     def __call__(self, last, drafts, caches):

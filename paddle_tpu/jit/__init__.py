@@ -87,7 +87,7 @@ class StaticFunction:
         _LIVE_STATIC_FUNCTIONS.add(self)
 
         if layer is not None:
-            def pure(state, rng_key, training, *args, **kwargs):
+            def to_static_forward(state, rng_key, training, *args, **kwargs):
                 # swap traced arrays in, restore eager arrays after the trace
                 # (otherwise tracers leak into the layer's eager state)
                 from ..nn.layer import functional_weights
@@ -105,13 +105,17 @@ class StaticFunction:
                     for l, m in zip(subs, prev_modes):
                         l.training = m
 
-            self._jitted = jax.jit(pure, static_argnums=(2,) + tuple(a + 3 for a in static_argnums))
+            self._jitted = jax.jit(
+                to_static_forward,
+                static_argnums=(2,) + tuple(a + 3 for a in static_argnums))
         else:
-            def pure(rng_key, *args, **kwargs):
+            def to_static_function(rng_key, *args, **kwargs):
                 with _random.rng_context(rng_key):
                     return _unwrap_tree(fn(*args, **kwargs))
 
-            self._jitted = jax.jit(pure, static_argnums=tuple(a + 1 for a in static_argnums))
+            self._jitted = jax.jit(
+                to_static_function,
+                static_argnums=tuple(a + 1 for a in static_argnums))
 
     def __call__(self, *args, **kwargs):
         from ..autograd import tape as _tape
@@ -230,7 +234,7 @@ class TrainStep:
         self._opt_state = None
         self._params0 = None
 
-        def pure_step(params, buffers, opt_state, rng_key, lr, *batch):
+        def train_step(params, buffers, opt_state, rng_key, lr, *batch):
             def loss_of(p):
                 from ..nn.layer import functional_weights
 
@@ -239,8 +243,13 @@ class TrainStep:
                     loss = loss_fn(model, *[wrap(b) for b in batch])
                 return unwrap(loss)
 
-            loss, grads = jax.value_and_grad(loss_of)(params)
-            new_params, new_opt_state = optimizer.apply_gradients(opt_state, params, grads, lr=lr)
+            # the two halves carry their scope in every instruction's
+            # metadata (op_name), at no run-time cost
+            with jax.named_scope("forward_backward"):
+                loss, grads = jax.value_and_grad(loss_of)(params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = optimizer.apply_gradients(
+                    opt_state, params, grads, lr=lr)
             return loss, new_params, new_opt_state
 
         # jit-path NaN/Inf hooks (VERDICT r2 missing #10): the eager
@@ -258,9 +267,9 @@ class TrainStep:
             # model's params and the optimizer state untouched and the user
             # can catch, skip the bad batch, and continue
             self._jitted = jax.jit(
-                checkify.checkify(pure_step, errors=checkify.float_checks))
+                checkify.checkify(train_step, errors=checkify.float_checks))
         else:
-            self._jitted = jax.jit(pure_step, donate_argnums=(0, 2))
+            self._jitted = jax.jit(train_step, donate_argnums=(0, 2))
 
     def _split_state(self):
         params, buffers = {}, {}
@@ -326,14 +335,14 @@ def save(layer, path, input_spec=None, **configs):
 
             specs = [s.to_shape_dtype_struct() for s in input_spec]
 
-            def pure(state_arrs, *args):
+            def exported_forward(state_arrs, *args):
                 from ..nn.layer import functional_weights
 
                 with functional_weights(layer, state_arrs):
                     return _unwrap_tree(
                         layer.forward(*[wrap(a) for a in args]))
 
-            exported = jax_export.export(jax.jit(pure))(
+            exported = jax_export.export(jax.jit(exported_forward))(
                 {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in state.items()},
                 *specs,
             )

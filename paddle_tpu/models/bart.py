@@ -422,7 +422,7 @@ class _BartDecodeStep:
         from ..autograd import tape as _tape
         from ..nn.layer import functional_weights
 
-        def pure(state, token, self_caches, cross_caches):
+        def bart_decode_step(state, token, self_caches, cross_caches):
             with functional_weights(model, state), _tape.no_grad():
                 hidden, new_self, _ = model.model.decode_cached(
                     wrap(token), self_caches, cross_caches)
@@ -431,7 +431,7 @@ class _BartDecodeStep:
                 {k: (unwrap(v) if isinstance(v, Tensor) else v)
                  for k, v in c.items()} for c in new_self]
 
-        self._jitted = jax.jit(pure, donate_argnums=(2,))
+        self._jitted = jax.jit(bart_decode_step, donate_argnums=(2,))
         self._state = dict(model.functional_state())
 
     def __call__(self, token, self_caches, cross_caches):

@@ -371,7 +371,7 @@ class _WhisperDecodeStep:
         from ..autograd import tape as _tape
         from ..nn.layer import functional_weights
 
-        def pure(state, token, self_caches, cross_caches):
+        def whisper_decode_step(state, token, self_caches, cross_caches):
             with functional_weights(model, state), _tape.no_grad():
                 hidden, new_self, _ = model.model.decode_cached(
                     wrap(token), self_caches, cross_caches)
@@ -380,7 +380,7 @@ class _WhisperDecodeStep:
                 {k: (unwrap(v) if isinstance(v, Tensor) else v)
                  for k, v in c.items()} for c in new_self]
 
-        self._jitted = jax.jit(pure, donate_argnums=(2,))
+        self._jitted = jax.jit(whisper_decode_step, donate_argnums=(2,))
         self._state = dict(model.functional_state())
 
     def __call__(self, token, self_caches, cross_caches):

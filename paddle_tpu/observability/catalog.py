@@ -19,13 +19,17 @@ _R = get_registry()
 
 SERVING_QUEUE_WAIT = _R.histogram(
     "serving_queue_wait_seconds",
-    "Time a request spent queued before slot admission",
+    "Time a request spent queued before slot admission: from its "
+    "submission to the ENGINE (add_request on the engine thread, after "
+    "the HTTP front's own submission queue) to the moment it takes a slot",
     labels=("engine",))
 
 SERVING_TTFT = _R.histogram(
     "serving_time_to_first_token_seconds",
-    "Submission to first generated token (queue wait + prefill + first "
-    "decode step)",
+    "Submission to the ENGINE (add_request, the same instant "
+    "serving_queue_wait_seconds starts from) to the first generated token "
+    "as the engine thread sees it (queue wait + prefill + first decode "
+    "step); HTTP parsing and the SSE write are outside it",
     labels=("engine",))
 
 SERVING_INTER_TOKEN = _R.histogram(
@@ -73,6 +77,28 @@ SERVING_PREFIX_PAGES = _R.counter(
     "serving_prefix_cache_pages_reused_total",
     "KV pages copied from an active slot instead of recomputed",
     labels=("engine",))
+
+SERVING_DECODE_ROWS = _R.counter(
+    "serving_decode_rows_total",
+    "Active rows summed over decode dispatches (one-token and "
+    "speculative); over serving_decode_step_seconds_count it is the mean "
+    "batch a decode program ran with",
+    labels=("engine",))
+
+SERVING_DECODE_CACHED_TOKENS = _R.counter(
+    "serving_decode_cached_tokens_total",
+    "K/V rows the decode attention reads, summed over decode dispatches: "
+    "prompt tokens + tokens generated so far of every active row, from "
+    "the host's bookkeeping at dispatch",
+    labels=("engine",))
+
+SERVING_PREFILL_TOKENS = _R.counter(
+    "serving_prefill_tokens_total",
+    "Tokens through the admission prefill programs: kind=prompt the real "
+    "tokens computed (a prefix-cache hit counts its suffix only), "
+    "kind=bucket the padded length the program ran at; 1 - prompt/bucket "
+    "is the padding waste",
+    labels=("engine", "kind"))
 
 SERVING_SPEC_ACCEPTED = _R.histogram(
     "serving_spec_accepted_tokens",

@@ -35,22 +35,40 @@ See docs/SERVING.md "Step anatomy & roofline accounting".
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from . import catalog as _cat
 from . import flightrecorder as _frec
 
 __all__ = ["PHASES", "PhaseClock", "StepProfiler", "get_profiler",
-           "profile_payload", "decode_step_params"]
+           "profile_payload", "decode_step_params", "NO_SPAN"]
 
 #: the phase vocabulary, in step order. ``draft`` only appears on the
 #: speculative path; the seq2seq engine folds its encoder+seed prefill
 #: into ``admit`` (that IS its admission prefill) and never drafts.
 PHASES = ("admit", "prefill", "draft", "dispatch", "sync", "retire")
+
+#: names on the profiler's trace (docs/SERVING.md "Tracing"): the step,
+#: and ``engine/<phase>`` for each phase and each annotation-only span
+STEP_SPAN = "engine/step"
+SPAN_PREFIX = "engine/"
+
+
+#: what a guarded annotation site enters while the profiler is off
+NO_SPAN = contextlib.nullcontext()
+
+
+def _enter(annotation):
+    annotation.__enter__()
+    return annotation
+
 
 #: recent-step window: exact quantiles + top-K come from here, while the
 #: histograms carry the unbounded series for the TSDB/alerting path
@@ -156,16 +174,35 @@ def _quantile(sorted_vals: List[float], q: float) -> float:
 class PhaseClock:
     """Engine-thread-only phase stopwatch. No locks: exactly one thread
     (the engine's step loop) ever touches an instance, and the profiler
-    reads it only inside that same thread's ``commit()``."""
+    reads it only inside that same thread's ``commit()``.
 
-    __slots__ = ("t0", "_last", "phases")
+    One stopwatch, two sinks: ``begin(step_num)`` ... ``open(phase)`` ...
+    ``close()`` ... ``end()`` accumulate ``phases`` exactly as
+    ``begin()``/``lap(phase)`` do, and ALSO open and close
+    ``jax.profiler`` annotations on the calling thread: one
+    ``engine/step`` (a ``StepTraceAnnotation``) per step and one
+    ``engine/<phase>`` per opened phase, so a device trace shows on its
+    own clock what the host was doing in every gap. With no profiler
+    session an annotation is a flag test."""
+
+    __slots__ = ("t0", "_last", "phases", "_phase", "_span", "_step")
 
     def __init__(self):
         self.t0 = 0.0
         self._last = 0.0
         self.phases: Dict[str, float] = {}
+        self._phase: Optional[str] = None
+        self._span = None       # the open engine/<phase> annotation
+        self._step = None       # the open engine/step annotation
 
-    def begin(self) -> None:
+    def begin(self, step_num: Optional[int] = None) -> None:
+        """Start a step. With ``step_num`` the step is also written to
+        the profiler's trace as ``engine/step``, until ``end()``."""
+        if self._step is not None:      # a step that raised: close it
+            self.end()
+        if step_num is not None:
+            self._step = _enter(jax.profiler.StepTraceAnnotation(
+                STEP_SPAN, step_num=step_num))
         self.t0 = self._last = time.perf_counter()
         self.phases.clear()
 
@@ -176,6 +213,42 @@ class PhaseClock:
         self.phases[phase] = (self.phases.get(phase, 0.0)
                               + (now - self._last))
         self._last = now
+
+    def open(self, phase: str) -> None:
+        """Open ``phase`` (closing the one that is open): the name is
+        known from here on, which an annotation needs."""
+        if self._phase is not None:
+            self.close()
+        self._phase = phase
+        self._span = _enter(jax.profiler.TraceAnnotation(
+            SPAN_PREFIX + phase))
+
+    def close(self) -> None:
+        """Close the open phase: its annotation ends and the time since
+        the previous boundary is lapped to it."""
+        phase, span = self._phase, self._span
+        if phase is None:
+            return
+        self._phase = self._span = None
+        span.__exit__(None, None, None)
+        self.lap(phase)
+
+    def span(self, name: str):
+        """An annotation-only span (not a phase: nothing is timed),
+        ``engine/<phase>/<name>`` inside an open phase and
+        ``engine/<name>`` outside: a context manager."""
+        phase = self._phase
+        return jax.profiler.TraceAnnotation(
+            f"{SPAN_PREFIX}{phase}/{name}" if phase
+            else SPAN_PREFIX + name)
+
+    def end(self) -> None:
+        """End the step on every path out of it (early returns and
+        raises included): closes a phase left open and ``engine/step``."""
+        self.close()
+        step, self._step = self._step, None
+        if step is not None:
+            step.__exit__(None, None, None)
 
     def total(self) -> float:
         """Wall seconds from ``begin()`` to the last lap — equals the
@@ -219,6 +292,11 @@ class StepProfiler:
 
     def disable(self) -> None:
         self.enabled = False
+
+    def span(self, name: str):
+        """``clock.span(name)`` while enabled, else a no-op context: the
+        guard of an annotation-only site (one attribute read while off)."""
+        return self.clock.span(name) if self.enabled else NO_SPAN
 
     def set_cost_params(self, params: Optional[dict]) -> None:
         """Attach the engine's cost-model params (``decode_step_params``

@@ -152,6 +152,7 @@ def _append_jit(q, k_buf, v_buf, pos, allowed, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((B, S, hk, g, D), q.dtype),
         interpret=interpret,
+        name="append_attention",
     )(pos_arr, q5, k3, v3, allowed)
     return out.reshape(B, S, H, D)
 

@@ -276,6 +276,7 @@ def _qkv_call(x, w_norm, wq, wk, wv, cs, eps, n_heads, n_kv, d,
             pltpu.VMEM((b, wid_kv), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_tail_qkv_rope",
     )(x, w_norm.reshape(1, hidden), wq, wk, wv, cs)
 
 
@@ -356,6 +357,7 @@ def _epilogue_call(attn, wo, residual, w_norm, eps, interpret, bk):
         ),
         scratch_shapes=[pltpu.VMEM((b, hidden), jnp.float32)],
         interpret=interpret,
+        name="decode_tail_epilogue",
     )(attn, wo, residual.astype(attn.dtype), w_norm.reshape(1, hidden))
 
 

@@ -102,6 +102,7 @@ def _rmsnorm_pallas(x2d, w, eps, block_rows):
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         interpret=backend.interpret_mode(),
+        name="rms_norm",
     )(x2d, w.reshape(1, d))
 
 
@@ -203,6 +204,7 @@ def _add_rms_pallas(x2d, r2d, w, eps, block):
             pl.BlockSpec((block, d), lambda i: (i, 0)),
         ),
         interpret=backend.interpret_mode(),
+        name="add_rms_norm",
     )(x2d, r2d, w.reshape(1, d))
 
 
@@ -293,6 +295,7 @@ def fused_rope(x, cos, sin):
             ],
             out_specs=pl.BlockSpec((s, d), lambda i: (0, 0)),
             interpret=backend.interpret_mode(),
+            name="fused_rope",
         )(x3, cs)
 
     out = jax.vmap(run)(xt)

@@ -159,6 +159,7 @@ def _decode_jit(q_lat, q_pe, ckv_buf, kpe_buf, pos, allowed, interpret,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_lat.dtype),
         interpret=interpret,
+        name="mla_decode",
     )(pos_arr, q_lat, q_pe, ckv_buf, kpe_buf, allowed)
 
 

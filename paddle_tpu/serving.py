@@ -40,7 +40,7 @@ from .tensor_class import Tensor, unwrap
 from .framework import random as _random
 from .generation import (_get_prefill_step, _get_select_decode,
                          _get_select_decode_rows, _get_spec_decode,
-                         _memoized_step)
+                         _memoized_step, _prefill_mask)
 
 
 #: default priority class — lower value is MORE important. 0 is the
@@ -569,6 +569,20 @@ class _RequestBookkeeping:
                        prompt_tokens=int(req.ids.size),
                        max_new_tokens=req.max_new_tokens,
                        queue_depth=len(self._queue))
+
+    def _profiled_step(self):
+        """``_step_decode`` under the step-anatomy clock: the tracer's
+        guarded fast path (one attribute read while profiling is off);
+        early returns and raises close their spans too."""
+        prof = self.profiler
+        if not prof.enabled:
+            return self._step_decode(None)
+        clk = prof.clock
+        clk.begin(self._n_steps)
+        try:
+            return self._step_decode(clk)
+        finally:
+            clk.end()
 
     def _observe_admission(self, req: _Request, now: float):
         """Queue-wait accounting at the moment a request takes a slot.
@@ -1133,6 +1147,14 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             engine="decoder", result="miss")
         self._m_prefix_pages = _metrics.SERVING_PREFIX_PAGES.labels(
             engine="decoder")
+        self._m_decode_rows = _metrics.SERVING_DECODE_ROWS.labels(
+            engine="decoder")
+        self._m_decode_cached = _metrics.SERVING_DECODE_CACHED_TOKENS.labels(
+            engine="decoder")
+        self._m_prefill_prompt = _metrics.SERVING_PREFILL_TOKENS.labels(
+            engine="decoder", kind="prompt")
+        self._m_prefill_bucket = _metrics.SERVING_PREFILL_TOKENS.labels(
+            engine="decoder", kind="bucket")
 
     def _require_fit(self, n_prompt: int, max_new: int):
         """Slot-capacity admission check. With speculation on, every
@@ -1740,26 +1762,31 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             raise RuntimeError(
                 "ContinuousBatchEngine: a failed admission invalidated the "
                 "page pool; rebuild the engine and resubmit requests")
-        # step-anatomy clock: the tracer's guarded fast path — one
-        # attribute read while profiling is off
-        prof = self.profiler
-        clk = prof.clock if prof.enabled else None
+        return self._profiled_step()
+
+    def _step_decode(self, clk) -> Dict[int, np.ndarray]:
+        """``step()``'s body; ``clk`` is the profiler's clock or None.
+        Every phase is opened under its name (``PhaseClock.open``), so it
+        is both timed and written to the profiler's trace."""
         if clk is not None:
-            clk.begin()
+            clk.open("admit")
         self._admit()
         if clk is not None:
-            clk.lap("admit")
+            clk.open("prefill")
         self._advance_chunk()
         if clk is not None:
-            clk.lap("prefill")
+            clk.close()
         if self.num_active == 0:
             self._clear_dispatch_guard()
             return self._drain_finished()
+        spec = self.speculative_k is not None and self._spec_eligible()
+        if clk is not None:
+            clk.open("draft" if spec else "dispatch")
         # pre-dispatch blame + poison injection: arm the deathnote with
         # the rids entering this dispatch (covers the speculative branch
         # too — it is the same device dispatch boundary)
         self._dispatch_guard([r for r in self._slots if r is not None])
-        if self.speculative_k is not None and self._spec_eligible():
+        if spec:
             return self._step_speculative(clk)
         t_dispatch = time.perf_counter()
         do_sample, temperature, top_k, top_p = self._sample_cfg
@@ -1796,14 +1823,15 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             # the most recently admitted slot typed, shrink the budget
             self._degrade_on_oom(None, where="step", exc=e)
             return self._drain_finished()
+        self._count_decode_dispatch()
         if clk is not None:
-            clk.lap("dispatch")
+            clk.open("sync")
         # THE one deliberate device->host sync of the decode loop: every
         # other host conversion below reads these already-fetched arrays
         toks = np.asarray(nxt)    # pdlint: disable=host-sync
         lps = np.asarray(logps)   # pdlint: disable=host-sync
         if clk is not None:
-            clk.lap("sync")
+            clk.open("retire")
         self._clear_dispatch_guard()  # step success: blame record erased
         inj = _chaos.active()
         if inj is not None and "engine.logits" in inj.plan.points():
@@ -1918,17 +1946,41 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         if first_exc is not None:
             raise first_exc
         if clk is not None:
-            clk.lap("retire")
+            clk.open("admit")  # trailing refill accumulates into admit
         self._admit()
         if clk is not None:
-            clk.lap("admit")   # trailing refill accumulates into admit
-            prof.commit(
+            clk.close()
+            self.profiler.commit(
                 active=int(active.sum()),
                 kv_len=max((int(r.ids.size) + len(r.tokens)
                             for r in self._slots if r is not None),
                            default=0),
                 fr_seq=fr_seq)
         return self._drain_finished()
+
+    def _count_decode_dispatch(self):
+        """One decode dispatch left the host: the rows that decode in it
+        and the K/V rows the attention reads for them (prompt + tokens
+        generated so far, per row), from the host's own bookkeeping."""
+        rows = cached = 0
+        for r in self._slots:
+            if r is not None:
+                rows += 1
+                cached += int(r.ids.size) + len(r.tokens)
+        self._m_decode_rows.inc(rows)
+        self._m_decode_cached.inc(cached)
+
+    def _count_prefill(self, n_tokens: int, bucket: int):
+        """One prefill program enqueued: the real tokens it computes and
+        the bucket it pads them to."""
+        self._m_prefill_prompt.inc(n_tokens)
+        self._m_prefill_bucket.inc(bucket)
+
+    def _dispatch_span(self):
+        """Annotation-only ``engine/<phase>/prefill_dispatch`` around the
+        calls that enqueue an admission's programs, so a trace tells
+        enqueueing from bookkeeping."""
+        return self.profiler.span("prefill_dispatch")
 
     # ---- speculative decoding: multi-token steps ------------------------
     def _spec_eligible(self) -> bool:
@@ -1991,7 +2043,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             rec.record(_frec.EV_SPEC_PROPOSE, engine=self._engine_label,
                        active=self.num_active, k=k, drafted=n_drafted)
         if clk is not None:
-            clk.lap("draft")   # host n-gram propose, pre-dispatch
+            clk.open("dispatch")  # closes draft: host n-gram propose
         try:
             with _frec.incident_scope("engine.step"):
                 step = _get_spec_decode(self.model, self.max_len, k)
@@ -2000,8 +2052,9 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         except _frec.XlaOom as e:
             self._degrade_on_oom(None, where="step", exc=e)
             return self._drain_finished()
+        self._count_decode_dispatch()
         if clk is not None:
-            clk.lap("dispatch")
+            clk.open("sync")
         # THE deliberate device->host sync of the speculative decode
         # loop: one dispatch produced all three arrays, the first
         # conversion blocks, the other two read already-fetched results
@@ -2009,7 +2062,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         n_row = np.asarray(n_emit)   # pdlint: disable=host-sync -- same dispatch as toks; variable per-slot advance drives host bookkeeping
         lps = np.asarray(logps)      # pdlint: disable=host-sync -- same dispatch as toks; the OpenAI logprobs field
         if clk is not None:
-            clk.lap("sync")
+            clk.open("retire")
         self._clear_dispatch_guard()  # step success: blame record erased
         now = time.perf_counter()
         self._m_step.observe(now - t_dispatch)
@@ -2131,10 +2184,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         if first_exc is not None:
             raise first_exc
         if clk is not None:
-            clk.lap("retire")
+            clk.open("admit")  # trailing refill accumulates into admit
         self._admit()
         if clk is not None:
-            clk.lap("admit")   # trailing refill accumulates into admit
+            clk.close()
             self.profiler.commit(
                 active=int(active.sum()),
                 kv_len=max((int(r.ids.size) + len(r.tokens)
@@ -2589,7 +2642,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         n_pages = bucket // ps
 
         def build():
-            def scatter(pages, bufs, base):
+            def kv_scatter(pages, bufs, base):
                 out = []
                 for (kp, vp), c_new in zip(pages, bufs):
                     new = []
@@ -2601,7 +2654,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                     out.append(tuple(new))
                 return out
 
-            fn = jax.jit(scatter, donate_argnums=(0,))
+            fn = jax.jit(kv_scatter, donate_argnums=(0,))
             fn._state = None  # _memoized_step refresh hook (stateless)
             return fn
 
@@ -2621,12 +2674,12 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         n_feats = int(px_shape[0]) * model.features_per_image()
 
         def build():
-            def pure(state, ids, pixels):
+            def multimodal_merge(state, ids, pixels):
                 with _functional_weights(model, state), _tape.no_grad():
                     return unwrap(model.merge_multimodal(
                         wrap(ids), wrap(pixels), n_feats=n_feats))
 
-            fn = jax.jit(pure)
+            fn = jax.jit(multimodal_merge)
             step = lambda ids, pixels: fn(step._state, ids, pixels)
             step._state = dict(model.functional_state())
             return step
@@ -2683,8 +2736,8 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         rope_len = self.max_len
 
         def build():
-            def run(state, pages, suffix_ids, suffix_len, src_base,
-                    dst_base):
+            def prefill_with_prefix(state, pages, suffix_ids, suffix_len,
+                                    src_base, dst_base):
                 with functional_weights(model, state), _tape2.no_grad():
                     caches = []
                     pref_tiles = []
@@ -2739,7 +2792,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                         new_pages.append(tuple(out_pair))
                 return last, new_pages
 
-            fn = jax.jit(run, donate_argnums=(1,))
+            fn = jax.jit(prefill_with_prefix, donate_argnums=(1,))
             fn._state = None  # _memoized_step refresh hook (state is an arg)
             return fn
 
@@ -2775,7 +2828,8 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         rope_len = self.max_len
 
         def build():
-            def run(state, bufs, suffix_ids, suffix_len, src, dst):
+            def prefill_with_prefix_latent(state, bufs, suffix_ids,
+                                           suffix_len, src, dst):
                 with functional_weights(model, state), _tape2.no_grad():
                     caches = []
                     for ckv, kpe in bufs:
@@ -2818,7 +2872,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                         ))
                 return last, new_bufs
 
-            fn = jax.jit(run, donate_argnums=(1,))
+            fn = jax.jit(prefill_with_prefix_latent, donate_argnums=(1,))
             fn._state = None  # _memoized_step refresh hook (state is an arg)
             return fn
 
@@ -2852,12 +2906,14 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             buf_keys, idx_scale = ("k_pages", "v_pages"), self._pages_per_slot
             poison_what = "page pool"
         bufs = [tuple(c[k] for k in buf_keys) for c in self._caches]
+        self._count_prefill(int(suf.size), sb)
         try:
-            last, new_bufs = fn(
-                dict(self.model.functional_state()), bufs,
-                jnp.asarray(ids), jnp.asarray(int(suf.size), jnp.int32),
-                jnp.asarray(src * idx_scale, jnp.int32),
-                jnp.asarray(slot * idx_scale, jnp.int32))
+            with self._dispatch_span():
+                last, new_bufs = fn(
+                    dict(self.model.functional_state()), bufs,
+                    jnp.asarray(ids), jnp.asarray(int(suf.size), jnp.int32),
+                    jnp.asarray(src * idx_scale, jnp.int32),
+                    jnp.asarray(slot * idx_scale, jnp.int32))
         except Exception as e:
             self._poisoned = True
             raise RuntimeError(
@@ -2891,7 +2947,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         rows into a slot's row across all layers (the latent-mode analog of
         _scatter_fn)."""
         def build():
-            def scatter(bufs, prefill, slot):
+            def kv_scatter_latent(bufs, prefill, slot):
                 out = []
                 for (ckv, kpe), c_new in zip(bufs, prefill):
                     out.append((
@@ -2904,7 +2960,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                     ))
                 return out
 
-            fn = jax.jit(scatter, donate_argnums=(0,))
+            fn = jax.jit(kv_scatter_latent, donate_argnums=(0,))
             fn._state = None  # _memoized_step refresh hook (stateless)
             return fn
 
@@ -2918,9 +2974,16 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         S0 = int(req.ids.size)
         bucket = self._bucket(S0)
         ragged = S0 != bucket
-        pad_mask = None
-        if ragged:
-            pad_mask = jnp.zeros((1, bucket), bool).at[0, :S0].set(True)
+        self._count_prefill(S0, bucket)
+
+        def run(prefill, inputs):
+            with self._dispatch_span():
+                lengths = jnp.asarray([S0], jnp.int32)
+                # one mask program per BUCKET: the length is traced
+                pad_mask = _prefill_mask(lengths, bucket) if ragged \
+                    else None
+                return prefill(jnp.asarray(inputs), lengths, pad_mask)
+
         if req.pixel_values is not None:
             # multimodal admission: ONE jitted merge (vision tower +
             # projector + placeholder scatter — eager would pay a device
@@ -2941,8 +3004,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                                merged.dtype).at[:, :S0].set(merged)
             prefill = _get_prefill_step_embeds(self.model, bucket, ragged,
                                                rope_len=self.max_len)
-            last, caches = prefill(embeds, jnp.asarray([S0], jnp.int32),
-                                   pad_mask)
+            last, caches = run(prefill, embeds)
             return last, caches, S0, bucket
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :S0] = req.ids
@@ -2950,8 +3012,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         # regimes (longrope) agree between this prefill and the decode step
         prefill = _get_prefill_step(self.model, bucket, ragged,
                                     rope_len=self.max_len)
-        last, caches = prefill(jnp.asarray(ids),
-                               jnp.asarray([S0], jnp.int32), pad_mask)
+        last, caches = run(prefill, ids)
         return last, caches, S0, bucket
 
     def _prefill_into_latent(self, slot: int, req: _Request):
@@ -2995,8 +3056,9 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         if self._latent_mode:
             bufs = [(c["c_kv"], c["k_pe"]) for c in self._caches]
             try:
-                new_bufs = self._latent_scatter_fn(bucket)(
-                    bufs, caches, jnp.asarray(slot, jnp.int32))
+                with self._dispatch_span():
+                    new_bufs = self._latent_scatter_fn(bucket)(
+                        bufs, caches, jnp.asarray(slot, jnp.int32))
             except Exception as e:
                 self._poisoned = True
                 raise RuntimeError(
@@ -3010,8 +3072,9 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             base = slot * self._pages_per_slot
             pages = [(c["k_pages"], c["v_pages"]) for c in self._caches]
             try:
-                new_pages = self._scatter_fn(bucket)(
-                    pages, caches, jnp.asarray(base, jnp.int32))
+                with self._dispatch_span():
+                    new_pages = self._scatter_fn(bucket)(
+                        pages, caches, jnp.asarray(base, jnp.int32))
             except Exception as e:
                 # the scatter DONATES the page pool: a mid-admission
                 # failure (device OOM etc.) may have invalidated it,
@@ -3233,7 +3296,8 @@ class Seq2SeqBatchEngine(_RequestBookkeeping):
             from .generation import _functional_weights, sample_logits
             from .tensor_class import wrap
 
-            def pure(state, last, key, sk, sv, ck, cv, enc_mask, lengths):
+            def seq2seq_decode_step(state, last, key, sk, sv, ck, cv,
+                                    enc_mask, lengths):
                 with _functional_weights(model, state), _tape.no_grad():
                     nxt = sample_logits(last, key, do_sample=do_sample,
                                         temperature=temperature,
@@ -3251,7 +3315,7 @@ class Seq2SeqBatchEngine(_RequestBookkeeping):
                         [c["k"] for c in new_self],
                         [c["v"] for c in new_self])
 
-            fn = jax.jit(pure, donate_argnums=(3, 4))
+            fn = jax.jit(seq2seq_decode_step, donate_argnums=(3, 4))
             step = lambda *a: fn(step._state, *a)
             step._state = dict(model.functional_state())
             return step
@@ -3264,29 +3328,31 @@ class Seq2SeqBatchEngine(_RequestBookkeeping):
     def step(self) -> Dict[int, np.ndarray]:
         """Decode ONE token for every active slot (one fused dispatch);
         returns newly finished requests {rid: generated ids}."""
-        # step-anatomy clock (guarded fast path, same as the decoder
-        # engine); the encoder+seed prefill inside _admit IS this
-        # engine's admission prefill, so it attributes to "admit"
-        prof = self.profiler
-        clk = prof.clock if prof.enabled else None
+        return self._profiled_step()
+
+    def _step_decode(self, clk) -> Dict[int, np.ndarray]:
+        # the encoder+seed prefill inside _admit IS this engine's
+        # admission prefill, so it attributes to "admit"
         if clk is not None:
-            clk.begin()
+            clk.open("admit")
         self._admit()
         if clk is not None:
-            clk.lap("admit")
+            clk.close()
         if self.num_active == 0:
             return self._drain()
+        if clk is not None:
+            clk.open("dispatch")
         t_dispatch = time.perf_counter()
         step = self._step_fn()
         nxt, self._last, self._self_k, self._self_v = step(
             self._last, _random.next_key(), self._self_k, self._self_v,
             self._cross_k, self._cross_v, self._enc_mask, self._lengths)
         if clk is not None:
-            clk.lap("dispatch")
+            clk.open("sync")
         # the seq2seq step's one deliberate device->host sync
         toks = np.asarray(nxt)    # pdlint: disable=host-sync
         if clk is not None:
-            clk.lap("sync")
+            clk.open("retire")
         now = time.perf_counter()
         self._m_step.observe(now - t_dispatch)
         self._n_steps += 1
@@ -3321,9 +3387,9 @@ class Seq2SeqBatchEngine(_RequestBookkeeping):
                 self._release_slot(s)
                 self._trace_end(req, "ok")
         if clk is not None:
-            clk.lap("retire")
+            clk.open("admit")  # trailing refill accumulates into admit
         self._admit()
         if clk is not None:
-            clk.lap("admit")   # trailing refill accumulates into admit
-            prof.commit(active=int(active.sum()), fr_seq=fr_seq)
+            clk.close()
+            self.profiler.commit(active=int(active.sum()), fr_seq=fr_seq)
         return self._drain()

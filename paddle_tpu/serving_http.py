@@ -708,17 +708,22 @@ class CompletionServer:
 
     def _engine_loop_inner(self):
         eng = self.engine
+        # annotation-only spans on the profiler's clock (the step
+        # profiler's guard: one attribute read while it is off)
+        prof = getattr(eng, "profiler", None)
+        span = prof.span if prof is not None else (lambda _: _perf.NO_SPAN)
         while not self._stop.is_set():
             # drain submissions (engine thread is the ONLY device-state
             # toucher)
             drained = False
-            while True:
-                try:
-                    sub = self._subs.get_nowait()
-                except queue.Empty:
-                    break
-                drained = True
-                self._handle_submission(sub)
+            with span("submissions"):
+                while True:
+                    try:
+                        sub = self._subs.get_nowait()
+                    except queue.Empty:
+                        break
+                    drained = True
+                    self._handle_submission(sub)
             if (eng.num_active or getattr(eng, "_queue", None)
                     or getattr(eng, "_chunking", None)):
                 try:
@@ -734,9 +739,12 @@ class CompletionServer:
                 # DIRECTLY — re-enqueueing at the tail would let a
                 # steady trickle of newer submissions starve it
                 try:
-                    self._handle_submission(self._subs.get(timeout=0.05))
+                    with span("idle_wait"):
+                        sub = self._subs.get(timeout=0.05)
                 except queue.Empty:
-                    pass
+                    continue
+                with span("submissions"):
+                    self._handle_submission(sub)
 
     # ---- handler hooks --------------------------------------------------
     def _make_handler(server_self):

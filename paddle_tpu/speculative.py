@@ -94,7 +94,7 @@ class _ProposeStep:
     def __init__(self, model, max_len, k, seed_len):
         self._model = model
 
-        def pure(state, seed, bufs, aux):
+        def spec_propose(state, seed, bufs, aux):
             caches = [{**b, **a} for b, a in zip(bufs, aux)]
             with _functional_weights(model, state), _tape.no_grad():
                 hidden, caches = model.llama.forward_cached(
@@ -122,7 +122,7 @@ class _ProposeStep:
             nb, na = _split_caches(_unwrap_caches(caches))
             return toks.T, nb, na  # [B, k]
 
-        self._jitted = jax.jit(pure, donate_argnums=(2,))
+        self._jitted = jax.jit(spec_propose, donate_argnums=(2,))
         self._state = dict(model.functional_state())
 
     def __call__(self, seed, caches):
@@ -138,7 +138,7 @@ class _VerifyStep:
     def __init__(self, model, max_len, chunk_len):
         self._model = model
 
-        def pure(state, chunk, bufs, aux):
+        def spec_verify_solo(state, chunk, bufs, aux):
             caches = [{**b, **a} for b, a in zip(bufs, aux)]
             with _functional_weights(model, state), _tape.no_grad():
                 hidden, caches = model.llama.forward_cached(
@@ -148,7 +148,7 @@ class _VerifyStep:
             nb, na = _split_caches(_unwrap_caches(caches))
             return greedy, nb, na  # [B, chunk_len]
 
-        self._jitted = jax.jit(pure, donate_argnums=(2,))
+        self._jitted = jax.jit(spec_verify_solo, donate_argnums=(2,))
         self._state = dict(model.functional_state())
 
     def __call__(self, chunk, caches):
@@ -304,7 +304,7 @@ class _MTPRoundStep:
         self._model = model
         mtp = model.mtp_layers[0]
 
-        def pure(state, h_tail, toks, bufs, aux, mbufs, maux):
+        def mtp_round(state, h_tail, toks, bufs, aux, mbufs, maux):
             caches = [{**b, **a} for b, a in zip(bufs, aux)]
             mtp_cache = {**mbufs[0], **maux[0]}
             with _functional_weights(model, state), _tape.no_grad():
@@ -326,7 +326,7 @@ class _MTPRoundStep:
             mb, ma = _split_caches(_unwrap_caches([mtp_cache]))
             return jnp.stack([g0, g1, draft]), unwrap(pre2), nb, na, mb, ma
 
-        self._jitted = jax.jit(pure, donate_argnums=(3, 5))
+        self._jitted = jax.jit(mtp_round, donate_argnums=(3, 5))
         self._state = dict(model.functional_state())
 
     def __call__(self, h_tail, toks, caches, mtp_caches):
