@@ -85,9 +85,9 @@ SIZES = {
 MAIN_PATH = {
     "train": ("flash_attention", "fused_rope", "rms_norm", "add_rms_norm"),
     "serve": ("flash_attention", "append_attention", "paged_attention",
-              "rms_norm", "add_rms_norm"),
-    "cluster": ("append_attention", "paged_attention", "rms_norm",
-                "add_rms_norm"),
+              "kv_page_write", "rms_norm", "add_rms_norm"),
+    "cluster": ("append_attention", "paged_attention", "kv_page_write",
+                "rms_norm", "add_rms_norm"),
     "mesh": ("flash_attention", "fused_rope", "rms_norm", "add_rms_norm"),
 }   # mesh: the ONE-CHIP reference; the hybrid step is checked apart
 

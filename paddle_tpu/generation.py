@@ -29,6 +29,7 @@ import numpy as np
 from .tensor_class import Tensor, unwrap, wrap
 from .ops.registry import apply
 from .ops.pallas import backend as _pallas_backend
+from .ops.pallas import kv_page_write as _kv_page_write
 from .autograd import tape as _tape
 from .framework import random as _random
 from .nn.layer import functional_weights as _functional_weights
@@ -193,10 +194,8 @@ def paged_cached_attention(q, k, v, cos, sin, k_pages, v_pages, page_indices,
         page = lengths // page_size                 # [B]
         slot = lengths % page_size                  # [B]
         rows = page_indices[jnp.arange(B), page]    # [B]
-        k_pages = k_pages.at[:, rows, slot].set(
-            jnp.moveaxis(k[:, 0], 0, 1).astype(k_pages.dtype))
-        v_pages = v_pages.at[:, rows, slot].set(
-            jnp.moveaxis(v[:, 0], 0, 1).astype(v_pages.dtype))
+        k_pages = _write_decode_rows(k_pages, rows, slot, k[:, 0])
+        v_pages = _write_decode_rows(v_pages, rows, slot, v[:, 0])
         out = paged_decode_attention(q[:, 0], k_pages, v_pages, lengths + 1,
                                      page_indices, window=window,
                                      softcap=softcap)
@@ -217,6 +216,18 @@ def paged_cached_attention(q, k, v, cos, sin, k_pages, v_pages, page_indices,
     out = _paged_chunk_attention(q, k_pages, v_pages, lengths, page_indices,
                                  window=window, softcap=softcap)
     return out, k_pages, v_pages
+
+
+def _write_decode_rows(pages, rows, slot, new):
+    """One decode step's new rows ``new`` [B,hk,D] into the pool
+    [hk,n_pages,page_size,D] at (rows[b], slot[b]). On a TPU the Pallas
+    page write, which leaves the pool in its own layout; the XLA scatter
+    (two relayouts of the whole pool on a TPU: docs/SERVING.md) is the
+    reference elsewhere and where the gate refuses."""
+    if _kv_page_write.supported(pages, new):
+        return _kv_page_write.kv_page_write(pages, rows, slot, new)
+    return pages.at[:, rows, slot].set(
+        jnp.moveaxis(new, 0, 1).astype(pages.dtype))
 
 
 def _paged_chunk_attention(q, k_pages, v_pages, lengths, page_indices,

@@ -15,6 +15,7 @@ relies on (``jax.core.trace_state_clean`` is gone from jax 0.9.0 and its
 four users swallowed the AttributeError).
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs: not /tmp
 
@@ -149,11 +150,9 @@ KERNELS = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(KERNELS))
-def test_kernel_compiles_for_v5e(chip, name):
-    fn, specs = KERNELS[name]
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-            for shape, dtype in specs]
+def _lower_for_chip(fn, args, donate=()):
+    """Optimized HLO of ``fn`` compiled for the described chip, with the
+    record of kernel paths reset first."""
     backend.reset_paths()
     # conftest asks for "highest" matmul precision (numpy-parity tests);
     # the chip runs the default, and Mosaic refuses an fp32 contraction
@@ -162,13 +161,88 @@ def test_kernel_compiles_for_v5e(chip, name):
             jax.default_matmul_precision("default"):
         # a fresh jit per compile: traces are cached per function, and one
         # made for the CPU carries interpret=True
-        compiled = jax.jit(lambda *a: fn(*a)).lower(*args).compile()
+        return jax.jit(lambda *a: fn(*a), donate_argnums=donate).lower(
+            *args).compile().as_text()
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, specs = KERNELS[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in specs]
     # the Mosaic kernel is in the program — not the interpreter's XLA ops,
     # not a composite a gate fell back to
-    assert "tpu_custom_call" in compiled.as_text(), name
+    assert "tpu_custom_call" in _lower_for_chip(fn, args), name
     took = backend.paths()
     assert not any(backend.INTERPRET in impls or backend.XLA in impls
                    for impls in took.values()), took
+
+
+# the chat cell's decode step (benchmarks/configs/mistral-7b-v0.3-d20):
+# 8 KV heads, 2048 pages of 16 (batch 16 x max_len 2048), 32 query heads
+POOL_PAGES, CHAT_B = 2048, 16
+
+
+def _pool_copies(hlo, pool_shape):
+    """HLO lines whose RESULT is a ``copy`` of the pool's shape: the
+    relayout XLA wraps around a scatter into a pool on a TPU."""
+    dims = ",".join(map(str, pool_shape))
+    return [line.strip()[:160] for line in hlo.splitlines()
+            if re.search(r"= \w+\[%s\]\S* copy\(" % dims, line)]
+
+
+@pytest.mark.parametrize("d,dtype", [(128, BF16), (128, F32), (256, BF16)],
+                         ids=["d128-bf16", "d128-f32", "d256-bf16"])
+def test_decode_write_leaves_no_copy_of_a_pool(chip, d, dtype):
+    """``paged_cached_attention`` at S == 1 with both pools donated: the
+    pool goes parameter -> kv_page_write -> paged_attention -> result in
+    ONE layout. With the XLA scatter in its place the same program held
+    four ``copy`` ops of the pool's shape (67 MB each at d128-bf16), 46%
+    of the chat cell's busy time on the chip (PERF.md, PR 28)."""
+    from paddle_tpu.generation import paged_cached_attention
+
+    pool = (HK, POOL_PAGES, PAGE, d)
+    pages_per_row = POOL_PAGES // CHAT_B
+    specs = [((CHAT_B, 1, H, d), dtype), ((CHAT_B, 1, HK, d), dtype),
+             ((CHAT_B, 1, HK, d), dtype),
+             ((pages_per_row * PAGE, d), F32),
+             ((pages_per_row * PAGE, d), F32),
+             (pool, dtype), (pool, dtype),
+             ((CHAT_B, pages_per_row), I32), ((CHAT_B,), I32)]
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+            for shape, dt in specs]
+    hlo = _lower_for_chip(
+        lambda *a: paged_cached_attention(*a, PAGE), args, donate=(5, 6))
+    assert _pool_copies(hlo, pool) == []
+    # donation reached the compiled program: results 1 and 2 ARE
+    # parameters 5 and 6
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert re.findall(r"\{(\d)\}: \((\d),", alias) == [("1", "5"),
+                                                         ("2", "6")]
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and "= " in line]
+    assert sum("%kv_page_write" in c.split("=")[0] for c in calls) == 2
+    assert sum("%paged_attention" in c.split("=")[0] for c in calls) == 1
+    assert backend.paths() == {"kv_page_write": {backend.PALLAS: 2},
+                               "paged_attention": {backend.PALLAS: 1}}
+
+
+@pytest.mark.parametrize("d", [64, 96, 192])
+def test_decode_write_falls_back_at_a_width_that_would_copy(chip, d):
+    """At a head width that is not whole lanes Mosaic compiles the page
+    write, but XLA wraps it in two copies of the pool (described compile,
+    PR 28): the gate refuses, the scatter stays and still compiles."""
+    from paddle_tpu.generation import _write_decode_rows
+
+    pool = (HK, POOL_PAGES, PAGE, d)
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+            for shape, dt in [(pool, BF16), ((CHAT_B,), I32),
+                              ((CHAT_B,), I32), ((CHAT_B, HK, d), BF16)]]
+    hlo = _lower_for_chip(_write_decode_rows, args, donate=(0,))
+    assert "kv_page_write" not in hlo and "tpu_custom_call" not in hlo
+    assert backend.paths() == {"kv_page_write": {backend.XLA: 1}}
+    (reason,) = backend.refusals()["kv_page_write"]
+    assert "128 lanes" in reason
 
 
 def test_backend_target_decides_interpret_not_the_argument():

@@ -317,7 +317,7 @@ def _pallas_names(jaxpr, out):
 
 def _kernel_calls():
     from paddle_tpu.ops.pallas import (append_attention, decode_tail,
-                                       fused_norm, mla_decode)
+                                       fused_norm, kv_page_write, mla_decode)
 
     b, hid, h, hk, d, t = 8, 256, 2, 1, 128, 128
     z = lambda *shape, dtype=F32: jnp.zeros(shape, dtype)  # noqa: E731
@@ -345,12 +345,17 @@ def _kernel_calls():
             mla_decode.mla_decode_attention,
             (z(b, h, 128), z(b, h, 128), z(b, t, 128), z(b, t, 128),
              jnp.zeros((b,), jnp.int32), jnp.ones((b, t), bool))),
+        "kv_page_write": (
+            kv_page_write.kv_page_write,
+            (z(hk, b, 16, d), jnp.arange(b, dtype=jnp.int32),
+             jnp.zeros((b,), jnp.int32), z(b, hk, d))),
     }
 
 
 @pytest.mark.parametrize("kernel", [
     "rms_norm", "add_rms_norm", "fused_rope", "append_attention",
-    "decode_tail_qkv_rope", "decode_tail_epilogue", "mla_decode"])
+    "decode_tail_qkv_rope", "decode_tail_epilogue", "mla_decode",
+    "kv_page_write"])
 def test_every_pallas_call_carries_its_kernel_name(kernel):
     from paddle_tpu.ops.pallas import backend
 
