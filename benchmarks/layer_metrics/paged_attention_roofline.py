@@ -1,9 +1,9 @@
 """The bundled paged decode attention's share of its roofline, from the
 traced slice: one call per layer per decode step; what it must move is
-every cached K and V row of the batch once (``reference/decoder.py``
-``paged_decode_cost``), so it is memory-bound. The cached tokens the
-batch held come from the clients' outcomes: each request's context while
-it decoded inside the slice, averaged over the slice."""
+every cached K and V row of the batch once (the ``paged_decode_cost`` of
+the cell's own reference module), so it is memory-bound. The cached
+tokens the batch held come from the clients' outcomes: each request's
+context while it decoded inside the slice, averaged over the slice."""
 LAYER = "ops/pallas + bundled splash / paged kernels"
 UNIT = "%"
 MOVES = "itl_p95_ms"
@@ -32,9 +32,9 @@ def mean_context_tokens(ctx):
 
 
 def read(ctx):
-    from benchmarks.lib.common import note
+    from benchmarks.lib.common import note, reference_function
     from benchmarks.lib.reduce_trace import kernel_seconds
-    from benchmarks.reference import decoder
+    from benchmarks.reference._costs import roofline_seconds
 
     trace, peaks = ctx.get("trace"), ctx.get("peaks")
     if not trace or not peaks or not ctx.get("trace_window"):
@@ -42,10 +42,13 @@ def read(ctx):
     seconds, calls = kernel_seconds(trace, "paged_attention")
     if seconds <= 0:
         return None
-    spec = decoder.Spec.from_config(ctx["config"])
+    decode_cost = reference_function(ctx, "paged_attention_roofline",
+                                     "paged_decode_cost")
+    if decode_cost is None:
+        return None
     tokens, rows = mean_context_tokens(ctx)
-    cost = decoder.paged_decode_cost(spec, tokens, rows)
-    least, bound = decoder.roofline_seconds(cost, peaks)
+    cost = decode_cost(ctx["spec"], tokens, rows)
+    least, bound = roofline_seconds(cost, peaks)
     note("roofline", kernel="paged_attention", bound=bound, calls=calls,
          kernel_s_per_call=seconds / calls, least_s_per_call=least,
          mean_cached_tokens=tokens, mean_rows=rows, **cost)

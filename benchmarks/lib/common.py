@@ -35,7 +35,8 @@ def note(_note: str, **fields) -> None:
 
 
 def load_module(path: str, name: str):
-    """Import one file of the benchmark by path (readers, traffic kinds)."""
+    """Import one file of the benchmark by path (readers, traffic kinds,
+    references)."""
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
@@ -88,6 +89,32 @@ class Cell:
     def reader(self, metric: str):
         path = os.path.join(self.bench_dir, "layer_metrics", f"{metric}.py")
         return load_module(path, f"bench_layer_metric_{metric}")
+
+    def reference(self):
+        """The plain reference this cell's configuration names: the file
+        ``reference/<module>.py`` (its contract is in
+        ``reference/__init__.py``). A configuration that names none is an
+        error, never a default."""
+        module = (self.config.get("reference") or {}).get("module")
+        if not module:
+            raise SystemExit(
+                f"benchmark: {self.config_entry['file']} names no reference "
+                f"(\"reference\": {{\"module\": <a file's stem under "
+                f"benchmarks/reference/>}})")
+        return load_module(os.path.join(self.bench_dir, "reference",
+                                        f"{module}.py"),
+                           f"bench_reference_{module}")
+
+
+def reference_function(ctx: dict, metric: str, name: str):
+    """The function ``name`` of the cell's own reference module, for the
+    reader of ``metric``; None, with a note, where that module has none.
+    A reader never borrows another configuration's arithmetic."""
+    fn = getattr(ctx.get("reference"), name, None)
+    if fn is None:
+        note("reader_skipped", metric=metric, lacks=name,
+             reference=getattr(ctx.get("reference"), "__name__", None))
+    return fn
 
 
 def rehearsed(section: dict, rehearse: bool) -> dict:
@@ -232,9 +259,11 @@ def start_jax(cell: Cell, rehearse: bool):
 
 
 def result_line(*, correct: bool, attempted: int, failed: int, metrics: dict,
-                units: dict, device: dict, breakdown=None) -> dict:
+                units: dict, device: dict, breakdown=None,
+                compared=None) -> dict:
     """The contract's last line. ``metrics`` maps a name to a number (or
-    None: left out)."""
+    None: left out); ``compared`` maps each number the comparison with
+    the reference read to ``{"value", "limit"}`` and comes last."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed),
             "metrics": {k: {"value": float(v), "unit": units[k]}
@@ -242,4 +271,5 @@ def result_line(*, correct: bool, attempted: int, failed: int, metrics: dict,
             "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = compared or {}
     return line

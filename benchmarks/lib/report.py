@@ -43,7 +43,8 @@ def finish(cell: Cell, *, trace: bool, rehearse: bool, device: dict,
              per_layer_names=sorted(read_layer_metrics(cell, ctx))
              if trace else [])
         return result_line(correct=False, attempted=attempted, failed=failed,
-                           metrics={}, units={}, device=device)
+                           metrics={}, units={}, device=device,
+                           compared=ctx.get("compared"))
     if peak is not None:
         end_to_end = dict(end_to_end, peak_hbm_gib=peak / 2.0 ** 30)
     group = "per_layer" if trace else "end_to_end"
@@ -58,4 +59,4 @@ def finish(cell: Cell, *, trace: bool, rehearse: bool, device: dict,
         breakdown = reduced.get("breakdown")
     return result_line(correct=correct, attempted=attempted, failed=failed,
                        metrics=metrics, units=wanted, device=device,
-                       breakdown=breakdown)
+                       breakdown=breakdown, compared=ctx.get("compared"))

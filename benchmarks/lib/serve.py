@@ -73,6 +73,38 @@ def parse_metrics(text: str) -> dict:
     return out
 
 
+def compare_logprobs(reference, spec, state: dict, prompts: list,
+                     answers: list) -> tuple:
+    """The engine's logprobs for up to four of ``prompts`` (spread over
+    the length grid they are sorted by) x ``CHECK_TOKENS`` tokens against
+    the plain float32 ``reference`` module, teacher-forced on the engine's
+    own tokens: logprobs, not tokens, since with random weights the top
+    logit changes on rounding. ``answers[i]`` is ``complete()``'s reply
+    to ``prompts[i]``. Returns (all within ``LOGPROB_TOL``, the worst
+    error, one row per compared prompt)."""
+    n = len(prompts)
+    picks = sorted({round(i * (n - 1) / 3) for i in range(4)} if n > 4
+                   else set(range(n)))
+    rows, worst = [], 0.0
+    for i in picks:
+        prompt, ans = prompts[i], answers[i]
+        toks = ans["token_ids"]
+        lp = np.asarray(reference.forward_logprobs(
+            spec, state, prompt + toks[:-1], last=len(toks)))
+        ref = lp[np.arange(len(toks)), np.asarray(toks)]
+        err = float(np.max(np.abs(ref - np.asarray(ans["logprobs"]))))
+        ok = (len(toks) == CHECK_TOKENS and np.isfinite(err)
+              and err <= LOGPROB_TOL)
+        rows.append({"prompt_tokens": len(prompt), "ok": bool(ok),
+                     "max_abs_logprob_err": err,
+                     "engine_logprobs": [round(float(v), 4)
+                                         for v in ans["logprobs"]],
+                     "reference_logprobs": [round(float(v), 4)
+                                            for v in ref]})
+        worst = max(worst, err)
+    return all(r["ok"] for r in rows), worst, rows
+
+
 class ServeRun:
     """One serving cell's run, from the process's start to its outcomes."""
 
@@ -83,6 +115,9 @@ class ServeRun:
         self.cfg = rehearsed(cell.config, self.rehearse)
         self.traffic = rehearsed(cell.traffic, self.rehearse)
         self.engine_args = dict(self.cfg["recipe"]["engine"])
+        self.reference = cell.reference()
+        self.spec = self.reference.Spec.from_config(self.cfg)
+        self.compared = {}
         self.server = None
 
     # ---- set-up ---------------------------------------------------------
@@ -145,39 +180,20 @@ class ServeRun:
              passes=passes, **self.meter.report())
 
     def check(self) -> bool:
-        """The engine's logprobs for up to four of the warm-up prompts
-        (spread over the buckets) x 8 tokens against the plain float32
-        reference, teacher-forced on the engine's own tokens: logprobs,
-        not tokens, since with random weights the top logit changes on
-        rounding."""
-        from ..reference import decoder
-
-        spec = decoder.Spec.from_config(self.cfg)
-        state = build.plain_state(self.model)
-        n = len(self.warm_prompts)
-        picks = sorted({round(i * (n - 1) / 3) for i in range(4)} if n > 4
-                       else set(range(n)))
-        rows, worst = [], 0.0
-        for i in picks:
-            prompt, ans = self.warm_prompts[i], self.warm_answers[i]
-            toks = ans["token_ids"]
-            lp = np.asarray(decoder.forward_logprobs(
-                spec, state, prompt + toks[:-1], last=len(toks)))
-            ref = lp[np.arange(len(toks)), np.asarray(toks)]
-            err = float(np.max(np.abs(ref - np.asarray(ans["logprobs"]))))
-            ok = (len(toks) == CHECK_TOKENS and np.isfinite(err)
-                  and err <= LOGPROB_TOL)
-            rows.append({"prompt_tokens": len(prompt), "ok": bool(ok),
-                         "max_abs_logprob_err": err,
-                         "engine_logprobs": [round(float(v), 4)
-                                             for v in ans["logprobs"]],
-                         "reference_logprobs": [round(float(v), 4)
-                                                for v in ref]})
-            worst = max(worst, err)
+        """The engine's answers to the warm-up prompts against the plain
+        reference the configuration names (``compare_logprobs``)."""
+        ok, worst, rows = compare_logprobs(
+            self.reference, self.spec, build.plain_state(self.model),
+            self.warm_prompts, self.warm_answers)
         self.clock.lap("reference_check_s")
-        note("reference_check", tolerance=LOGPROB_TOL, worst=worst,
-             prompts=rows)
-        return all(r["ok"] for r in rows)
+        # a prompt also fails by a short answer or an error that is no number
+        failed = sum(not r["ok"] for r in rows)
+        self.compared = {
+            "logprob_err_nats": {"value": worst, "limit": LOGPROB_TOL},
+            "prompts_failed": {"value": failed, "limit": 0}}
+        note("reference_check", reference=self.reference.__name__,
+             tolerance=LOGPROB_TOL, worst=worst, prompts=rows)
+        return ok
 
     # ---- the window -----------------------------------------------------
     def snapshot(self) -> dict:
@@ -246,6 +262,8 @@ class ServeRun:
         return {
             "cell": cell.name, "kind": traffic["kind"], "seconds": seconds,
             "chips": cell.chips, "config": self.cfg, "traffic": traffic,
+            "reference": self.reference, "spec": self.spec,
+            "compared": self.compared,
             "engine_args": self.engine_args, "peaks": self.peaks,
             "digest": result["digest"], "outcomes": result["outcomes"],
             "before": before, "after": after, "health": health,

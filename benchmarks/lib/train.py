@@ -32,6 +32,9 @@ class TrainRun:
         self.job = rehearsed(cell.traffic, self.rehearse)
         self.batch, self.seq = int(self.job["batch"]), int(self.job["seq_len"])
         self.tokens_per_step = self.batch * self.seq
+        self.reference = cell.reference()
+        self.spec = self.reference.Spec.from_config(self.cfg)
+        self.compared = {}
 
     # ---- set-up ---------------------------------------------------------
     def setup(self) -> None:
@@ -99,15 +102,13 @@ class TrainRun:
         warm-up steps."""
         import jax
 
-        from ..reference import decoder
-
-        spec = decoder.Spec.from_config(self.cfg)
         ids = self.make_batch(0)
         dev0 = jax.devices()[0]
         state = {k: jax.device_put(v, dev0)
                  for k, v in build.plain_state(self.model).items()}
-        ref = float(np.mean([decoder.next_token_loss(spec, state, row)
-                             for row in ids]))
+        ref = float(np.mean([
+            self.reference.next_token_loss(self.spec, state, row)
+            for row in ids]))
         del state
         self.clock.lap("reference_check_s")
         first = float(self.step(*self._tensors(ids)).numpy())
@@ -118,7 +119,10 @@ class TrainRun:
         self.clock.lap("warmup_s")
         err = abs(first - ref)
         ok = bool(np.isfinite(first) and err <= LOSS_TOL)
-        note("reference_check", tolerance=LOSS_TOL, step_loss=first,
+        self.compared = {"first_loss_abs_err": {"value": err,
+                                                "limit": LOSS_TOL}}
+        note("reference_check", reference=self.reference.__name__,
+             tolerance=LOSS_TOL, step_loss=first,
              reference_loss=ref, abs_err=err, ok=ok,
              ln_vocab=float(np.log(self.cfg["vocab_size"])))
         note("warmup", steps=int(self.job["warmup_steps"]),
@@ -183,13 +187,12 @@ class TrainRun:
         step_s = list(np.diff([t0] + done_at))
         note("setup_split", setup_s=self.setup_s, **self.clock.parts,
              **self.meter.report())
-        from ..reference import decoder
-
         return {
             "cell": self.cell.name, "kind": "train_job", "seconds": seconds,
             "elapsed_s": elapsed, "chips": self.cell.chips,
             "config": self.cfg, "traffic": self.job, "peaks": self.peaks,
-            "spec": decoder.Spec.from_config(self.cfg),
+            "reference": self.reference, "spec": self.spec,
+            "compared": self.compared,
             "batch": self.batch, "seq_len": self.seq,
             "tokens_per_step": self.tokens_per_step,
             "tokens_per_s": steps * self.tokens_per_step / elapsed,

@@ -1,7 +1,8 @@
 """The plain reference: one float32 decoder forward in straightforward
 ``jax.numpy`` that covers both families of the benchmark by what the
 configuration file states, and the arithmetic of operations and bytes
-that ``train_mfu`` and ``attn_roofline_share`` divide by.
+that ``train_mfu``, ``serve_mfu`` and the attention rooflines divide by
+(``reference/__init__.py`` has the contract of such a module).
 
 No kernels, no cache, no batching. It follows the published descriptions:
 
@@ -27,6 +28,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.reference._costs import roofline_seconds  # noqa: F401
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -195,6 +198,19 @@ def train_flops_per_token(spec: Spec, seq: int) -> float:
     return 6.0 * matmul_params(spec) + attn_flops_per_token(spec, seq, 3)
 
 
+def serve_flops_per_token(spec: Spec, context: float,
+                          sampled_share: float = 1.0) -> float:
+    """What serving REQUIRES per token that enters the model, prompt or
+    generated: one forward pass through the layers' matrices, attention
+    over ``context`` keys (the mean over those tokens of the keys each
+    attends to), and the head for the ``sampled_share`` of them whose
+    logits a token is drawn from (a prompt needs its last position's
+    only). Logits a program computes and drops do not count."""
+    head = spec.hidden_size * spec.vocab_size
+    return (2.0 * (matmul_params(spec) - head * (1.0 - sampled_share))
+            + attn_flops_per_token(spec, 2.0 * context, passes=1))
+
+
 def flash_train_cost(spec: Spec, batch: int, seq: int) -> dict:
     """One train step's attention kernels (forward and backward, all
     layers): required flops, and the bytes that must cross HBM at least
@@ -219,11 +235,3 @@ def paged_decode_cost(spec: Spec, context_tokens: float,
     return {"flops": 4.0 * context_tokens * spec.num_attention_heads
             * spec.head_dim,
             "bytes": float(context_tokens * kv_row + qo)}
-
-
-def roofline_seconds(cost: dict, peaks: dict) -> tuple:
-    """(least seconds the chip could take, which peak bounds it)."""
-    t_flops = cost["flops"] / peaks["flops_bf16_per_s"]
-    t_bytes = cost["bytes"] / peaks["hbm_bytes_per_s"]
-    return (max(t_flops, t_bytes),
-            "compute" if t_flops >= t_bytes else "memory")

@@ -68,6 +68,11 @@ def main(argv=None) -> int:
         end_to_end=out["end_to_end"], ctx=out["ctx"])
     common.emit(line)
     sys.stdout.flush()
+    # each number compared beside its limit: the last lines of stderr too
+    for name, pair in line["compared"].items():
+        print(f"compared {name}: {pair['value']} (limit {pair['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

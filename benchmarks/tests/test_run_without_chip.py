@@ -54,3 +54,8 @@ def test_rehearsal_runs_end_to_end_and_cannot_pass():
     note = next(ln for ln in lines if ln.get("note") == "rehearsal")
     assert note["would_be_correct"] is True
     assert note["compiles_in_window"] == 0
+    assert list(last)[-1] == "compared"
+    err = last["compared"]["first_loss_abs_err"]
+    assert err["limit"] == 0.01 and 0.0 <= err["value"] < 1e-3
+    assert proc.stderr.splitlines()[-1] == (
+        f"compared first_loss_abs_err: {err['value']} (limit 0.01)")
