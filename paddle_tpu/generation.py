@@ -1240,29 +1240,73 @@ class _ScanDecodeStep:
         return toks, last_f, [{**b, **a} for b, a in zip(nb, na)]
 
 
+def _engine_token_step(model, max_len, last, key, bufs, aux, lengths,
+                       advance, sample):
+    """The body of the engine's one-token programs: ``_sample_and_forward``
+    with the engine's per-slot ``lengths`` carried THROUGH the step, so
+    the host has nothing to compute between two steps. ``advance`` is the
+    per-slot code the engine uploads when slot membership changes: > 0 an
+    active row (decodes at ``lengths[b]``, leaves ``lengths[b] + 1``),
+    < 0 a slot held mid chunked-prefill (its throwaway K/V lands at
+    ``lengths[b]``, where the next chunk's scatter overwrites it, and the
+    length keeps its place), 0 a free row (reads and leaves 0: a row that
+    retired at ``lengths[b] == max_len`` must not write past its pages).
+    ``lengths is None`` (dense caches: the correctness sentinel's replay)
+    carries nothing."""
+    new_lengths = None
+    if lengths is not None:
+        lengths = jnp.where(advance != 0, lengths, 0)
+        new_lengths = lengths + (advance > 0).astype(lengths.dtype)
+        aux = [dict(a, lengths=lengths) for a in aux]
+    nxt, lp, last_n, nb, na = _sample_and_forward(model, max_len, last, key,
+                                                  bufs, aux, **sample)
+    if lengths is not None:
+        # ONE lengths output, not one per layer (the model hands back its
+        # own lengths + 1 in every layer's aux)
+        na = [{k: v for k, v in a.items() if k != "lengths"} for a in na]
+    return nxt, lp, last_n.astype(jnp.float32), nb, na, new_lengths
+
+
+def _split_step_caches(caches, lengths):
+    """``_split_caches`` for the engine's one-token programs: with the
+    lengths passed on their own, the per-layer copies stay at home."""
+    bufs, aux = _split_caches(caches)
+    if lengths is not None:
+        aux = [{k: v for k, v in a.items() if k != "lengths"} for a in aux]
+    return bufs, aux
+
+
+def _join_step_caches(nb, na, lengths):
+    extra = {} if lengths is None else {"lengths": lengths}
+    return [{**b, **a, **extra} for b, a in zip(nb, na)]
+
+
 class _SelectDecodeStep:
     """sample + one cached forward fused into ONE jitted dispatch: the
     continuous-batching engine's per-step unit (the scan variant without
-    the scan — the host must see each token for slot retirement)."""
+    the scan — the host must see each token for slot retirement). Called
+    with the engine's ``lengths`` and ``advance`` code it also returns the
+    advanced lengths (``_engine_token_step``): step N + 1's inputs are
+    all outputs of step N that never leave the device."""
 
     def __init__(self, model, max_len, do_sample, temperature, top_k, top_p):
         self._model = model
+        sample = dict(do_sample=do_sample, temperature=temperature,
+                      top_k=top_k, top_p=top_p)
 
-        def decode_step(state, last, key, bufs, aux):
+        def decode_step(state, last, key, bufs, aux, lengths, advance):
             with _functional_weights(model, state):
-                nxt, lp, last_n, nb, na = _sample_and_forward(
-                    model, max_len, last, key, bufs, aux,
-                    do_sample, temperature, top_k, top_p)
-            return nxt, lp, last_n.astype(jnp.float32), nb, na
+                return _engine_token_step(model, max_len, last, key, bufs,
+                                          aux, lengths, advance, sample)
 
         self._jitted = jax.jit(decode_step, donate_argnums=(3,))
         self._state = dict(model.functional_state())
 
-    def __call__(self, last, key, caches):
-        bufs, aux = _split_caches(caches)
-        nxt, lp, last_f, nb, na = self._jitted(self._state, last, key,
-                                               bufs, aux)
-        return nxt, lp, last_f, [{**b, **a} for b, a in zip(nb, na)]
+    def __call__(self, last, key, caches, lengths=None, advance=None):
+        bufs, aux = _split_step_caches(caches, lengths)
+        nxt, lp, last_f, nb, na, lengths = self._jitted(
+            self._state, last, key, bufs, aux, lengths, advance)
+        return nxt, lp, last_f, _join_step_caches(nb, na, lengths), lengths
 
 
 class _SelectDecodeRowsStep:
@@ -1274,24 +1318,25 @@ class _SelectDecodeRowsStep:
         self._model = model
 
         def decode_step_rows(state, last, key, do_s, temp, tk, tp, bufs,
-                             aux):
+                             aux, lengths, advance):
+            sample = dict(
+                do_sample=None, temperature=None, top_k=None, top_p=None,
+                sampler=lambda lg, k: sample_logits_rows(lg, k, do_s, temp,
+                                                         tk, tp))
             with _functional_weights(model, state):
-                nxt, lp, last_n, nb, na = _sample_and_forward(
-                    model, max_len, last, key, bufs, aux,
-                    None, None, None, None,
-                    sampler=lambda lg, k: sample_logits_rows(
-                        lg, k, do_s, temp, tk, tp))
-            return nxt, lp, last_n.astype(jnp.float32), nb, na
+                return _engine_token_step(model, max_len, last, key, bufs,
+                                          aux, lengths, advance, sample)
 
         self._jitted = jax.jit(decode_step_rows, donate_argnums=(7,))
         self._state = dict(model.functional_state())
 
-    def __call__(self, last, key, do_s, temp, tk, tp, caches):
-        bufs, aux = _split_caches(caches)
-        nxt, lp, last_f, nb, na = self._jitted(self._state, last, key,
-                                               do_s, temp, tk, tp, bufs,
-                                               aux)
-        return nxt, lp, last_f, [{**b, **a} for b, a in zip(nb, na)]
+    def __call__(self, last, key, do_s, temp, tk, tp, caches, lengths=None,
+                 advance=None):
+        bufs, aux = _split_step_caches(caches, lengths)
+        nxt, lp, last_f, nb, na, lengths = self._jitted(
+            self._state, last, key, do_s, temp, tk, tp, bufs, aux, lengths,
+            advance)
+        return nxt, lp, last_f, _join_step_caches(nb, na, lengths), lengths
 
 
 class _SpecDecodeStep:

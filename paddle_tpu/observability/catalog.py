@@ -80,16 +80,33 @@ SERVING_PREFIX_PAGES = _R.counter(
 
 SERVING_DECODE_ROWS = _R.counter(
     "serving_decode_rows_total",
-    "Active rows summed over decode dispatches (one-token and "
-    "speculative); over serving_decode_step_seconds_count it is the mean "
-    "batch a decode program ran with",
+    "Rows whose token was delivered, summed over decode steps (one-token "
+    "and speculative); over serving_decode_step_seconds_count it is the "
+    "mean batch a decode program ran with",
     labels=("engine",))
 
 SERVING_DECODE_CACHED_TOKENS = _R.counter(
     "serving_decode_cached_tokens_total",
-    "K/V rows the decode attention reads, summed over decode dispatches: "
-    "prompt tokens + tokens generated so far of every active row, from "
-    "the host's bookkeeping at dispatch",
+    "K/V rows the decode attention reads, summed over decode steps: "
+    "prompt tokens + tokens generated so far of every row whose token was "
+    "delivered, from the host's bookkeeping",
+    labels=("engine",))
+
+SERVING_DECODE_DISPATCH = _R.counter(
+    "serving_decode_dispatch_total",
+    "Decode steps enqueued: mode=ahead while the step before it was still "
+    "unfetched (its host work hides behind that program), mode=drained "
+    "with nothing in flight (the first step after idle, every speculative "
+    "step, the step after a drain); ahead / both is the share of steps "
+    "whose dispatch the device never waited for",
+    labels=("engine", "mode"))
+
+SERVING_DECODE_DISCARDED_ROWS = _R.counter(
+    "serving_decode_discarded_rows_total",
+    "Rows a decode step computed for a request that had already finished "
+    "by eos / a stop token in the step before it, or was cancelled while "
+    "the step was in flight: never delivered, and in neither "
+    "serving_decode_rows_total nor serving_decode_cached_tokens_total",
     labels=("engine",))
 
 SERVING_PREFILL_TOKENS = _R.counter(

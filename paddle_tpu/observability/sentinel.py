@@ -146,7 +146,7 @@ def reference_decode(model, ids, max_new_tokens: int,
         toks: List[int] = []
         lps: List[float] = []
         for _ in range(max_new):
-            nxt, lp, last, caches = sel(last, key, caches)
+            nxt, lp, last, caches, _ = sel(last, key, caches)
             t = int(np.asarray(nxt)[0])
             toks.append(t)
             lps.append(float(np.asarray(lp)[0]))

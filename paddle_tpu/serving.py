@@ -23,7 +23,7 @@ import os
 import time
 import zlib
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -811,6 +811,17 @@ class _RequestBookkeeping:
             getattr(self, "_finished_usage", {}).pop(old, None)
 
 
+class _Flight(NamedTuple):
+    """One enqueued one-token decode step whose tokens the host has not
+    fetched yet: the device arrays to fetch, the ``(slot, request)``
+    pairs the step decodes for, and when it was enqueued."""
+
+    nxt: object
+    logps: object
+    rows: list
+    t_dispatch: float
+
+
 class _ChunkState:
     """A request mid chunked-prefill: it has RESERVED a slot (invisible
     to _alloc_slot) but is not yet decoding — ``pos`` tokens of its prompt
@@ -1077,6 +1088,12 @@ class ContinuousBatchEngine(_RequestBookkeeping):
 
         self._poisoned = False
         self._slots: List[Optional[_Request]] = [None] * max_batch
+        # the decode step enqueued and not yet fetched (_step_decode keeps
+        # one in flight), the device inputs that change only with slot
+        # membership (key, arrays: _enqueue_decode), the last fetch's time
+        self._in_flight: Optional[_Flight] = None
+        self._step_inputs = (None, None)
+        self._t_fetched = 0.0
         self._init_bookkeeping("decoder")
         # roofline join: llama-shaped configs get the serving_decode_step
         # cost model (None keeps phase attribution without a roofline)
@@ -1150,6 +1167,12 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         self._m_decode_rows = _metrics.SERVING_DECODE_ROWS.labels(
             engine="decoder")
         self._m_decode_cached = _metrics.SERVING_DECODE_CACHED_TOKENS.labels(
+            engine="decoder")
+        self._m_dispatch = {
+            m: _metrics.SERVING_DECODE_DISPATCH.labels(engine="decoder",
+                                                       mode=m)
+            for m in ("ahead", "drained")}
+        self._m_discarded = _metrics.SERVING_DECODE_DISCARDED_ROWS.labels(
             engine="decoder")
         self._m_prefill_prompt = _metrics.SERVING_PREFILL_TOKENS.labels(
             engine="decoder", kind="prompt")
@@ -1602,6 +1625,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             raise NotImplementedError(
                 "migration is not supported in latent (MLA) mode — the "
                 "compressed cache rows are engine-layout-specific")
+        self._drain_in_flight()  # the bundle holds every token decoded
         slot = next((s for s, r in enumerate(self._slots)
                      if r is not None and r.rid == rid), None)
         if slot is None:
@@ -1748,10 +1772,34 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         evicted from the retention window or while in flight."""
         return self._finished_logprobs.get(rid)
 
+    def cancel(self, rid: int) -> bool:
+        """``_RequestBookkeeping.cancel``; a row of the step in flight
+        that belonged to ``rid`` is discarded when that step retires, and
+        a step left decoding for no one is retired here."""
+        live = super().cancel(rid)
+        if live and self.num_active == 0:
+            self._drain_in_flight()
+        return live
+
     def step(self) -> Dict[int, np.ndarray]:
-        """Decode ONE token for every active slot (sample + forward fused
-        into a single device dispatch); returns newly finished requests
-        {rid: generated ids}.
+        """Retire ONE decode step: one token for every slot that was
+        active in it (sample + forward fused into a single device
+        dispatch); returns newly finished requests {rid: generated ids}.
+
+        The plain one-token step keeps one step IN FLIGHT: a call
+        enqueues step N + 1 (the program carries the engine's lengths, so
+        its inputs are all device outputs of step N), then fetches step
+        N's tokens, retires N and admits while N + 1 runs. A finish by
+        ``max_new_tokens`` is known before N + 1 is enqueued and that
+        slot is not part of it; a finish by eos / stop token, or a
+        ``cancel``, is learnt one step late and the slot's row of N + 1
+        is discarded (never delivered; ``serving_decode_discarded_rows_
+        total``). Every call still retires exactly one step, and once
+        the last active slot's finish is known nothing stays in flight.
+        The engine steps synchronously (``_drain_in_flight`` first)
+        wherever the host must see step N's tokens to build step N + 1
+        or reads the caches between steps: speculative steps, preemption,
+        ``export_slot``, OOM degrade.
 
         With chunked prefill enabled, each step advances AT MOST one
         prefill chunk before the decode dispatch — a long prompt lands
@@ -1767,7 +1815,14 @@ class ContinuousBatchEngine(_RequestBookkeeping):
     def _step_decode(self, clk) -> Dict[int, np.ndarray]:
         """``step()``'s body; ``clk`` is the profiler's clock or None.
         Every phase is opened under its name (``PhaseClock.open``), so it
-        is both timed and written to the profiler's trace."""
+        is both timed and written to the profiler's trace.
+
+        The plain one-token step keeps ONE step in flight: this call
+        retires step N (enqueued by the call before, or here when nothing
+        was in flight), and before it fetches N's tokens it enqueues
+        step N + 1, whose inputs are all device outputs of N. ``dispatch``
+        is that enqueue, ``sync`` the wait for N while N + 1 is queued,
+        ``retire`` and the trailing ``admit`` run beside N + 1."""
         if clk is not None:
             clk.open("admit")
         self._admit()
@@ -1776,10 +1831,16 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         self._advance_chunk()
         if clk is not None:
             clk.close()
+        spec = self.speculative_k is not None and self._spec_eligible()
+        if spec:
+            # the drafter reads the tokens of the step before
+            self._drain_in_flight(clk)
         if self.num_active == 0:
+            # nothing is in flight here unless a callback raised out of
+            # the step that learnt the last finish
+            self._drain_in_flight(clk)
             self._clear_dispatch_guard()
             return self._drain_finished()
-        spec = self.speculative_k is not None and self._spec_eligible()
         if clk is not None:
             clk.open("draft" if spec else "dispatch")
         # pre-dispatch blame + poison injection: arm the deathnote with
@@ -1788,51 +1849,124 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         self._dispatch_guard([r for r in self._slots if r is not None])
         if spec:
             return self._step_speculative(clk)
-        t_dispatch = time.perf_counter()
-        do_sample, temperature, top_k, top_p = self._sample_cfg
-        for c in self._caches:
-            c["lengths"] = self._lengths  # engine-owned (masks stale +1s)
-        # per-row program only while an ACTIVE slot carries an override —
-        # all-default mixes keep the static program (no per-row filter
-        # sorts, no [B] knob transfers), and the engine falls back to it
-        # as soon as the overriding requests retire
         try:
             with _frec.incident_scope("engine.step"):
-                if any(r is not None and r.sampling is not None
-                       for r in self._slots):
-                    rows = [(r.sampling or self._sample_cfg)
-                            if r is not None
-                            else self._sample_cfg for r in self._slots]
-                    step = _get_select_decode_rows(self.model,
-                                                   self.max_len)
-                    nxt, logps, self._last, self._caches = step(
-                        self._last, _random.next_key(),
-                        jnp.asarray([r[0] for r in rows], bool),
-                        jnp.asarray([r[1] for r in rows], jnp.float32),
-                        jnp.asarray([r[2] for r in rows], jnp.int32),
-                        jnp.asarray([r[3] for r in rows], jnp.float32),
-                        self._caches)
-                else:
-                    step = _get_select_decode(self.model, self.max_len,
-                                              do_sample, temperature,
-                                              top_k, top_p)
-                    nxt, logps, self._last, self._caches = step(
-                        self._last, _random.next_key(), self._caches)
+                if self._in_flight is None:
+                    self._in_flight = self._enqueue_decode()
+                cur = self._in_flight
+                # step N + 1 goes out before N's tokens are fetched:
+                # the host's share of a step hides behind the program
+                self._in_flight = self._enqueue_decode()
         except _frec.XlaOom as e:
             # graceful degradation instead of an engine-loop death: shed
             # the most recently admitted slot typed, shrink the budget
             self._degrade_on_oom(None, where="step", exc=e)
             return self._drain_finished()
-        self._count_decode_dispatch()
+        fr_seq, n_rows = self._retire_decode(cur, clk)
+        if self.num_active == 0:
+            # the last finish is known: what is still in flight decodes
+            # for no one
+            self._drain_in_flight(clk)
+        if clk is not None:
+            clk.open("admit")  # trailing refill accumulates into admit
+        self._admit()
+        if clk is not None:
+            clk.close()
+            self.profiler.commit(
+                active=n_rows,
+                kv_len=max((int(r.ids.size) + len(r.tokens)
+                            for r in self._slots if r is not None),
+                           default=0),
+                fr_seq=fr_seq)
+        return self._drain_finished()
+
+    def _enqueue_decode(self) -> Optional[_Flight]:
+        """Enqueue ONE plain one-token decode step for every row that
+        still owes a token, and return its record; None, with nothing
+        enqueued and no key drawn, where no row does. A row of the step
+        still in flight has one token on the way that the host has not
+        seen: it decodes again unless that token fills its budget (a
+        finish by length is known beforehand; one by eos, a stop token
+        or ``cancel`` is learnt a step late, and ``_retire_decode`` discards
+        the row). The program takes ``_lengths`` and the per-slot
+        ``advance`` code and returns the lengths advanced, so a steady
+        step costs the host the key split and this one call; the code
+        (and the per-row sampling knobs) are uploaded again only when
+        they change."""
+        before = self._in_flight
+        owed = {id(r) for _, r in before.rows} if before is not None else ()
+        rows = [(s, r) for s, r in enumerate(self._slots)
+                if r is not None and (id(r) not in owed or
+                                      len(r.tokens) + 1 < r.max_new_tokens)]
+        if not rows:
+            return None
+        t_dispatch = time.perf_counter()
+        code = [0] * self.max_batch
+        for s in self._chunking:
+            code[s] = -1  # held at its chunk frontier
+        for s, _ in rows:
+            code[s] = 1
+        # per-row program only while a DECODING row carries an override —
+        # all-default mixes keep the static program (no per-row filter
+        # sorts), and the engine falls back to it as soon as the
+        # overriding requests retire
+        knobs = None
+        if any(r.sampling is not None for _, r in rows):
+            knobs = [self._sample_cfg] * self.max_batch
+            for s, r in rows:
+                knobs[s] = tuple(r.sampling or self._sample_cfg)
+            knobs = tuple(knobs)
+        key = (tuple(code), knobs)
+        if key != self._step_inputs[0]:
+            arrays = [jnp.asarray(np.asarray(code, np.int32))]
+            if knobs is not None:
+                arrays += [jnp.asarray(np.asarray([k[i] for k in knobs], t))
+                           for i, t in enumerate((bool, np.float32,
+                                                  np.int32, np.float32))]
+            self._step_inputs = (key, arrays)
+        advance, *rows_knobs = self._step_inputs[1]
+        if knobs is not None:
+            step = _get_select_decode_rows(self.model, self.max_len)
+        else:
+            step = _get_select_decode(self.model, self.max_len,
+                                      *self._sample_cfg)
+        nxt, logps, self._last, self._caches, self._lengths = step(
+            self._last, _random.next_key(), *rows_knobs, self._caches,
+            self._lengths, advance)
+        self._m_dispatch["ahead" if before is not None else "drained"].inc()
+        return _Flight(nxt, logps, rows, t_dispatch)
+
+    def _drain_in_flight(self, clk=None) -> None:
+        """Fetch and retire the step in flight, if there is one, so that
+        the host's bookkeeping and the device's state agree again: called
+        wherever the host must see step N's tokens before it can build
+        step N + 1 (speculation's drafter) or reads the caches between
+        steps (preemption, migration export, OOM degrade, the engine
+        loop's exit), and when the batch empties under a step. What
+        finishes here is returned by the next ``step()``."""
+        flight, self._in_flight = self._in_flight, None
+        if flight is not None:
+            self._retire_decode(flight, clk)
+            if clk is not None:
+                clk.close()
+
+    def _retire_decode(self, flight: _Flight, clk=None):
+        """Fetch one enqueued step (``sync``) and deliver it (``retire``):
+        per-row bookkeeping, finishes, slot release, then the callbacks.
+        A row is delivered only if its slot still holds the request the
+        step decoded for; the others (finished by eos or a stop token in
+        the step before, cancelled since) are discarded and counted.
+        Returns (flight-recorder sequence number, rows delivered)."""
         if clk is not None:
             clk.open("sync")
         # THE one deliberate device->host sync of the decode loop: every
         # other host conversion below reads these already-fetched arrays
-        toks = np.asarray(nxt)    # pdlint: disable=host-sync
-        lps = np.asarray(logps)   # pdlint: disable=host-sync
+        toks = np.asarray(flight.nxt)
+        lps = np.asarray(flight.logps)
         if clk is not None:
             clk.open("retire")
-        self._clear_dispatch_guard()  # step success: blame record erased
+        if self._in_flight is None:
+            self._clear_dispatch_guard()  # step success: blame record erased
         inj = _chaos.active()
         if inj is not None and "engine.logits" in inj.plan.points():
             # chaos: one emitted token flipped AFTER the device sync —
@@ -1840,8 +1974,8 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             # catch, and replay_divergence must bisect back to the plan
             fault = inj.fire("engine.logits")
             if fault is not None and fault.action == "perturb_logit":
-                s0 = next((s for s, r in enumerate(self._slots)
-                           if r is not None), None)
+                s0 = next((s for s, r in flight.rows
+                           if self._slots[s] is r), None)
                 if s0 is not None:
                     vocab = int(self.model.config.vocab_size)
                     t_new = (int(toks[s0]) + 1) % vocab
@@ -1850,11 +1984,14 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                         t_new = (t_new + 1) % vocab
                     toks = toks.copy()
                     toks[s0] = t_new
-        # np.asarray forced the device->host sync, so the span covers the
-        # whole fused dispatch; ONE clock for every token this step
-        # produced (they came from one dispatch)
+        # ONE clock for every token this step produced (they came from
+        # one dispatch). The step's span starts when it was enqueued, or
+        # when the step before it was fetched if that came later: behind
+        # a step in flight the time from the enqueue holds that step too
         now = time.perf_counter()
-        self._m_step.observe(now - t_dispatch)
+        t_start = max(flight.t_dispatch, self._t_fetched)
+        self._t_fetched = now
+        self._m_step.observe(now - t_start)
         self._n_steps += 1
         fr_seq = 0
         rec = _frec.RECORDER
@@ -1863,21 +2000,24 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             # stays O(steps) however many slots decode concurrently
             fr_seq = rec.record(_frec.EV_STEP, engine=self._engine_label,
                                 active=self.num_active,
-                                seconds=now - t_dispatch)
+                                seconds=now - t_start)
         # perf_counter and perf_counter_ns share one clock, so the span
         # bounds come from the timestamps already taken for the metric
         trace_on = _tracing.get_tracer().enabled
-        t0_ns, t1_ns = (int(t_dispatch * 1e9), int(now * 1e9)) \
+        t0_ns, t1_ns = (int(t_start * 1e9), int(now * 1e9)) \
             if trace_on else (0, 0)
         retiring = []
         events = []  # (cb, rid, token, done): fired AFTER bookkeeping, so a
-        # raising callback cannot leave _lengths/slot state desynced from
-        # the already-advanced device step
+        # raising callback cannot leave slot state desynced from the
+        # already-advanced device step
         at = self.kvatlas
         at_on = at.enabled  # hoisted: one predicate for the whole loop
-        for s, req in enumerate(self._slots):
-            if req is None:
+        n_rows = cached = 0
+        for s, req in flight.rows:
+            if self._slots[s] is not req:
                 continue
+            n_rows += 1
+            cached += int(req.ids.size) + len(req.tokens)
             req.dispatches += 1
             t = int(toks[s])
             req.tokens.append(t)
@@ -1906,25 +2046,11 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                                req.rid, t, lp, finished))
             if finished:
                 retiring.append(s)
-        active = np.array([r is not None for r in self._slots])
-        if self._chunking:
-            # mid-chunk slots HOLD their position: the fixed-shape decode
-            # dispatch wrote a throwaway token's KV at lengths[slot], and
-            # keeping lengths there parks that garbage exactly where the
-            # next chunk's scatter overwrites it (resetting to 0 would
-            # park it in page 0 — INSIDE the prefix the next chunk
-            # gathers)
-            hold = np.zeros(self.max_batch, bool)
-            for s in self._chunking:
-                hold[s] = True
-            self._lengths = jnp.where(
-                jnp.asarray(active), self._lengths + 1,
-                jnp.where(jnp.asarray(hold), self._lengths,
-                          jnp.zeros_like(self._lengths)))
-        else:
-            self._lengths = jnp.where(jnp.asarray(active),
-                                      self._lengths + 1,
-                                      jnp.zeros_like(self._lengths))
+        # only what was delivered counts as decoded: a discarded row
+        # would inflate the rows and the context a step is credited with
+        self._m_decode_rows.inc(n_rows)
+        self._m_decode_cached.inc(cached)
+        self._m_discarded.inc(len(flight.rows) - n_rows)
         for s in retiring:
             req = self._slots[s]
             self._finished[req.rid] = np.asarray(req.tokens, np.int64)
@@ -1945,23 +2071,15 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                     first_exc = e
         if first_exc is not None:
             raise first_exc
-        if clk is not None:
-            clk.open("admit")  # trailing refill accumulates into admit
-        self._admit()
-        if clk is not None:
-            clk.close()
-            self.profiler.commit(
-                active=int(active.sum()),
-                kv_len=max((int(r.ids.size) + len(r.tokens)
-                            for r in self._slots if r is not None),
-                           default=0),
-                fr_seq=fr_seq)
-        return self._drain_finished()
+        return fr_seq, n_rows
 
     def _count_decode_dispatch(self):
-        """One decode dispatch left the host: the rows that decode in it
-        and the K/V rows the attention reads for them (prompt + tokens
-        generated so far, per row), from the host's own bookkeeping."""
+        """One speculative dispatch left the host (synchronous: nothing
+        was in flight): the rows that decode in it and the K/V rows the
+        attention reads for them (prompt + tokens generated so far, per
+        row), from the host's own bookkeeping. The one-token step counts
+        the same in ``_retire_decode``, for the rows it delivers."""
+        self._m_dispatch["drained"].inc()
         rows = cached = 0
         for r in self._slots:
             if r is not None:
@@ -2235,10 +2353,11 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         request; a decode step carries every active slot):
 
         - the **deathnote** (supervisor.Deathnote, cluster workers only)
-          atomically records the request ids entering the dispatch and
-          is erased on step success — if the process dies mid-dispatch
-          the supervisor blames exactly these rids, not every request
-          the router had in flight here;
+          atomically records the request ids entering the dispatch, with
+          those of a decode step still in flight, and is erased when a
+          step succeeds with none in flight behind it — if the process
+          dies mid-dispatch the supervisor blames exactly these rids,
+          not every request the router had in flight here;
         - the ``engine.dispatch`` **chaos point** hands the injector the
           same ids: a planned ``crash_on_rid`` fault kills the process
           the moment its poison rid enters a dispatch (``os._exit``,
@@ -2250,6 +2369,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         inj = _chaos.active()
         if dn is None and inj is None:
             return
+        if self._in_flight is not None:
+            # a decode step is still on the device: its rows stay blamed
+            reqs = reqs + [r for _, r in self._in_flight.rows
+                           if r not in reqs]
         rids = [r.ext_id if r.ext_id is not None else f"rid:{r.rid}"
                 for r in reqs]
         if dn is not None:
@@ -2277,6 +2400,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         occupancy that OOM'd (floor 1), and emit ``sched.degrade`` so
         /health and debug_state() show the reduced budget. The incident
         bundle was already written by the dispatch's incident_scope."""
+        self._drain_in_flight()  # a victim is chosen among settled slots
         occupancy = (self.num_active + len(self._chunking)
                      + (1 if req is not None else 0))
         prev = self.max_active_slots
@@ -2422,7 +2546,11 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 victim_slot, victim_key = s, key
         if victim_slot < 0:
             return False
-        self._preempt_slot(victim_slot, by=cand)
+        victim = self._slots[victim_slot]
+        self._drain_in_flight()  # the bundle holds every token decoded
+        if self._slots[victim_slot] is victim:
+            self._preempt_slot(victim_slot, by=cand)
+        # else the victim finished in the drained step: its slot is free
         return True
 
     def _slot_kv_bundle(self, s: int, req: _Request):

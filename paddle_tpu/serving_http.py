@@ -745,6 +745,10 @@ class CompletionServer:
                     continue
                 with span("submissions"):
                     self._handle_submission(sub)
+        # a stopped loop leaves no decode step behind on the device
+        drain = getattr(eng, "_drain_in_flight", None)
+        if drain is not None:
+            drain()
 
     # ---- handler hooks --------------------------------------------------
     def _make_handler(server_self):

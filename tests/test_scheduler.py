@@ -135,13 +135,19 @@ def test_short_prompts_skip_chunking(tiny_model, recorder):
 
 # ---- the bounded-stall guarantee --------------------------------------------
 
-def test_mixed_load_bounded_stalls(tiny_model, recorder):
+def test_mixed_load_bounded_stalls(recorder):
     """THE acceptance bar: with chunking on, a decode dispatch runs
     between every pair of prefill chunks (structural bound: no decode
     step waits longer than one chunk-step), and the live request's
     worst wall-clock inter-token gap during the long prefill is
     strictly smaller than under the monolithic prefill."""
-    m = tiny_model
+    # wide enough that a prefill's compute, not the host's dispatch,
+    # decides a gap on the CPU: at the tiny widths the two worst gaps
+    # (~4 ms each) compared as a coin toss, alone and on every tree
+    paddle.seed(0)
+    m = LlamaForCausalLM(LlamaConfig.tiny(
+        num_hidden_layers=4, hidden_size=512, intermediate_size=2048,
+        num_attention_heads=8, num_key_value_heads=4))
     rng = np.random.RandomState(7)
     long_p = rng.randint(0, m.config.vocab_size, (48,))
     short_p = rng.randint(0, m.config.vocab_size, (5,))
@@ -273,8 +279,9 @@ def test_preempt_restore_token_identity(tiny_model, recorder):
     res = [e for e in evs if e["kind"] == "sched.restore"]
     assert len(pre) == 1 and len(res) == 1
     assert pre[0]["rid"] == victim and res[0]["rid"] == victim
-    assert pre[0]["generated"] == 3 and pre[0]["bytes"] > 0
-    assert pre[0]["kv_len"] == res[0]["kv_len"] == short_p.size + 3
+    # three steps retired, and the one in flight drained before eviction
+    assert pre[0]["generated"] == 4 and pre[0]["bytes"] > 0
+    assert pre[0]["kv_len"] == res[0]["kv_len"] == short_p.size + 4
     assert eng.stats()["requests_preempted"] == 1
 
 
