@@ -49,9 +49,11 @@ HIGH = ("float32", "float64")
 _ARITH = {"add", "sub", "mul", "div", "max", "min", "pow", "atan2",
           "rem", "nextafter", "dot_general"}
 
-# call-like eqns whose single sub-jaxpr maps invars/outvars 1:1
-_TRANSPARENT_CALLS = {"pjit", "custom_vjp_call_jaxpr", "custom_jvp_call",
-                      "custom_vjp_call", "remat", "checkpoint"}
+# call-like eqns whose single sub-jaxpr maps invars/outvars 1:1 (a
+# jitted call is the primitive "pjit" in older JAX and "jit" in newer)
+_TRANSPARENT_CALLS = {"pjit", "jit", "custom_vjp_call_jaxpr",
+                      "custom_jvp_call", "custom_vjp_call", "remat",
+                      "checkpoint"}
 
 
 @dataclasses.dataclass

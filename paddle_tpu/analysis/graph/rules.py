@@ -206,9 +206,6 @@ class AutotuneCostTableRule(GraphRule):
         from ...ops.pallas import autotune
         # importing the kernel modules registers their cost models
         from ...ops.pallas import decode_tail, fused_norm  # noqa: F401
-        # the step profiler persists serving_decode_step observations
-        # into the same table; its model must be live for the replay
-        from ...observability import perf  # noqa: F401
 
         path = autotune.cache_path()
         if not os.path.isfile(path):

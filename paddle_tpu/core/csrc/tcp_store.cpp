@@ -141,7 +141,7 @@ class Server {
           int one = 1;
           ::setsockopt(conn, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
           // bound per-request reads so one stalled/partial-writing peer
-          // cannot wedge the single daemon thread (ADVICE.md round 1)
+          // cannot wedge the single daemon thread
           set_op_timeout(conn, 30.0);
           alive.push_back(conn);
         }

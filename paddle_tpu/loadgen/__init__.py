@@ -8,8 +8,9 @@ QPS-sweep knee finder (:mod:`.harness`). Drives the single-process
 ``POST /v1/completions``), and reads shed/429/preempt/migrate accounting
 off the metrics the stack already exports.
 
-CLI: ``scripts/load_replay.py``; bench leg: ``BENCH_CONFIG=load``;
-runbook: docs/SERVING.md "Capacity & overload runbook".
+CLI: ``scripts/load_replay.py``; the chaos dryrun (``chaos/dryrun.py``)
+drives its load through it; runbook: docs/SERVING.md "Capacity &
+overload runbook".
 """
 from .trace import (TraceRequest, dump_trace, dumps_trace, load_trace,
                     loads_trace, trace_digest)

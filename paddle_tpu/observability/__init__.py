@@ -4,7 +4,7 @@ tracing.
 
 Importing this package registers the full metric catalog (catalog.py)
 into the process-wide default registry — serving engines, the HTTP
-front-end, hapi callbacks, the profiler, and bench.py all publish into
+front-end, hapi callbacks and the profiler all publish into
 the SAME registry, so one ``GET /metrics`` (or one SnapshotWriter line)
 is a whole-process snapshot. scripts/check_metrics_catalog.py lints the
 registered names against the docs/SERVING.md catalog in both directions.

@@ -347,7 +347,6 @@ FEDERATED_SERIES = frozenset({
     "cluster_deadline_misses",
     "cluster_tokens_generated",
     "cluster_profile_step_ms",
-    "cluster_profile_roofline_ratio",
     "cluster_kv_pages_in_use",
     "cluster_kv_bytes",
     "cluster_kv_headroom_slots",

@@ -158,35 +158,10 @@ SERVING_STEP_PHASE = _R.histogram(
     "serving_step_phase_seconds",
     "Per-step wall time attributed to one named engine phase "
     "(phase=admit|prefill|draft|dispatch|sync|retire; the step-anatomy "
-    "profiler, docs/SERVING.md 'Step anatomy & roofline accounting' — "
-    "sum over phases of one step ~= serving_decode_step_seconds)",
+    "profiler's HOST clock, docs/SERVING.md 'Step anatomy' — with one "
+    "decode step in flight, sync is the wait for step N while N+1 is "
+    "queued)",
     labels=("engine", "phase"))
-
-SERVING_ROOFLINE_RATIO = _R.gauge(
-    "serving_roofline_ratio",
-    "Roofline-predicted dispatch ms / measured dispatch ms for the most "
-    "recent profiled window (1.0 = running at the hardware roofline; "
-    "0 until the engine has a registered cost model and traffic)",
-    labels=("engine",))
-
-SERVING_ACHIEVED_HBM_GBPS = _R.gauge(
-    "serving_achieved_hbm_gbps",
-    "Achieved HBM bandwidth over the most recent profiled window "
-    "(analytical bytes moved / measured dispatch time)",
-    labels=("engine",))
-
-SERVING_ACHIEVED_GFLOPS = _R.gauge(
-    "serving_achieved_gflops",
-    "Achieved compute throughput over the most recent profiled window "
-    "(analytical FLOPs / measured dispatch time)",
-    labels=("engine",))
-
-SERVING_MFU = _R.gauge(
-    "serving_mfu",
-    "Serving model-FLOPs utilization: achieved FLOP/s over the device's "
-    "peak bf16 FLOP/s (autotune.roofline_caps) for the most recent "
-    "profiled window",
-    labels=("engine",))
 
 SERVING_KV_PAGES_IN_USE = _R.gauge(
     "serving_kv_pages_in_use",

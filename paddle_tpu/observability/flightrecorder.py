@@ -259,13 +259,6 @@ EV_LOCK_ORDER = _register(
     "contradicts the static lock graph "
     "(violation=inversion|static_conflict, held, acquired, thread) — "
     "the full stacks ride bundle['lock_witness']")
-EV_PERF_ROOFLINE = _register(
-    "perf.roofline",
-    "the step-anatomy profiler persisted a roofline observation into "
-    "the autotune cost table (engine, measured_ms, predicted_ms, ratio, "
-    "mfu) — one (signature, measured, predicted) training row for a "
-    "later learned cost-model fit; see docs/SERVING.md 'Step anatomy & "
-    "roofline accounting'")
 EV_AUDIT_PASS = _register(
     "audit.pass",
     "a correctness-sentinel audit replayed the request on the "
@@ -475,8 +468,8 @@ BUNDLE_SCHEMA = {
     # (None when no manager exists)
     "alerts": (dict, type(None)),
     # the step-anatomy profile (perf.profile_payload(); None when no
-    # engine ever registered a profiler) — per-phase p50/p99, roofline
-    # ratios, and the top-K slowest recent steps at crash time
+    # engine ever registered a profiler) — per-phase p50/p99 and the
+    # top-K slowest recent steps at crash time
     "profile": (dict, type(None)),
     # the KV & memory atlas (kvatlas.kvstate_payload(); None when no
     # engine ever registered an atlas) — pool occupancy, per-slot page

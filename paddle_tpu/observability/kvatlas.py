@@ -3,9 +3,7 @@
 perf.py explains where each decode step's *milliseconds* go; this module
 explains where the KV pool's *bytes* go — the measured side of the
 memory story whose predicted side is ``analysis.graph.cost
-.kv_cache_bytes`` (the preflight estimate), joined continuously the way
-the step profiler joins measured dispatch time against the roofline
-model:
+.kv_cache_bytes`` (the preflight estimate), joined continuously:
 
 - ``KvAtlas`` — one per engine, registered by label like the
   StepProfiler. Disabled by default and guarded Tracer-style at every
@@ -61,8 +59,8 @@ KVSTATE_SCHEMA_VERSION = 1
 PREFIX_INDEX_CAP = 256
 
 #: cadence (in ledger mutations) of occupancy-gauge refresh — batched
-#: like the profiler's roofline gauges so the per-token cost stays far
-#: under the 1% overhead bar (snapshot reads also refresh them)
+#: so the per-token cost stays far under the 1% overhead bar (snapshot
+#: reads also refresh them)
 _GAUGE_EVERY = 32
 
 #: forecast window over the TSDB admission/finish counters

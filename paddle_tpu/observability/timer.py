@@ -1,10 +1,10 @@
 """StepTimer: train-loop step telemetry into the metrics registry.
 
 One object serves three call styles — the hapi callback wraps
-begin()/end() around each batch, bench.py records an externally timed
-loop through observe(), and ad-hoc loops can use the ``step()`` context
-manager. Every record publishes the step-time histogram, tokens/s and
-samples/s gauges, and the device-memory gauges from
+begin()/end() around each batch, a caller that timed its own loop
+records through observe(), and ad-hoc loops can use the ``step()``
+context manager. Every record publishes the step-time histogram,
+tokens/s and samples/s gauges, and the device-memory gauges from
 ``framework.device.memory_stats``; when ``FLAGS_log_memory_stats`` is set
 (utils/flags.py — the reference's memory/stats.cc step logging) each
 step also logs live/peak bytes through the rank-aware logger so
@@ -74,8 +74,8 @@ class StepTimer:
 
     def observe(self, step_seconds: float, n_samples: Optional[int] = None,
                 n_tokens: Optional[int] = None):
-        """Record one step of known duration (bench.py times its loop
-        around a block_until_ready sync, then records here)."""
+        """Record one step of known duration (``end()`` records here; so
+        can a caller that timed its loop around a block_until_ready)."""
         dt = float(step_seconds)
         self.last_step_seconds = dt
         self.n_steps += 1

@@ -80,7 +80,7 @@ def _logger():
 # ---------------------------------------------------------------------------
 
 #: device-kind substring → (HBM bytes/s, peak bf16 FLOP/s): the repo's ONE
-#: table of chip peaks (bench.py's MFU reads it too). Matched against
+#: table of chip peaks. Matched against
 #: jax's ``device_kind`` lowercased; first hit wins. Sources: Google Cloud
 #: TPU documentation, "TPU v6e" / "TPU v5p" / "TPU v5e" / "TPU v4" system
 #: architecture pages. The ``cpu`` row is a nominal host figure that only

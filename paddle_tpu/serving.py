@@ -1095,15 +1095,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         self._step_inputs = (None, None)
         self._t_fetched = 0.0
         self._init_bookkeeping("decoder")
-        # roofline join: llama-shaped configs get the serving_decode_step
-        # cost model (None keeps phase attribution without a roofline)
-        self.profiler.set_cost_params(
-            _perf.decode_step_params(cfg, max_batch))
         # KV & memory atlas, configured with this pool's real geometry —
         # replaces the degenerate instance _init_bookkeeping registered.
-        # preflight_bytes is the PREDICTED pool footprint (the memory
-        # analogue of the profiler's roofline join): measured occupancy
-        # is reported against it on /kvstate and in bench kv legs
+        # preflight_bytes is the PREDICTED pool footprint: measured
+        # occupancy is reported against it on /kvstate
         try:
             from .analysis.graph.cost import kv_cache_bytes as _kv_pre
 

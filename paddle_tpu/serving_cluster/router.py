@@ -308,12 +308,11 @@ class RouterServer:
         ("tokens_generated", "cluster_tokens_generated"),
     )
 
-    # step-anatomy profiler scalars federated as per-replica GAUGES (the
-    # watch_cluster perf panel's sparkline feed); same /health-probe
+    # the step-anatomy profiler's scalar federated as a per-replica GAUGE
+    # (the watch_cluster perf panel's sparkline feed); same /health-probe
     # transport as the counters above — a sample never does network I/O
     _FEDERATED_PERF = (
         ("profile_step_ms", "cluster_profile_step_ms"),
-        ("profile_roofline_ratio", "cluster_profile_roofline_ratio"),
     )
 
     # KV-atlas scalars federated as per-replica GAUGES (the

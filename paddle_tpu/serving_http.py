@@ -145,10 +145,9 @@ def timeseries_payload(query: str) -> dict:
 
 def profile_payload(query: str = "") -> dict:
     """``GET /profile`` body: every registered engine's step anatomy —
-    per-phase p50/p99/share over the recent window, roofline ratios and
-    MFU, and the top-K slowest steps with their flight-recorder seqs
-    (``?top=`` bounds K; docs/SERVING.md 'Step anatomy & roofline
-    accounting')."""
+    per-phase p50/p99/share over the recent window (host time) and the
+    top-K slowest steps with their flight-recorder seqs (``?top=``
+    bounds K; docs/SERVING.md 'Step anatomy')."""
     q = parse_qs(query)
     top_k = 5
     if q.get("top"):

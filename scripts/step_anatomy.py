@@ -1,13 +1,13 @@
 #!/usr/bin/env python
 """step_anatomy: pretty-print the serving step-anatomy profile.
 
-Reads the ``GET /profile`` document (docs/SERVING.md "Step anatomy &
-roofline accounting") from a live server, a router's federated
-``GET /profile/cluster``, or the ``profile`` section of a saved
-incident bundle, and renders per-engine phase tables: where each decode
-step's wall time went (admit / prefill / draft / dispatch / sync /
-retire), the achieved-vs-roofline ratio, and the slowest recent steps
-with their flight-recorder sequence anchors.
+Reads the ``GET /profile`` document (docs/SERVING.md "Step anatomy")
+from a live server, a router's federated ``GET /profile/cluster``, or
+the ``profile`` section of a saved incident bundle, and renders
+per-engine phase tables: where each engine step's HOST time went (admit
+/ prefill / draft / dispatch / sync / retire) and the slowest recent
+steps with their flight-recorder sequence anchors. Device time, MFU and
+rooflines come from the benchmark's device trace (PERF.md section 3).
 
 Usage:
     python scripts/step_anatomy.py http://127.0.0.1:8000
@@ -73,18 +73,6 @@ def render_engine(name: str, eng: dict, lines: List[str]) -> None:
                          f"{_fmt_ms(info.get('p99_ms', 0))} "
                          f"{_fmt_ms(info.get('mean_ms', 0))}  "
                          f"{share:6.1%} {bar}")
-    roof = eng.get("roofline")
-    if roof:
-        lines.append(
-            f"  roofline  ratio={roof.get('ratio', 0):.3f}  "
-            f"measured={roof.get('measured_ms', 0):.3f}ms  "
-            f"predicted={roof.get('predicted_ms', 0):.3f}ms  "
-            f"({roof.get('device', '?')}, window of "
-            f"{roof.get('window_steps', 0)} steps)")
-        lines.append(
-            f"            achieved {roof.get('achieved_hbm_gbps', 0):.1f} "
-            f"HBM GB/s, {roof.get('achieved_gflops', 0):.1f} GFLOP/s, "
-            f"MFU {roof.get('mfu', 0):.4f}")
     top = eng.get("top_slowest") or []
     if top:
         lines.append("  slowest steps (ms | dominant phase | active "

@@ -39,13 +39,9 @@ DEFAULT_METRICS = (
 )
 
 #: perf panel series (metric, printf format for the last value): the
-#: router's federated per-replica gauges first, then the process-local
-#: roofline gauges a single server publishes
+#: router's federated per-replica gauge of the engine step's host time
 PERF_METRICS = (
     ("cluster_profile_step_ms", "%.2f ms"),
-    ("cluster_profile_roofline_ratio", "%.3f"),
-    ("serving_roofline_ratio", "%.3f"),
-    ("serving_mfu", "%.3f"),
 )
 
 #: memory panel series (same shape as PERF_METRICS): the router's
@@ -190,9 +186,9 @@ def render(snap: dict, metrics) -> str:
         lines.append(f"ENGINE  active={health.get('active')} "
                      f"queued={health.get('queued')} "
                      f"max_active_slots={health.get('max_active_slots')}")
-    # ---- perf panel: step anatomy / roofline --------------------------
-    # federated gauges on a router (per-replica labels), process gauges
-    # on a single server; silent when neither has published yet
+    # ---- perf panel: step anatomy ---------------------------------------
+    # federated gauges on a router (per-replica labels); silent until
+    # one has published
     perf_rows = []
     for metric, fmt in PERF_METRICS:
         for s in series_windows(ts, metric):
@@ -204,7 +200,7 @@ def render(snap: dict, metrics) -> str:
                 f"  {label:<52} {sparkline(s['values'])} "
                 f"last={fmt % s['last']}")
     if perf_rows:
-        lines.append("PERF  (decode step anatomy & roofline — see "
+        lines.append("PERF  (engine step, host clock — see "
                      "GET /profile for the per-phase breakdown)")
         lines.extend(perf_rows)
     # ---- memory panel: KV atlas ---------------------------------------

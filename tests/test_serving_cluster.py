@@ -688,8 +688,12 @@ def unified_cluster():
         # semantics (a killed worker STAYS dead and the survivor carries
         # the streams) — the supervised kill→restart→heal→quarantine
         # story has its own referee in the chaos dryrun gate
+        # ttl 10 s: the clean-run control below must see no lease lapse,
+        # and on a starved machine a worker's heartbeat can come 2 s
+        # late; the failover gate learns of its kill from the broken
+        # socket (mark_dead), not from the lease
         cluster = launch_cluster(_cluster_cfg(
-            [{"role": "unified", "count": 2}]), supervise=False)
+            [{"role": "unified", "count": 2}], ttl=10.0), supervise=False)
     except BaseException:
         os.environ.pop("FLAGS_lock_witness", None)
         set_flags({"lock_witness": False})
@@ -722,15 +726,27 @@ def test_cluster_gate_federation_and_clean_alerts(unified_cluster):
         assert clean and len(toks) == 4
     # a fresh probe (worker stats into the pool) then a forced sample
     # (pool stats into the federated store + one alert evaluation) —
-    # the background cadences must not gate the assertions
-    cluster.pool.refresh()
-    tsm.get_store().sample_once()
-
-    # ---- /metrics/cluster: one exposition for the whole tier --------
-    with urllib.request.urlopen(url + "/metrics/cluster",
-                                timeout=30) as r:
-        assert "text/plain" in (r.headers.get("Content-Type") or "")
-        text = r.read().decode()
+    # the background cadences must not gate the assertions. Probe and
+    # scrape give a worker 2 s to answer, and on a starved machine one
+    # misses that: by design a `# scrape_error` comment, not a fault of
+    # the federation. So wait (bounded) for ONE view in which every
+    # worker answered, and hold that view to all of it.
+    deadline = time.monotonic() + 120
+    while True:
+        cluster.pool.refresh()
+        tsm.get_store().sample_once()
+        # ---- /metrics/cluster: one exposition for the whole tier ----
+        with urllib.request.urlopen(url + "/metrics/cluster",
+                                    timeout=30) as r:
+            assert "text/plain" in (r.headers.get("Content-Type") or "")
+            text = r.read().decode()
+        whole = ("# scrape_error" not in text
+                 and "cluster_workers_alive 2" in text)
+        if whole or time.monotonic() > deadline:
+            break
+        time.sleep(0.5)
+    assert "# scrape_error" not in text, [
+        ln for ln in text.splitlines() if "scrape_error" in ln]
     for rid in ("0", "1"):
         assert f'serving_requests_total{{replica="{rid}",' in text, rid
     assert 'replica="router"' in text
