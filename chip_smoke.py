@@ -66,8 +66,8 @@ SIZES = {
         widths={},
         train=dict(depth=2, seq=4096, batch=1),
         serve=dict(depth=16, max_batch=8, max_len=4096, page_size=16,
-                   # 200 pads to its 256 bucket (the ragged prefill ->
-                   # append_attention); 1024 IS its bucket (flash prefill)
+                   # 200 pads to its 256 bucket, 1024 IS its bucket: the
+                   # engine passes no pad mask, so both prefill on splash
                    prompt_lens=(200, 1024)),
         mesh=dict(depth=2, seq=2048, batch=2)),
     True: dict(
@@ -84,9 +84,9 @@ SIZES = {
 #: Pallas kernels (``ops/pallas/backend.py`` keeps the record)
 MAIN_PATH = {
     "train": ("flash_attention", "fused_rope", "rms_norm", "add_rms_norm"),
-    "serve": ("flash_attention", "append_attention", "paged_attention",
-              "kv_page_write", "rms_norm", "add_rms_norm"),
-    "cluster": ("append_attention", "paged_attention", "kv_page_write",
+    "serve": ("flash_attention", "paged_attention", "kv_page_write",
+              "rms_norm", "add_rms_norm"),
+    "cluster": ("flash_attention", "paged_attention", "kv_page_write",
                 "rms_norm", "add_rms_norm"),
     "mesh": ("flash_attention", "fused_rope", "rms_norm", "add_rms_norm"),
 }   # mesh: the ONE-CHIP reference; the hybrid step is checked apart

@@ -72,11 +72,19 @@ def cached_attention(q, k, v, cos, sin, k_buf, v_buf, pos, allowed=None,
     k_buf/v_buf [B,Smax,hk,D]; pos = buffer write offset (scalar);
     allowed = optional [B,Tmax] column-validity mask (padded prompts);
     row_pos = optional [B] per-row RoPE positions (ragged batches);
-    use_flash = route an unpadded pos=0 prefill (the serving hot path)
-    through the GQA splash flash kernel instead of the dense einsum against
-    the whole buffer — at pos=0 prefill, causal attention over the prompt
-    equals causal self-attention on the S new tokens, so the flash kernel
-    is exact and never touches the (mostly empty) Smax buffer.
+    use_flash = route a pos=0 prefill without ``allowed`` (the serving
+    hot path) through the GQA splash flash kernel instead of the dense
+    einsum against the whole buffer — at pos=0 prefill, causal attention
+    over the prompt equals causal self-attention on the S new tokens, so
+    the flash kernel is exact and never touches the (mostly empty) Smax
+    buffer. A prompt padded on the RIGHT needs no ``allowed`` for this:
+    under the causal mask a real token at position s sees columns 0..s,
+    none of them a pad, so the engine's admission passes none (the pad
+    rows' outputs are never read). ``allowed`` is for pads a real token
+    could see: left padding, and the decode steps of a padded batch. It
+    costs the kernel: a mask sends S > 1 to ``append_attention``, whose
+    gate refuses more than 2048 score rows, and from there to the f32
+    einsum over the whole buffer ([B, hk, g, S, T] scores).
     ``rope_applied``: q/k arrive already rotated (the fused decode-tail
     kernel ropes in-register) — skip the rope, keep everything else.
     Returns (out [B,S,H,D], new_k_buf, new_v_buf).
@@ -977,7 +985,19 @@ class _PrefillStep:
     layers (flash kernel over the prompt — cache `pos` is a concrete 0
     inside the trace, so the fast path survives jit) → each row's last real
     logit. Eager prefill costs one device dispatch per op per layer; this is
-    the serving path's second half of the TrainStep pattern."""
+    the serving path's second half of the TrainStep pattern.
+
+    ``ragged`` stamps ``pad_mask`` on the fresh caches as ``allowed``, which
+    takes the attention off the flash kernel (cached_attention). Only pads
+    that a real token could attend need it: generate()'s batches (left
+    padding; the mask also serves the decode steps that follow). The
+    serving engine pads ONE prompt on the RIGHT and attends causally from
+    position 0, so no real token sees a pad: it asks for ``ragged=False``
+    at every prompt length and passes ``lengths`` for the last-logit gather
+    alone. The pad rows' K/V and outputs are garbage nobody reads.
+
+    ``attention_impl``: which implementation the attention site took when
+    this step was last traced (``traced_attention_impl``)."""
 
     def __init__(self, model, max_len, ragged, rope_len=None,
                  embeds_input=False):
@@ -1016,18 +1036,31 @@ class _PrefillStep:
         self._jitted = jax.jit(prefill)
         self._state = dict(model.functional_state())
 
-    def __call__(self, ids, lengths, pad_mask):
-        return self._jitted(self._state, ids, lengths, pad_mask)
+    def __call__(self, ids, lengths, pad_mask=None):
+        return traced_attention_impl(self._jitted, self._state, ids,
+                                     lengths, pad_mask)
+
+    @property
+    def attention_impl(self):
+        return self._jitted.attention_impl
 
 
-def prefill_mask(lengths, bucket):
-    """[B, bucket] bool, True on each row's real tokens: the pad mask of
-    a ragged admission prefill. The lengths are a traced operand, so one
-    program serves every prompt length of a bucket."""
-    return jnp.arange(bucket, dtype=jnp.int32)[None, :] < lengths[:, None]
-
-
-_prefill_mask = jax.jit(prefill_mask, static_argnums=(1,))
+def traced_attention_impl(fn, *args):
+    """``fn(*args)`` for a jitted prefill program; when the call traced it
+    (the Pallas gates speak at trace time only; a first call always
+    traces), ``fn.attention_impl`` keeps what the prefill attention took:
+    ``flash`` (splash over the new tokens), ``append`` (the streaming
+    kernel over the buffer) or ``xla`` (the f32 composite, also where no
+    gate was asked). The engine counts admissions by it."""
+    with _pallas_backend.recording() as taken:
+        out = fn(*args)
+    if taken or getattr(fn, "attention_impl", None) is None:
+        kernels = {site for site, impl in taken
+                   if impl != _pallas_backend.XLA}
+        fn.attention_impl = ("flash" if "flash_attention" in kernels
+                             else "append" if "append_attention" in kernels
+                             else "xla")
+    return out
 
 
 def _trace_flags_key() -> tuple:

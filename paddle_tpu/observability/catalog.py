@@ -117,6 +117,18 @@ SERVING_PREFILL_TOKENS = _R.counter(
     "is the padding waste",
     labels=("engine", "kind"))
 
+SERVING_PREFILL_ATTENTION = _R.counter(
+    "serving_prefill_attention_total",
+    "Admission prefill programs enqueued (the same events as "
+    "serving_prefill_tokens_total), by the implementation their attention "
+    "took when the program was traced: impl=flash the splash kernel over "
+    "the new tokens (a right-padded prompt in a bucket the kernel tiles: "
+    "128 and up at head width 128), impl=append the streaming kernel over "
+    "the buffer (a prefix hit's suffix, a later chunk), impl=xla the f32 "
+    "composite (the gate refused: ops/pallas/backend.refusals() on /health "
+    "says why)",
+    labels=("engine", "impl"))
+
 SERVING_SPEC_ACCEPTED = _R.histogram(
     "serving_spec_accepted_tokens",
     "Draft tokens the target accepted per speculative verify, observed "

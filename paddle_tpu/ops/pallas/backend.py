@@ -23,14 +23,17 @@ Call sites report the implementation they chose through :func:`took`
 (at trace time, so the cost is per compile, not per step). A gate that
 refuses a shape on a TPU is logged once with its reason, and
 :func:`paths` hands the counts to ``chip_smoke.py``, which fails when a
-main-path site ran interpreted or on a reference branch.
+main-path site ran interpreted or on a reference branch. A caller that
+wants the decisions of ONE trace (a prefill step, for the engine's
+``serving_prefill_attention_total``) wraps the traced call in
+:func:`recording`.
 """
 from __future__ import annotations
 
 import contextlib
 import threading
 from collections import Counter
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
 
@@ -103,10 +106,27 @@ def composites() -> Iterator[None]:
         _tls.composites = prev
 
 
+@contextlib.contextmanager
+def recording() -> Iterator[List[Tuple[str, str]]]:
+    """The ``(site, impl)`` decisions :func:`took` records on this thread
+    inside the block, in order. Decisions are made while a program is
+    TRACED: the list stays empty around a call that runs a program jit
+    already holds."""
+    prev = getattr(_tls, "recording", None)
+    _tls.recording = taken = []
+    try:
+        yield taken
+    finally:
+        _tls.recording = prev
+
+
 def took(site: str, impl: str, reason: Optional[str] = None) -> None:
     """Record that ``site`` chose ``impl``. A refusal (``impl`` is
     :data:`XLA` with a ``reason``) while targeting a TPU is logged once
     per (site, reason) — visible, not silent."""
+    taken = getattr(_tls, "recording", None)
+    if taken is not None:
+        taken.append((site, impl))
     with _lock:
         _paths[(site, impl)] += 1
         first = reason is not None and (site, reason) not in _logged

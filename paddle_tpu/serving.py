@@ -40,7 +40,7 @@ from .tensor_class import Tensor, unwrap
 from .framework import random as _random
 from .generation import (_get_prefill_step, _get_select_decode,
                          _get_select_decode_rows, _get_spec_decode,
-                         _memoized_step, _prefill_mask)
+                         _memoized_step, traced_attention_impl)
 
 
 #: default priority class — lower value is MORE important. 0 is the
@@ -1173,6 +1173,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             engine="decoder", kind="prompt")
         self._m_prefill_bucket = _metrics.SERVING_PREFILL_TOKENS.labels(
             engine="decoder", kind="bucket")
+        self._m_prefill_attention = {
+            impl: _metrics.SERVING_PREFILL_ATTENTION.labels(
+                engine="decoder", impl=impl)
+            for impl in ("flash", "append", "xla")}
 
     def _require_fit(self, n_prompt: int, max_new: int):
         """Slot-capacity admission check. With speculation on, every
@@ -2083,11 +2087,13 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         self._m_decode_rows.inc(rows)
         self._m_decode_cached.inc(cached)
 
-    def _count_prefill(self, n_tokens: int, bucket: int):
-        """One prefill program enqueued: the real tokens it computes and
-        the bucket it pads them to."""
+    def _count_prefill(self, n_tokens: int, bucket: int, impl: str):
+        """One prefill program enqueued: the real tokens it computes, the
+        bucket it pads them to, and the implementation its attention took
+        when the program was traced (``traced_attention_impl``)."""
         self._m_prefill_prompt.inc(n_tokens)
         self._m_prefill_bucket.inc(bucket)
+        self._m_prefill_attention[impl].inc()
 
     def _dispatch_span(self):
         """Annotation-only ``engine/<phase>/prefill_dispatch`` around the
@@ -3029,11 +3035,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             buf_keys, idx_scale = ("k_pages", "v_pages"), self._pages_per_slot
             poison_what = "page pool"
         bufs = [tuple(c[k] for k in buf_keys) for c in self._caches]
-        self._count_prefill(int(suf.size), sb)
         try:
             with self._dispatch_span():
-                last, new_bufs = fn(
-                    dict(self.model.functional_state()), bufs,
+                last, new_bufs = traced_attention_impl(
+                    fn, dict(self.model.functional_state()), bufs,
                     jnp.asarray(ids), jnp.asarray(int(suf.size), jnp.int32),
                     jnp.asarray(src * idx_scale, jnp.int32),
                     jnp.asarray(slot * idx_scale, jnp.int32))
@@ -3043,6 +3048,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 f"ContinuousBatchEngine: suffix prefill failed "
                 f"after the {poison_what} was donated; rebuild the engine "
                 f"and resubmit in-flight requests") from e
+        self._count_prefill(int(suf.size), sb, fn.attention_impl)
         for c_eng, new in zip(self._caches, new_bufs):
             for k, v in zip(buf_keys, new):
                 c_eng[k] = v
@@ -3091,21 +3097,30 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                               build)
 
     def _bucketed_prefill(self, req: _Request):
-        """Shared admission prefill: one prompt through the bucketed jitted
-        prefill step. Returns (last_logits [1,V], per-layer caches, S0,
-        bucket)."""
+        """Shared admission prefill: one prompt, padded on the RIGHT to its
+        bucket, through the bucket's one jitted prefill step. Returns
+        (last_logits [1,V], per-layer caches, S0, bucket).
+
+        No pad mask is built or passed, whatever S0: the row starts at
+        cache position 0 and every family's cached prefill attention is
+        causal (``cached_attention``, ``mla_cached_attention``), so a real
+        token at position s < S0 sees columns 0..s and never a pad. The
+        pads' own rows are never read: the last logit is gathered at
+        ``lengths - 1``, and their K/V land beyond ``_lengths[slot] = S0``,
+        which decode masks and then overwrites. A mask here changes no
+        result and takes every bucket off the flash kernel onto O(S^2) f32
+        score tensors (PERF.md, PR 34). A family whose prefill attention is
+        NOT causal from position 0 would have to declare that on its
+        attention layer and get its mask back here."""
         S0 = int(req.ids.size)
         bucket = self._bucket(S0)
-        ragged = S0 != bucket
-        self._count_prefill(S0, bucket)
 
         def run(prefill, inputs):
             with self._dispatch_span():
-                lengths = jnp.asarray([S0], jnp.int32)
-                # one mask program per BUCKET: the length is traced
-                pad_mask = _prefill_mask(lengths, bucket) if ragged \
-                    else None
-                return prefill(jnp.asarray(inputs), lengths, pad_mask)
+                out = prefill(jnp.asarray(inputs),
+                              jnp.asarray([S0], jnp.int32))
+            self._count_prefill(S0, bucket, prefill.attention_impl)
+            return out
 
         if req.pixel_values is not None:
             # multimodal admission: ONE jitted merge (vision tower +
@@ -3125,7 +3140,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             req.pixel_values = True
             embeds = jnp.zeros((1, bucket, merged.shape[-1]),
                                merged.dtype).at[:, :S0].set(merged)
-            prefill = _get_prefill_step_embeds(self.model, bucket, ragged,
+            prefill = _get_prefill_step_embeds(self.model, bucket, False,
                                                rope_len=self.max_len)
             last, caches = run(prefill, embeds)
             return last, caches, S0, bucket
@@ -3133,7 +3148,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         ids[0, :S0] = req.ids
         # rope provisioned at the engine's max_len so length-keyed rope
         # regimes (longrope) agree between this prefill and the decode step
-        prefill = _get_prefill_step(self.model, bucket, ragged,
+        prefill = _get_prefill_step(self.model, bucket, False,
                                     rope_len=self.max_len)
         last, caches = run(prefill, ids)
         return last, caches, S0, bucket

@@ -133,8 +133,9 @@ KERNELS = {
                                    ((HID, HK * D), BF16),
                                    ((HID, HK * D), BF16),
                                    ((B, D), F32), ((B, D), F32)]),
-    # the engine's ragged prefill (one prompt padded to its bucket) and
-    # generate(prefill_chunk_size=) against the full decode cache
+    # a prefix hit's suffix or a chunk of a chunked admission (one prompt,
+    # pad mask on) and generate(prefill_chunk_size=) against the full
+    # decode cache; the engine's admission prefill runs splash (below)
     "append_attention_prefill": (_append, [((1, 256, H, D), BF16),
                                            ((1, 256, HK, D), BF16),
                                            ((1, 256, HK, D), BF16),
@@ -243,6 +244,69 @@ def test_decode_write_falls_back_at_a_width_that_would_copy(chip, d):
     assert backend.paths() == {"kv_page_write": {backend.XLA: 1}}
     (reason,) = backend.refusals()["kv_page_write"]
     assert "128 lanes" in reason
+
+
+# the engine's admission prefill (benchmarks/configs/mistral-7b-v0.3-d20):
+# ONE prompt padded on the right to its bucket, 32 query / 8 KV heads of 128
+def _admission_attention(S, masked):
+    """``cached_attention`` as ``_PrefillStep`` reaches it through the
+    attention layer: fresh bucket-sized buffers, the static ``prefill``
+    marker, ``use_flash``; with ``masked`` also the pad mask the engine
+    built until PR 34."""
+    from paddle_tpu.generation import cached_attention
+
+    def call(q, k, v, cos, sin, k_buf, v_buf, *allowed):
+        return cached_attention(q, k, v, cos, sin, k_buf, v_buf, 0,
+                                *allowed, use_flash=True, prefill=True)
+
+    specs = [((1, S, H, D), BF16), ((1, S, HK, D), BF16),
+             ((1, S, HK, D), BF16), ((2048, D), F32), ((2048, D), F32),
+             ((1, S, HK, D), BF16), ((1, S, HK, D), BF16)]
+    if masked:
+        specs.append(((1, S), jnp.bool_))
+    return call, specs
+
+
+def _score_tensors(hlo):
+    """f32 array shapes in the HLO with two dimensions of 1024 or more:
+    a dense [.., S, T] attention score tensor."""
+    found = set()
+    for dims in re.findall(r"f32\[([\d,]+)\]", hlo):
+        if sum(int(n) >= 1024 for n in dims.split(",")) >= 2:
+            found.add(dims)
+    return sorted(found)
+
+
+@pytest.mark.parametrize("S", [128, 512, 1024, 2048])
+def test_admission_prefill_attention_is_one_splash_call(chip, S):
+    """No pad mask, so every bucket the kernel tiles takes it: one splash
+    call, and no O(S^2) f32 score tensor anywhere in the program."""
+    call, specs = _admission_attention(S, masked=False)
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+            for shape, dt in specs]
+    hlo = _lower_for_chip(call, args)
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and "= " in line]
+    assert len(calls) == 1 and "splash" in calls[0].split("=")[0], calls
+    assert backend.paths() == {"flash_attention": {backend.PALLAS: 1}}
+    assert _score_tensors(hlo) == []
+
+
+def test_a_pad_mask_on_the_admission_prefill_costs_the_kernel(chip):
+    """What the mask cost at the 2048 bucket until PR 34, pinned so that
+    a change that hands the engine's prefill a mask again is caught here:
+    splash is never asked, ``append_attention`` refuses 4 heads a group x
+    2048 rows, and the f32 composite materialises [8, 4, 2048, 2048]
+    scores (537 MB a layer)."""
+    call, specs = _admission_attention(2048, masked=True)
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+            for shape, dt in specs]
+    hlo = _lower_for_chip(call, args)
+    assert "tpu_custom_call" not in hlo
+    assert backend.paths() == {"append_attention": {backend.XLA: 1}}
+    assert backend.refusals()["append_attention"] == [
+        "4 x 2048 score rows exceed 2048"]
+    assert _score_tensors(hlo) != []
 
 
 def test_backend_target_decides_interpret_not_the_argument():

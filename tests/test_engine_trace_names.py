@@ -198,11 +198,8 @@ class _Compiled(logging.Handler):
 
 
 def _flow_engine():
-    from paddle_tpu import generation
-
-    generation._prefill_mask.clear_cache()   # one jit for every engine
     eng = _engine()
-    eng.add_request(_prompt(5), 3)               # ragged: bucket 8
+    eng.add_request(_prompt(5), 3)               # padded to bucket 8
     eng.add_request(_prompt(8), 3)               # fills its bucket
     eng.run_until_done()
 
@@ -264,8 +261,7 @@ def _flow_train_and_to_static():
 
 
 FLOWS = {
-    "engine": (_flow_engine, {"decode_step", "prefill", "prefill_ragged",
-                              "prefill_mask", "kv_scatter"}),
+    "engine": (_flow_engine, {"decode_step", "prefill", "kv_scatter"}),
     "engine_per_row_sampling": (_flow_engine_rows, {"decode_step_rows"}),
     "engine_speculative": (_flow_engine_speculative, {"spec_verify"}),
     "engine_prefix_cache": (_flow_engine_prefix_cache,
@@ -291,6 +287,11 @@ def test_every_program_is_compiled_under_its_role_name(flow):
         logger.removeHandler(seen)
     assert expected <= seen.names, sorted(seen.names)
     assert not {"pure", "pure_step"} & seen.names
+    if flow.startswith("engine"):
+        # ONE prefill program a bucket: a right-padded prompt under causal
+        # attention needs no pad mask, so the engine builds none
+        assert not {"prefill_ragged", "prefill_embeds_ragged",
+                    "prefill_mask"} & seen.names, sorted(seen.names)
 
 
 def test_no_two_roles_share_a_program_name():
