@@ -285,6 +285,84 @@ def _grouped_ffn(xe, w1, b1, w2, b2, activation: str):
     return jnp.einsum("ech,ehm->ecm", h, w2) + b2
 
 
+def dropless_expert_ffn(tokens, topk_idx, topk_w, w1, b1, w2, b2,
+                        activation: str, held=None, valid=None):
+    """The routed experts' output for EVERY (token, expert) pair whose
+    expert this device holds: nothing is dropped and no capacity exists.
+
+    tokens [T, M]; topk_idx / topk_w [T, K] (weights in f32, already
+    normalised over ALL K choices); w1 [H, M, F1], w2 [H, F, M] are the
+    ``H`` held experts' stacked weights, ``held = (lo, hi)`` their range in
+    the routing width (None: all of ``w1``'s). ``valid`` [T] marks the rows
+    whose output is read (a right-padded prefill passes its real rows):
+    the others route nowhere.
+
+    The T x K pairs are sorted by expert, absent and invalid pairs last,
+    and the held ones run through one grouped matmul per projection
+    (``jax.lax.ragged_dot``: XLA's own on every backend; measured against
+    the bundled megablox ``gmm`` on the v5e at 8192 and 32 tokens, PERF.md
+    PR 35) in chunks of a STATIC number of rows, a chunk past the last held pair
+    taking an empty branch: the work and the live memory
+    follow the pairs this device owns (T x K x held / experts in
+    expectation), never the T x K bound, and a pair of an absent expert
+    costs its place in the sort. Returns (out [T, M] in tokens' dtype,
+    counts [H] int32: pairs per held expert)."""
+    T, K = topk_idx.shape
+    M = tokens.shape[-1]
+    n_held = w1.shape[0]
+    lo = 0 if held is None else int(held[0])
+    local = topk_idx.astype(jnp.int32) - lo
+    is_held = (local >= 0) & (local < n_held)
+    if valid is not None:
+        is_held = is_held & valid[:, None]
+    P = T * K
+    key = jnp.where(is_held, local, n_held).reshape(P)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)   # sorted -> pair
+    place = jnp.argsort(order).astype(jnp.int32)              # pair -> sorted
+    counts = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    n_pairs = ends[-1]
+    # a chunk holds every pair of a decode step; a long prefill's pairs
+    # (about one a real token here) take a chunk or two of T rows
+    R = min(P, max(T, 256))
+    n_chunks = -(-P // R)
+    b1r, b2r = b1[:, 0], b2[:, 0]
+    # padded so that the last chunk's slice never clamps
+    order = jnp.pad(order, (0, n_chunks * R - P))
+
+    def chunk(i, ys):
+        base = i * R
+        rows = base + jnp.arange(R, dtype=jnp.int32)
+        pair = jax.lax.dynamic_slice_in_dim(order, base, R)
+        xs = tokens[pair // K]
+        gs = (jnp.clip(ends, base, base + R)
+              - jnp.clip(starts, base, base + R)).astype(jnp.int32)
+        expert = jnp.clip(jnp.searchsorted(ends, rows, side="right"),
+                          0, n_held - 1)
+        h = _expert_act(jax.lax.ragged_dot(xs, w1, gs) + b1r[expert],
+                        activation)
+        y = jax.lax.ragged_dot(h.astype(xs.dtype), w2, gs) + b2r[expert]
+        return jax.lax.dynamic_update_slice_in_dim(
+            ys, y.astype(ys.dtype), base, 0)
+
+    # static bounds (a scan: training differentiates through it); a chunk
+    # past the last held pair runs the empty branch
+    ys = jax.lax.fori_loop(
+        0, n_chunks,
+        lambda i, ys: jax.lax.cond(i * R < n_pairs, chunk,
+                                   lambda _, ys: ys, i, ys),
+        jnp.zeros((n_chunks * R, M), tokens.dtype))
+    place = place.reshape(T, K)
+    out = None
+    for j in range(K):
+        y = ys[place[:, j]].astype(jnp.float32)
+        y = jnp.where(is_held[:, j, None], topk_w[:, j, None] * y, 0.0)
+        out = y if out is None else out + y
+    return out.astype(tokens.dtype), counts
+
+
 class GroupedMLP(Layer):
     """All E experts' FFN weights stacked on a leading expert dim — the
     grouped-GEMM formulation (parity: fused_moe cutlass grouped GEMM,
@@ -318,32 +396,6 @@ class GroupedMLP(Layer):
         """xe: [E, C, M] → [E, C, M]."""
         return _grouped_ffn(xe, unwrap(self.w1), unwrap(self.b1),
                             unwrap(self.w2), unwrap(self.b2), self.activation)
-
-    def forward_ragged(self, x, group_sizes):
-        """Ragged grouped GEMM: x [T, M] tokens SORTED by expert,
-        group_sizes [E] (sum = T). Uses jax.lax.ragged_dot, which lowers to
-        the TPU grouped-matmul kernel (the role of the reference's cutlass
-        moe_gemm, fusion/cutlass/cutlass_kernels/moe_gemm/) — no padding to
-        a uniform capacity, so imbalanced expert loads waste no FLOPs."""
-        xs = unwrap(x)
-        gs = unwrap(group_sizes).astype(jnp.int32)
-        T = xs.shape[0]
-        try:  # loud failure beats silently-garbage trailing rows
-            total = int(gs.sum())
-            if total != T:
-                raise ValueError(
-                    f"forward_ragged: group_sizes sums to {total} but x has "
-                    f"{T} tokens")
-        except jax.errors.TracerIntegerConversionError:
-            pass  # traced sizes: shape agreement is the caller's contract
-        w1, b1 = unwrap(self.w1), unwrap(self.b1)
-        w2, b2 = unwrap(self.w2), unwrap(self.b2)
-        b1_tok = jnp.repeat(b1[:, 0], gs, axis=0, total_repeat_length=T)
-        b2_tok = jnp.repeat(b2[:, 0], gs, axis=0, total_repeat_length=T)
-        h = _expert_act(jax.lax.ragged_dot(xs, w1, gs) + b1_tok,
-                        self.activation)
-        out = jax.lax.ragged_dot(h, w2, gs) + b2_tok
-        return wrap(out)
 
     def forward(self, x):
         return wrap(self.forward_expert_batch(unwrap(x)))

@@ -178,7 +178,7 @@ def cached_attention(q, k, v, cos, sin, k_buf, v_buf, pos, allowed=None,
 
 def paged_cached_attention(q, k, v, cos, sin, k_pages, v_pages, page_indices,
                            lengths, page_size, window=None, softcap=None,
-                           rope_applied=False):
+                           rope_applied=False, ring=False):
     """Multi-token decode over the PAGED cache (in-layer dispatch).
 
     q [B,S,H,D]; pages [hk, n_pages, page_size, D]; lengths [B] = tokens
@@ -192,21 +192,32 @@ def paged_cached_attention(q, k, v, cos, sin, k_pages, v_pages, page_indices,
     chunk-causal mask). ``rope_applied``: q/k arrive already rotated
     (fused decode tail) — skip the per-row rope, keep the write +
     attention.
+
+    ``ring``: this layer's pool keeps a WINDOW per row, not the row: its
+    ``page_indices`` has ``ceil(window / page_size) + 1`` pages a row and
+    position ``p`` lives in page ``(p // page_size) mod`` that many (the
+    engine's pools by layer type: docs/SERVING.md). One token a step only.
     """
     B, S = q.shape[0], q.shape[1]
     lengths = jnp.asarray(lengths, jnp.int32)
     if not rope_applied:
         q = _rope_rows(q, cos, sin, lengths)
         k = _rope_rows(k, cos, sin, lengths)
+    if ring and S != 1:
+        raise NotImplementedError(
+            "a window ring takes one token a step: a chunk of "
+            f"{S} would overwrite keys its own first token still sees")
     if S == 1:
         page = lengths // page_size                 # [B]
+        if ring:
+            page = page % page_indices.shape[1]
         slot = lengths % page_size                  # [B]
         rows = page_indices[jnp.arange(B), page]    # [B]
         k_pages = _write_decode_rows(k_pages, rows, slot, k[:, 0])
         v_pages = _write_decode_rows(v_pages, rows, slot, v[:, 0])
         out = paged_decode_attention(q[:, 0], k_pages, v_pages, lengths + 1,
                                      page_indices, window=window,
-                                     softcap=softcap)
+                                     softcap=softcap, ring=ring)
         return out[:, None], k_pages, v_pages
     # speculative-verify chunk: scatter all S tokens at per-row positions
     # lengths[b]+j, then chunk-causal attention over the gathered pages.
@@ -263,7 +274,7 @@ def _paged_chunk_attention(q, k_pages, v_pages, lengths, page_indices,
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                            pages_per_compute_block=None, window=None,
-                           softcap=None):
+                           softcap=None, ring=False):
     """Decode attention over a paged cache: JAX's bundled Pallas kernel on
     TPU, a jnp gather reference (identical semantics) elsewhere.
 
@@ -276,7 +287,15 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
 
     ``pages_per_compute_block`` defaults to the largest divisor of
     pages-per-sequence <= 8: bigger blocks amortize the kernel's grid
-    overhead across more of the KV stream (HBM-bandwidth-bound op)."""
+    overhead across more of the KV stream (HBM-bandwidth-bound op).
+
+    ``ring``: the pool holds each row's last ``page_indices.shape[1]``
+    pages as a ring (``_paged_ring_attention``)."""
+    if ring:
+        _pallas_backend.took("paged_attention", _pallas_backend.XLA,
+                             "window ring")
+        return _paged_ring_attention(q, k_pages, v_pages, lengths,
+                                     page_indices, window, softcap=softcap)
     if window is not None:
         cache_positions = page_indices.shape[1] * k_pages.shape[2]
         if window < cache_positions:
@@ -349,6 +368,48 @@ def _paged_window_attention(q, k_pages, v_pages, lengths, page_indices,
     valid = (colpos < lengths[:, None]) & \
             (colpos >= (lengths[:, None] - window))
     return _banded_sdpa(q, k, v, valid, softcap=softcap)
+
+
+def _paged_ring_attention(q, k_pages, v_pages, lengths, page_indices,
+                          window, softcap=None):
+    """Sliding-window decode over a pool that keeps only a ring of
+    ``page_indices.shape[1]`` pages a row: position ``p`` lives at ring
+    index ``p mod W`` (``W`` = ring pages x page size, at least ``window +
+    page_size``), so index ``i`` of a row that holds ``lengths`` tokens
+    holds the newest position congruent to it, and is read iff that
+    position is inside the band. A ring pool is SLOT-MAJOR (row ``b`` owns
+    pages ``[b x ring, (b + 1) x ring)``: the engine allocates it so and
+    ``page_indices`` says the same), so the read is a reshape of the pool
+    and never a gather of it: K and V cross HBM once, in their own type,
+    with f32 scores and softmax. Attention does not see the order of its
+    keys, so nothing is rotated back."""
+    B, H, D = q.shape
+    hk, n_pages, page_size, _ = k_pages.shape
+    ring = page_indices.shape[1]
+    if n_pages != B * ring:
+        raise ValueError(
+            f"a ring pool is slot-major: {n_pages} pages are not "
+            f"{B} rows x {ring}")
+    W = ring * page_size
+    g = H // hk
+    k = k_pages.reshape(hk, B, W, D)
+    v = v_pages.reshape(hk, B, W, D)
+    newest = lengths[:, None] - 1                                # [B, 1]
+    held = newest - (newest - jnp.arange(W)[None, :]) % W       # [B, W]
+    valid = (held >= 0) & (held > newest - window)
+    # the batch dimensions in the POOL's order (head, row): in the other
+    # order XLA transposes both pools to it, a copy of each in every step
+    qk = jnp.moveaxis(q.reshape(B, hk, g, D), 0, 1)             # [hk,B,g,D]
+    scores = jnp.einsum("kbgd,kbtd->kbgt", qk, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(D)
+    if softcap is not None:
+        scores = softcap * jnp.tanh(scores / softcap)
+    scores = jnp.where(valid[None, :, None, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("kbgt,kbtd->kbgd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return jnp.moveaxis(out, 0, 1).reshape(B, H, D).astype(q.dtype)
 
 
 def _banded_sdpa(q, k, v, valid, softcap=None):
@@ -591,7 +652,8 @@ def _seen_from_prompt(ids, vocab, pad_mask=None):
 # decode step machinery
 # ---------------------------------------------------------------------------
 
-def _empty_caches(model, batch, max_len, allowed=None, row_pos=None):
+def _empty_caches(model, batch, max_len, allowed=None, row_pos=None,
+                  row_lengths=None):
     from .models.llama import head_dim_of
 
     cfg = model.config
@@ -621,6 +683,10 @@ def _empty_caches(model, batch, max_len, allowed=None, row_pos=None):
             c["allowed"] = allowed
         if row_pos is not None:
             c["row_pos"] = row_pos
+        if row_lengths is not None:
+            # a right-padded prefill's real rows: an expert layer routes
+            # no pad (models/llama_moe.valid_rows); attention ignores it
+            c["row_lengths"] = row_lengths
         caches.append(c)
     return caches
 
@@ -999,6 +1065,8 @@ class _PrefillStep:
     ``attention_impl``: which implementation the attention site took when
     this step was last traced (``traced_attention_impl``)."""
 
+    moe_counts = None
+
     def __init__(self, model, max_len, ragged, rope_len=None,
                  embeds_input=False):
         # rope_len decouples the cos/sin table length from the cache
@@ -1015,7 +1083,8 @@ class _PrefillStep:
                 B = ids_or_embeds.shape[0]
                 caches = _empty_caches(
                     model, B, max_len,
-                    allowed=pad_mask if ragged else None)
+                    allowed=pad_mask if ragged else None,
+                    row_lengths=None if ragged else lengths)
                 if embeds_input:
                     hidden, caches = model.llama.forward_cached(
                         None, caches, rope_len=rope_len,
@@ -1027,7 +1096,8 @@ class _PrefillStep:
                     unwrap(hidden),
                     (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)
                 last = unwrap(model.lm_head_logits(wrap(h_last)))[:, 0, :]
-            return last, _unwrap_caches(caches)
+            caches, counts = pop_moe_counts(_unwrap_caches(caches))
+            return last, caches, counts
 
         # the program's name says which variant ran: prefill,
         # prefill_ragged, prefill_embeds, prefill_embeds_ragged
@@ -1037,8 +1107,11 @@ class _PrefillStep:
         self._state = dict(model.functional_state())
 
     def __call__(self, ids, lengths, pad_mask=None):
-        return traced_attention_impl(self._jitted, self._state, ids,
-                                     lengths, pad_mask)
+        # moe_counts: what this call's expert layers counted (on the
+        # device; None for a model without one), for the engine's counters
+        last, caches, self.moe_counts = traced_attention_impl(
+            self._jitted, self._state, ids, lengths, pad_mask)
+        return last, caches
 
     @property
     def attention_impl(self):
@@ -1289,15 +1362,34 @@ def _engine_token_step(model, max_len, last, key, bufs, aux, lengths,
     new_lengths = None
     if lengths is not None:
         lengths = jnp.where(advance != 0, lengths, 0)
-        new_lengths = lengths + (advance > 0).astype(lengths.dtype)
-        aux = [dict(a, lengths=lengths) for a in aux]
+        live = (advance > 0).astype(lengths.dtype)
+        new_lengths = lengths + live
+        # row_lengths: an expert layer routes the live rows only
+        aux = [dict(a, lengths=lengths, row_lengths=live) for a in aux]
     nxt, lp, last_n, nb, na = _sample_and_forward(model, max_len, last, key,
                                                   bufs, aux, **sample)
+    na, counts = pop_moe_counts(na)
     if lengths is not None:
         # ONE lengths output, not one per layer (the model hands back its
         # own lengths + 1 in every layer's aux)
-        na = [{k: v for k, v in a.items() if k != "lengths"} for a in na]
-    return nxt, lp, last_n.astype(jnp.float32), nb, na, new_lengths
+        na = [{k: v for k, v in a.items()
+               if k not in ("lengths", "row_lengths")} for a in na]
+    return nxt, lp, last_n.astype(jnp.float32), nb, na, new_lengths, counts
+
+
+def pop_moe_counts(caches):
+    """(the layers' caches without ``moe_counts``, those counts summed over
+    layers): int32 [1 + held], the rows routed then the pairs per held
+    expert, as the expert layers of the forward just traced handed them
+    back in their caches (``models/llama_moe.with_moe_counts``); None for
+    a model without one. An output of the same program as the tokens: the
+    engine fetches both at once."""
+    counts = [unwrap(c["moe_counts"]) for c in caches
+              if isinstance(c, dict) and "moe_counts" in c]
+    if not counts:
+        return caches, None
+    return ([{k: v for k, v in c.items() if k != "moe_counts"}
+             if isinstance(c, dict) else c for c in caches], sum(counts))
 
 
 def _split_step_caches(caches, lengths):
@@ -1320,7 +1412,11 @@ class _SelectDecodeStep:
     the scan — the host must see each token for slot retirement). Called
     with the engine's ``lengths`` and ``advance`` code it also returns the
     advanced lengths (``_engine_token_step``): step N + 1's inputs are
-    all outputs of step N that never leave the device."""
+    all outputs of step N that never leave the device. ``moe_counts``
+    keeps the last call's expert-layer counts (a device array; None for a
+    model without expert layers)."""
+
+    moe_counts = None
 
     def __init__(self, model, max_len, do_sample, temperature, top_k, top_p):
         self._model = model
@@ -1337,7 +1433,7 @@ class _SelectDecodeStep:
 
     def __call__(self, last, key, caches, lengths=None, advance=None):
         bufs, aux = _split_step_caches(caches, lengths)
-        nxt, lp, last_f, nb, na, lengths = self._jitted(
+        nxt, lp, last_f, nb, na, lengths, self.moe_counts = self._jitted(
             self._state, last, key, bufs, aux, lengths, advance)
         return nxt, lp, last_f, _join_step_caches(nb, na, lengths), lengths
 
@@ -1346,6 +1442,8 @@ class _SelectDecodeRowsStep:
     """_SelectDecodeStep with PER-ROW sampling parameters as traced args:
     one compiled program serves any per-request greedy/temperature/top-k/
     top-p mix in the continuous-batching engine."""
+
+    moe_counts = None
 
     def __init__(self, model, max_len):
         self._model = model
@@ -1366,7 +1464,7 @@ class _SelectDecodeRowsStep:
     def __call__(self, last, key, do_s, temp, tk, tp, caches, lengths=None,
                  advance=None):
         bufs, aux = _split_step_caches(caches, lengths)
-        nxt, lp, last_f, nb, na, lengths = self._jitted(
+        nxt, lp, last_f, nb, na, lengths, self.moe_counts = self._jitted(
             self._state, last, key, do_s, temp, tk, tp, bufs, aux, lengths,
             advance)
         return nxt, lp, last_f, _join_step_caches(nb, na, lengths), lengths

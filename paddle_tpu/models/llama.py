@@ -568,7 +568,7 @@ class LlamaAttention(Layer):
                 kv_cache["v_pages"], kv_cache["page_indices"],
                 kv_cache["lengths"], kv_cache.get("page_size"),
                 window=self.window, softcap=softcap,
-                rope_applied=rope_applied)
+                rope_applied=rope_applied, ring="ring" in kv_cache)
             new = dict(kv_cache)
             new.update(k_pages=kp, v_pages=vp,
                        lengths=kv_cache["lengths"] + s)

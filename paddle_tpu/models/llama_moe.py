@@ -19,6 +19,7 @@ block and norms are reused from models/llama.py unchanged.
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +58,11 @@ class LlamaMoEConfig(LlamaConfig):
     # (DeepSeek-V2 group_limited_greedy / V3 noaux_tc)
     n_group: int = 1
     topk_group: int = 1
+    # the experts THIS model holds, as a contiguous range [lo, hi) of the
+    # n_routed_experts the router scores (one device's share of an
+    # expert-parallel deployment; docs/SERVING.md "A held share of the
+    # experts"). None: all of them
+    held_experts: Optional[tuple] = None
 
     @staticmethod
     def tiny_moe(**kw):
@@ -175,31 +181,68 @@ def pack_hf_experts(take, hf_prefix, n_experts, hidden_size,
     return w1, b1, w2, b2
 
 
+def valid_rows(kv_cache, s: int):
+    """[b, s] bool: the rows of a right-padded prefill that hold a real
+    token (``row_lengths`` on the fresh cache: ``generation._PrefillStep``);
+    None where the cache says nothing, and every row counts."""
+    n = kv_cache.get("row_lengths") if isinstance(kv_cache, dict) else None
+    if n is None:
+        return None
+    return jnp.arange(s)[None, :] < unwrap(n)[:, None]
+
+
+def with_moe_counts(new_cache, valid, counts):
+    """The layer's cache, carrying what its expert layer counted
+    (``moe_counts``), where the cache that came in said which rows are
+    real (``valid_rows``): the serving programs pass that and take the
+    counts out again (``generation.pop_moe_counts``); every other caller's
+    cache keeps the keys it came with."""
+    if valid is None or not isinstance(new_cache, dict):
+        return new_cache
+    return dict(new_cache, moe_counts=counts)
+
+
 class MoEMLP(Layer):
     """Routed experts + shared experts (DeepSeekMoE block).
 
-    The routed path is the dense GShard dispatch: router → top-k → capacity
-    positions → [S, E, C] combine/dispatch einsums → grouped FFN → combine.
-    All of it is one pure function per call, so GSPMD shards the expert dim
-    over the ep/data axes and the dispatch einsums become all_to_alls.
+    The router scores all ``n_routed_experts`` in f32; the layer holds the
+    experts ``held_experts = [lo, hi)`` of them (default all) and computes,
+    DROPLESSLY, every (token, expert) pair whose expert it holds
+    (``distributed.moe.dropless_expert_ffn``): the combine weights are
+    normalised over all of a token's choices, so a share's output is what
+    the whole layer's would hold of those experts, and the shares of a
+    deployment sum to it. Only under an expert-parallel mesh
+    (``_ep_axes``: training on the fleet topology) does the layer keep
+    the dense GShard dispatch with its capacity, whose einsums GSPMD
+    turns into the all_to_alls.
+
+    ``shared_scale`` multiplies the shared experts' summed output (a
+    family that AVERAGES its ``n`` shared experts passes ``1 / n``).
     """
 
-    def __init__(self, config: LlamaMoEConfig):
+    def __init__(self, config: LlamaMoEConfig, shared_scale: float = 1.0):
         super().__init__(dtype=config.dtype)
         from ..distributed.moe import (GroupedMLP, default_ep_axes,
                                        shard_grouped_experts)
         from ..framework.dtype import dtype_guard
 
         self.config = config
+        self.shared_scale = float(shared_scale)
         h = config.hidden_size
+        E = config.n_routed_experts
+        held = getattr(config, "held_experts", None)
+        self.held = (0, E) if held is None else (int(held[0]), int(held[1]))
+        if not 0 <= self.held[0] < self.held[1] <= E:
+            raise ValueError(
+                f"held_experts {held} is no range inside the "
+                f"{E} routed experts")
         self.gate_weight = self.create_parameter(
-            [h, config.n_routed_experts],
-            default_initializer=XavierUniform())
+            [h, E], default_initializer=XavierUniform())
         with dtype_guard(config.dtype):  # expert weights in the config dtype
             # SwiGLU experts (reference parity: DeepSeekMoE/Qwen2-MoE/ERNIE
             # experts are gate/up/down; the fused gate‖up keeps it one
             # grouped GEMM) — r5: was a plain 2-matmul silu FFN
-            self.experts = GroupedMLP(config.n_routed_experts, h,
+            self.experts = GroupedMLP(self.held[1] - self.held[0], h,
                                       config.moe_intermediate_size,
                                       activation="swiglu")
         # expert parallelism: when constructed under a hybrid topology, the
@@ -207,7 +250,11 @@ class MoEMLP(Layer):
         # defaults to the dp communicator) and the dispatch einsums become
         # all_to_alls at the EP boundary
         self._ep_axes = shard_grouped_experts(
-            self.experts, default_ep_axes(config.n_routed_experts))
+            self.experts, default_ep_axes(E))
+        if self._ep_axes and self.held != (0, E):
+            raise NotImplementedError(
+                "held_experts is one device's share: it cannot be sharded "
+                "again over an expert-parallel mesh")
         if config.n_shared_experts > 0:
             shared_cfg = dataclasses.replace(
                 config,
@@ -218,8 +265,7 @@ class MoEMLP(Layer):
             self.shared_expert = None
         if getattr(config, "moe_correction_bias", False):
             self.e_score_correction_bias = self.create_parameter(
-                [config.n_routed_experts],
-                default_initializer=Constant(0.0))
+                [E], default_initializer=Constant(0.0))
         else:
             self.e_score_correction_bias = None
         if getattr(config, "shared_expert_gate", False):
@@ -238,83 +284,81 @@ class MoEMLP(Layer):
 
         return ep_constrain(arr, self._ep_axes)
 
-    def forward(self, x):
-        from ..distributed.moe import compute_capacity, one_hot_dispatch
+    def forward(self, x, router_input=None, valid=None):
+        return self.forward_counted(x, router_input, valid)[0]
+
+    def forward_counted(self, x, router_input=None, valid=None):
+        """x [b, s, h]. ``router_input``: what the router scores, where it
+        is not ``x`` itself (a block that feeds the experts a bf16 cast
+        keeps the f32 norm output for the router: a rank-k / rank-k+1
+        near-tie decides which expert runs). ``valid`` [b, s] bool: the rows
+        whose output is read; the others route nowhere. Returns (out,
+        counts): counts int32 [1 + held], the rows routed, then the pairs
+        per held expert (the serving engine's counters)."""
+        from ..distributed.moe import (_grouped_ffn, compute_capacity,
+                                       dropless_expert_ffn, one_hot_dispatch)
 
         cfg = self.config
         b, s, h = x.shape[0], x.shape[1], x.shape[2]
         k = cfg.num_experts_per_tok
         E = cfg.n_routed_experts
 
-        def route_and_run(xf, gate_w, w1, b1, w2, b2, *sel_bias):
+        def route_and_run(xf, gate_w, w1, b1, w2, b2, sel_bias, r_in, ok):
             tokens = xf.reshape(-1, h)
             S = tokens.shape[0]
-            logits = (tokens.astype(jnp.float32)
-                      @ gate_w.astype(jnp.float32))
-            if cfg.moe_scoring_func == "sigmoid":
-                # DeepSeek-V3: per-expert sigmoid affinities (top-k over
-                # bias-corrected scores; combine weights renormalize below)
-                probs = jax.nn.sigmoid(logits)
-            elif cfg.moe_scoring_func == "softmax":
-                probs = jax.nn.softmax(logits, axis=-1)
-            else:
-                raise ValueError(
-                    f"moe_scoring_func must be 'softmax' or 'sigmoid', got "
-                    f"{cfg.moe_scoring_func!r}")
-            # aux-free balancing (HF Ernie4_5 moe_statics / DeepSeek-V3):
-            # the bias picks the experts, the raw probs weight the combine
-            sel = (probs + sel_bias[0].astype(jnp.float32) if sel_bias
-                   else probs)
-            if cfg.n_group > 1:
-                # group-limited selection (DeepSeek device-limited
-                # routing): keep only the topk_group best expert groups
-                # per token before the expert top-k. Group score: sum of
-                # the group's top-2 affinities under the aux-free bias
-                # (V3 noaux_tc), else the group max (V2
-                # group_limited_greedy).
-                G = cfg.n_group
-                if E % G != 0:
-                    raise ValueError(
-                        f"n_routed_experts {E} not divisible by n_group {G}")
-                if k > cfg.topk_group * (E // G):
-                    # top_k past the surviving experts would hand real
-                    # combine weight to -inf-masked (out-of-group) experts
-                    raise ValueError(
-                        f"num_experts_per_tok {k} exceeds the "
-                        f"{cfg.topk_group} allowed group(s) x {E // G} "
-                        f"experts/group")
-                sel_g = sel.reshape(S, G, E // G)
-                if sel_bias:
-                    top2, _ = jax.lax.top_k(sel_g, min(2, E // G))
-                    gscore = top2.sum(-1)
+            with jax.named_scope("moe/router"):
+                scored = (tokens if r_in is None else r_in.reshape(-1, h))
+                logits = jnp.dot(scored.astype(jnp.float32),
+                                 gate_w.astype(jnp.float32),
+                                 precision=jax.lax.Precision.HIGHEST)
+                if cfg.moe_scoring_func == "sigmoid":
+                    # DeepSeek-V3: per-expert sigmoid affinities (top-k over
+                    # bias-corrected scores; combine weights renormalize
+                    # below)
+                    probs = jax.nn.sigmoid(logits)
+                elif cfg.moe_scoring_func == "softmax":
+                    probs = jax.nn.softmax(logits, axis=-1)
                 else:
-                    gscore = sel_g.max(-1)
-                _, gidx = jax.lax.top_k(gscore, cfg.topk_group)
-                gmask = jnp.zeros((S, G), bool).at[
-                    jnp.arange(S)[:, None], gidx].set(True)
-                sel = jnp.where(jnp.repeat(gmask, E // G, axis=1),
-                                sel, -jnp.inf)
-            _, topk_idx = jax.lax.top_k(sel, k)
-            topk_p = jnp.take_along_axis(probs, topk_idx, axis=-1)
-            if cfg.norm_topk_prob:
-                topk_p = topk_p / jnp.maximum(
-                    topk_p.sum(-1, keepdims=True), 1e-20)
-            # re-scatter the (possibly renormalized) top-k weights to [S, E]
-            weights = jnp.zeros((S, E), probs.dtype).at[
-                jnp.arange(S)[:, None], topk_idx].set(topk_p)
-            cap = compute_capacity(S, E, k, cfg.moe_capacity_factor)
-            combine, dispatch = one_hot_dispatch(weights, topk_idx, cap)
-            # dispatch tokens: [S,E,C] x [S,M] -> [E,C,M]
-            xe = jnp.einsum("sec,sm->ecm", dispatch.astype(tokens.dtype),
-                            tokens)
-            xe = self._ep_constrain(xe)  # all_to_all boundary (EP)
-            from ..distributed.moe import _grouped_ffn
-
-            ye = _grouped_ffn(xe, w1, b1, w2, b2, "swiglu")
-            ye = self._ep_constrain(ye)
-            out = jnp.einsum("sec,ecm->sm", combine.astype(ye.dtype), ye)
+                    raise ValueError(
+                        f"moe_scoring_func must be 'softmax' or 'sigmoid', "
+                        f"got {cfg.moe_scoring_func!r}")
+                # aux-free balancing (HF Ernie4_5 moe_statics /
+                # DeepSeek-V3): the bias picks the experts, the raw probs
+                # weight the combine
+                sel = (probs + sel_bias.astype(jnp.float32)
+                       if sel_bias is not None else probs)
+                if cfg.n_group > 1:
+                    sel = self._group_limited(sel, sel_bias is not None, S)
+                _, topk_idx = jax.lax.top_k(sel, k)
+                topk_p = jnp.take_along_axis(probs, topk_idx, axis=-1)
+                if cfg.norm_topk_prob:
+                    topk_p = topk_p / jnp.maximum(
+                        topk_p.sum(-1, keepdims=True), 1e-20)
+            rows_ok = None if ok is None else ok.reshape(-1)
+            with jax.named_scope("moe/experts"):
+                if self._ep_axes:
+                    # training on an expert-parallel mesh: the dense GShard
+                    # dispatch, whose capacity drops in batch order
+                    weights = jnp.zeros((S, E), probs.dtype).at[
+                        jnp.arange(S)[:, None], topk_idx].set(topk_p)
+                    cap = compute_capacity(S, E, k, cfg.moe_capacity_factor)
+                    combine, dispatch = one_hot_dispatch(weights, topk_idx,
+                                                         cap)
+                    # dispatch tokens: [S,E,C] x [S,M] -> [E,C,M]
+                    xe = jnp.einsum("sec,sm->ecm",
+                                    dispatch.astype(tokens.dtype), tokens)
+                    xe = self._ep_constrain(xe)  # all_to_all boundary (EP)
+                    ye = _grouped_ffn(xe, w1, b1, w2, b2, "swiglu")
+                    ye = self._ep_constrain(ye)
+                    out = jnp.einsum("sec,ecm->sm", combine.astype(ye.dtype),
+                                     ye)
+                    counts = dispatch.sum((0, 2), dtype=jnp.int32)
+                else:
+                    out, counts = dropless_expert_ffn(
+                        tokens, topk_idx, topk_p, w1, b1, w2, b2, "swiglu",
+                        held=self.held, valid=rows_ok)
             if cfg.routed_scaling_factor != 1.0:
-                out = out * jnp.asarray(cfg.routed_scaling_factor, ye.dtype)
+                out = out * jnp.asarray(cfg.routed_scaling_factor, out.dtype)
             # Switch-style aux loss on the router DISTRIBUTION — sigmoid
             # affinities don't sum to 1, so the load measure always uses
             # the softmax of the logits
@@ -324,16 +368,22 @@ class MoEMLP(Layer):
             ce = jax.nn.one_hot(topk_idx[:, 0], E,
                                 dtype=dist.dtype).mean(0)
             aux = E * jnp.sum(me * ce)
-            return out.reshape(b, s, h).astype(xf.dtype), aux
+            n_tok = (jnp.asarray(S, jnp.int32) if rows_ok is None
+                     else rows_ok.sum(dtype=jnp.int32))
+            return (out.reshape(b, s, h).astype(xf.dtype), aux,
+                    jnp.concatenate([n_tok[None], counts]))
 
-        extra = ([self.e_score_correction_bias]
-                 if self.e_score_correction_bias is not None else [])
-        out, aux = apply("moe_mlp", route_and_run, x, self.gate_weight,
-                         self.experts.w1, self.experts.b1,
-                         self.experts.w2, self.experts.b2, *extra)
+        out, aux, counts = apply(
+            "moe_mlp", route_and_run, x, self.gate_weight,
+            self.experts.w1, self.experts.b1, self.experts.w2,
+            self.experts.b2, self.e_score_correction_bias, router_input,
+            valid)
         self._aux_loss = aux
         if self.shared_expert is not None:
-            shared = self.shared_expert(x)
+            with jax.named_scope("moe/shared"):
+                shared = self.shared_expert(x)
+                if self.shared_scale != 1.0:
+                    shared = shared * self.shared_scale
             if self.shared_gate_weight is not None:
                 # through apply(): the eager tape must record the gate so
                 # shared_gate_weight trains outside jit too
@@ -344,7 +394,36 @@ class MoEMLP(Layer):
                     ).astype(sh.dtype) * sh,
                     x, self.shared_gate_weight, shared)
             out = out + shared
-        return out
+        return out, counts
+
+    def _group_limited(self, sel, biased: bool, S: int):
+        """Group-limited selection (DeepSeek device-limited routing): keep
+        only the ``topk_group`` best expert groups per token before the
+        expert top-k. Group score: sum of the group's top-2 affinities
+        under the aux-free bias (V3 noaux_tc), else the group max (V2
+        group_limited_greedy)."""
+        cfg = self.config
+        E, G, k = cfg.n_routed_experts, cfg.n_group, cfg.num_experts_per_tok
+        if E % G != 0:
+            raise ValueError(
+                f"n_routed_experts {E} not divisible by n_group {G}")
+        if k > cfg.topk_group * (E // G):
+            # top_k past the surviving experts would hand real combine
+            # weight to -inf-masked (out-of-group) experts
+            raise ValueError(
+                f"num_experts_per_tok {k} exceeds the "
+                f"{cfg.topk_group} allowed group(s) x {E // G} "
+                f"experts/group")
+        sel_g = sel.reshape(S, G, E // G)
+        if biased:
+            top2, _ = jax.lax.top_k(sel_g, min(2, E // G))
+            gscore = top2.sum(-1)
+        else:
+            gscore = sel_g.max(-1)
+        _, gidx = jax.lax.top_k(gscore, cfg.topk_group)
+        gmask = jnp.zeros((S, G), bool).at[
+            jnp.arange(S)[:, None], gidx].set(True)
+        return jnp.where(jnp.repeat(gmask, E // G, axis=1), sel, -jnp.inf)
 
 
 class LlamaMoEDecoderLayer(Layer):
@@ -377,8 +456,9 @@ class LlamaMoEDecoderLayer(Layer):
 
         residual = hidden_states
         hidden_states = self.input_layernorm(hidden_states)
+        new_cache = None
         if kv_cache is not None:
-            hidden_states, kv_cache = self.self_attn(
+            hidden_states, new_cache = self.self_attn(
                 hidden_states, cos, sin, attention_mask, kv_cache)
         else:
             hidden_states = self.self_attn(hidden_states, cos, sin,
@@ -389,9 +469,16 @@ class LlamaMoEDecoderLayer(Layer):
             lambda a, r, w: fused_norm.add_rms_norm(a, r, w, eps),
             hidden_states, residual,
             self.post_attention_layernorm.effective_weight())
-        hidden_states = residual + self.mlp(hidden_states)
+        if self.is_moe:
+            valid = valid_rows(kv_cache, hidden_states.shape[1])
+            mlp_out, counts = self.mlp.forward_counted(hidden_states,
+                                                       valid=valid)
+            new_cache = with_moe_counts(new_cache, valid, counts)
+        else:
+            mlp_out = self.mlp(hidden_states)
+        hidden_states = residual + mlp_out
         if kv_cache is not None:
-            return hidden_states, kv_cache
+            return hidden_states, new_cache
         return hidden_states
 
 

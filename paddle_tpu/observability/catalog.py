@@ -109,6 +109,43 @@ SERVING_DECODE_DISCARDED_ROWS = _R.counter(
     "serving_decode_rows_total nor serving_decode_cached_tokens_total",
     labels=("engine",))
 
+SERVING_MOE_TOKENS = _R.counter(
+    "serving_moe_tokens_total",
+    "Rows routed by an expert layer, summed over the model's expert layers "
+    "(a token through four of them counts four): the real rows of a prefill "
+    "and the live rows of a decode step, counted by the programs and fetched "
+    "with the step's tokens",
+    labels=("engine",))
+
+SERVING_MOE_HELD_PAIRS = _R.counter(
+    "serving_moe_held_pairs_total",
+    "(token, expert) pairs of those rows whose expert this model holds, the "
+    "rows the grouped expert matmul computes; over serving_moe_tokens_total "
+    "it is top-k x held / routed experts in expectation",
+    labels=("engine",))
+
+SERVING_MOE_EXPERT_TOKENS = _R.counter(
+    "serving_moe_expert_tokens_total",
+    "The same pairs by held expert (expert = its index in the routing "
+    "width), summed over layers: max over mean is the load imbalance the "
+    "grouped matmul sees",
+    labels=("engine", "expert"))
+
+SERVING_DECODE_ROWS_OVER_WINDOW = _R.counter(
+    "serving_decode_rows_over_window_total",
+    "Delivered decode rows whose context was longer than the sliding "
+    "window of the engine's ring layers (their ring had wrapped); over "
+    "serving_decode_rows_total it is the share of rows a window layer "
+    "serves from a full ring. Only an engine with ring layers counts",
+    labels=("engine",))
+
+SERVING_KV_POOL_BYTES = _R.gauge(
+    "serving_kv_pool_bytes",
+    "Bytes the engine reserved for K/V pools at construction, by layer "
+    "type: layer_type=global a max_len of pages a slot, layer_type=window "
+    "a ring of ceil(window / page_size) + 1 pages a slot",
+    labels=("engine", "layer_type"))
+
 SERVING_PREFILL_TOKENS = _R.counter(
     "serving_prefill_tokens_total",
     "Tokens through the admission prefill programs: kind=prompt the real "

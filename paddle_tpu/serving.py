@@ -820,6 +820,10 @@ class _Flight(NamedTuple):
     logps: object
     rows: list
     t_dispatch: float
+    # expert-layer counts, still on the device: (this step's, the
+    # admission prefills' running sum as it stood when the step was
+    # enqueued), or None for a model without expert layers
+    moe: object = None
 
 
 class _ChunkState:
@@ -1065,25 +1069,58 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         # rows of the compressed buffers instead of the paged K/V pool
         make = getattr(model.llama, "empty_cache_layer", None)
         self._latent_mode = make is not None
+        # pages a slot owns in each layer's pool: max_len of them, or, in
+        # a model whose layers differ by type (``layer_types``), a RING of
+        # ceil(window / page_size) + 1 on a sliding-window layer (position
+        # p in page (p // page_size) mod ring; docs/SERVING.md "Pools by
+        # layer type"). None marks a layer that keeps the whole row. A
+        # model whose EVERY layer has the one window keeps whole rows: it
+        # serves today with prefix caching, chunked prefill, preemption,
+        # speculation, handoff and migration, which read a slot's pages as
+        # one run and which rings refuse (``_refuse_on_rings``; handoff
+        # and migration are calls, not options the constructor sees)
+        self._ring_pages = [None] * cfg.num_hidden_layers
         if self._latent_mode:
             self._caches = [dict(make(max_batch, max_len, dt),
                                  lengths=self._lengths)
                             for _ in range(cfg.num_hidden_layers)]
         else:
-            from .models.llama import head_dim_of
+            from .models.llama import head_dim_of, layer_window
 
             hk = cfg.num_key_value_heads
             d = head_dim_of(cfg)
-            n_pages = max_batch * self._pages_per_slot
-            page_indices = jnp.arange(n_pages, dtype=jnp.int32).reshape(
-                max_batch, self._pages_per_slot)
-            self._caches = [{
-                "k_pages": jnp.zeros((hk, n_pages, page_size, d), dt),
-                "v_pages": jnp.zeros((hk, n_pages, page_size, d), dt),
-                "page_indices": page_indices,
-                "lengths": self._lengths,
-                "page_size": page_size,
-            } for _ in range(cfg.num_hidden_layers)]
+            self._caches = []
+            tables = {}
+            for layer in range(cfg.num_hidden_layers):
+                pps = self._pages_per_slot
+                window = (layer_window(cfg, layer)
+                          if getattr(cfg, "layer_types", None) else None)
+                if window is not None and -(-window // page_size) + 1 < pps:
+                    pps = self._ring_pages[layer] = (
+                        -(-window // page_size) + 1)
+                if pps not in tables:
+                    tables[pps] = jnp.arange(
+                        max_batch * pps, dtype=jnp.int32).reshape(
+                            max_batch, pps)
+                cache = {
+                    "k_pages": jnp.zeros((hk, max_batch * pps, page_size, d),
+                                         dt),
+                    "v_pages": jnp.zeros((hk, max_batch * pps, page_size, d),
+                                         dt),
+                    "page_indices": tables[pps],
+                    "lengths": self._lengths,
+                    "page_size": page_size,
+                }
+                if self._ring_pages[layer] is not None:
+                    cache["ring"] = True    # the KEY is what attention reads
+                self._caches.append(cache)
+        self._has_rings = any(r is not None for r in self._ring_pages)
+        for feature, on in (("prefix caching", enable_prefix_cache),
+                            ("chunked prefill", prefill_chunk_tokens),
+                            ("preemption", enable_preemption),
+                            ("speculative decoding", speculative_k)):
+            if on:
+                self._refuse_on_rings(feature)
         self._last = jnp.zeros((max_batch, cfg.vocab_size), jnp.float32)
 
         self._poisoned = False
@@ -1177,6 +1214,61 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             impl: _metrics.SERVING_PREFILL_ATTENTION.labels(
                 engine="decoder", impl=impl)
             for impl in ("flash", "append", "xla")}
+        self._init_pool_and_moe_metrics()
+
+    def _refuse_on_rings(self, feature: str):
+        """THE refusal of everything that reads or writes a slot's pages
+        as one run of ``max_len / page_size`` in every layer: a window
+        layer's ring holds the last window only, in its own order."""
+        if self._has_rings:
+            raise NotImplementedError(
+                f"{feature} is not supported on a model whose sliding-window "
+                f"layers keep a window-sized ring of K/V pages (layers "
+                f"{[i for i, r in enumerate(self._ring_pages) if r]}): it "
+                "assumes every layer holds a slot's whole row")
+
+    def _init_pool_and_moe_metrics(self):
+        """The pools' bytes by layer type (a gauge, set once) and the
+        children of the expert layers' counters: one per held expert,
+        labelled by its index in the routing width."""
+        cfg = self.model.config
+        pool = {"global": 0, "window": 0}
+        if not self._latent_mode:
+            for c, ring in zip(self._caches, self._ring_pages):
+                pool["window" if ring else "global"] += (
+                    c["k_pages"].nbytes + c["v_pages"].nbytes)
+        for kind, nbytes in pool.items():
+            _metrics.SERVING_KV_POOL_BYTES.labels(
+                engine="decoder", layer_type=kind).set(nbytes)
+        self._ring_window = (int(cfg.sliding_window) if self._has_rings
+                             else None)
+        self._m_rows_over_window = (
+            _metrics.SERVING_DECODE_ROWS_OVER_WINDOW.labels(engine="decoder"))
+        self._m_moe_tokens = _metrics.SERVING_MOE_TOKENS.labels(
+            engine="decoder")
+        self._m_moe_pairs = _metrics.SERVING_MOE_HELD_PAIRS.labels(
+            engine="decoder")
+        lo, hi = (getattr(cfg, "held_experts", None)
+                  or (0, getattr(cfg, "n_routed_experts", 0)))
+        self._m_moe_expert = [
+            _metrics.SERVING_MOE_EXPERT_TOKENS.labels(engine="decoder",
+                                                      expert=str(e))
+            for e in range(lo, hi)]
+        # the admission prefills' counts, summed on the device as they are
+        # enqueued; a decode step's record carries the sum as it stood, and
+        # its retirement counts what came since the one before
+        self._moe_prefills = None
+        self._moe_prefills_seen = 0
+
+    def _count_moe(self, counts) -> None:
+        """``counts``: int array [1 + held], rows routed then pairs per
+        held expert (``generation.moe_counts``), already on the host."""
+        if not counts[0]:
+            return          # no row routed (no prefill since the last step)
+        self._m_moe_tokens.inc(int(counts[0]))
+        self._m_moe_pairs.inc(int(counts[1:].sum()))
+        for child, n in zip(self._m_moe_expert, counts[1:]):
+            child.inc(int(n))
 
     def _require_fit(self, n_prompt: int, max_new: int):
         """Slot-capacity admission check. With speculation on, every
@@ -1491,6 +1583,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             raise NotImplementedError(
                 "KV handoff is not supported in latent (MLA) mode — the "
                 "compressed cache rows are engine-layout-specific")
+        self._refuse_on_rings("KV handoff")
         ids = np.asarray(unwrap(ids) if isinstance(ids, Tensor)
                          else ids).reshape(-1)
         if ids.size + max_new_tokens > self.max_len:
@@ -1542,6 +1635,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         if self._latent_mode:
             raise NotImplementedError(
                 "KV handoff is not supported in latent (MLA) mode")
+        self._refuse_on_rings("KV handoff")
         verify_bundle(handoff, kind="prefill")
         bucket = int(handoff["bucket"])
         if bucket % self.page_size != 0 or bucket > self.max_len:
@@ -1585,11 +1679,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             sum(k.nbytes + v.nbytes for k, v in h["layers"])))
         c_new = [{"k": jnp.asarray(k)[None], "v": jnp.asarray(v)[None]}
                  for k, v in h["layers"]]
-        base = slot * self._pages_per_slot
         pages = [(c["k_pages"], c["v_pages"]) for c in self._caches]
         try:
             new_pages = self._scatter_fn(bucket)(
-                pages, c_new, jnp.asarray(base, jnp.int32))
+                pages, c_new, jnp.asarray([slot, S0], jnp.int32))
         except Exception as e:
             # same donation-failure protocol as a local prefill: the page
             # pool may be gone, so poison instead of limping on
@@ -1624,6 +1717,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             raise NotImplementedError(
                 "migration is not supported in latent (MLA) mode — the "
                 "compressed cache rows are engine-layout-specific")
+        self._refuse_on_rings("migration")
         self._drain_in_flight()  # the bundle holds every token decoded
         slot = next((s for s, r in enumerate(self._slots)
                      if r is not None and r.rid == rid), None)
@@ -1694,6 +1788,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         if self._latent_mode:
             raise NotImplementedError(
                 "migration is not supported in latent (MLA) mode")
+        self._refuse_on_rings("migration")
         verify_bundle(handoff, kind="migrate")
         bucket = int(handoff["bucket"])
         if bucket % self.page_size != 0 or bucket > self.max_len:
@@ -1933,7 +2028,9 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             self._last, _random.next_key(), *rows_knobs, self._caches,
             self._lengths, advance)
         self._m_dispatch["ahead" if before is not None else "drained"].inc()
-        return _Flight(nxt, logps, rows, t_dispatch)
+        moe = (None if step.moe_counts is None
+               else (step.moe_counts, self._moe_prefills))
+        return _Flight(nxt, logps, rows, t_dispatch, moe)
 
     def _drain_in_flight(self, clk=None) -> None:
         """Fetch and retire the step in flight, if there is one, so that
@@ -1962,8 +2059,17 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         # other host conversion below reads these already-fetched arrays
         toks = np.asarray(flight.nxt)
         lps = np.asarray(flight.logps)
+        # outputs of the same program (and of prefills enqueued before
+        # it): the fetch waits for nothing the tokens did not wait for
+        moe = None if flight.moe is None else tuple(
+            None if a is None else np.asarray(a) for a in flight.moe)
         if clk is not None:
             clk.open("retire")
+        if moe is not None:
+            self._count_moe(moe[0])
+            if moe[1] is not None:
+                self._count_moe(moe[1] - self._moe_prefills_seen)
+                self._moe_prefills_seen = moe[1]
         if self._in_flight is None:
             self._clear_dispatch_guard()  # step success: blame record erased
         inj = _chaos.active()
@@ -2011,12 +2117,15 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         # already-advanced device step
         at = self.kvatlas
         at_on = at.enabled  # hoisted: one predicate for the whole loop
-        n_rows = cached = 0
+        n_rows = cached = over_window = 0
         for s, req in flight.rows:
             if self._slots[s] is not req:
                 continue
             n_rows += 1
             cached += int(req.ids.size) + len(req.tokens)
+            if self._ring_window is not None and (
+                    int(req.ids.size) + len(req.tokens) > self._ring_window):
+                over_window += 1
             req.dispatches += 1
             t = int(toks[s])
             req.tokens.append(t)
@@ -2049,6 +2158,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         # would inflate the rows and the context a step is credited with
         self._m_decode_rows.inc(n_rows)
         self._m_decode_cached.inc(cached)
+        self._m_rows_over_window.inc(over_window)
         self._m_discarded.inc(len(flight.rows) - n_rows)
         for s in retiring:
             req = self._slots[s]
@@ -2339,9 +2449,21 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 return s
         return -1
 
+    #: a prompt longer than twice this pads to the next multiple of it
+    _BUCKET_STEP = 1024
+
     def _bucket(self, n: int) -> int:
-        """Prompt-length bucket: next power of two, page-aligned — bounds
-        the number of prefill jit programs to O(log max_len)."""
+        """Prompt-length bucket, page-aligned: the next power of two up to
+        ``2 * _BUCKET_STEP``, beyond that the next multiple of
+        ``_BUCKET_STEP``. A padded token costs what a real one costs, and
+        between two powers of two a long prompt's cost would hang on
+        which side of the lower one its length fell (4100 tokens in the
+        program of 8192: PERF.md, PR 35). The prefill programs are O(log)
+        short ones and ``max_len / _BUCKET_STEP`` long ones, each built
+        when a prompt first needs it."""
+        step = -(-self._BUCKET_STEP // self.page_size) * self.page_size
+        if n > 2 * step:
+            return min(-(-n // step) * step, self.max_len)
         b = self.page_size
         while b < n:
             b *= 2
@@ -2618,11 +2740,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         bucket, kv_len = int(r["bucket"]), int(r["kv_len"])
         c_new = [{"k": jnp.asarray(k)[None], "v": jnp.asarray(v)[None]}
                  for k, v in r["layers"]]
-        base = slot * self._pages_per_slot
         pages = [(c["k_pages"], c["v_pages"]) for c in self._caches]
         try:
             new_pages = self._scatter_fn(bucket)(
-                pages, c_new, jnp.asarray(base, jnp.int32))
+                pages, c_new, jnp.asarray([slot, kv_len], jnp.int32))
         except Exception as e:
             self._poisoned = True
             raise RuntimeError(
@@ -2705,7 +2826,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 take = min(ct, S0)
                 first = _Request(-1, req.ids[:take], 0)
                 last, caches, _, bucket = self._bucketed_prefill(first)
-                self._scatter_prefill(slot, last, caches, bucket)
+                self._scatter_prefill(slot, last, caches, bucket, take)
                 st.pos = take
         else:
             take = min(ct, S0 - st.pos)
@@ -2769,17 +2890,30 @@ class ContinuousBatchEngine(_RequestBookkeeping):
         same model reuses the compiled scatter."""
         ps = self.page_size
         n_pages = bucket // ps
+        rings = tuple(self._ring_pages)
+        pps = self._pages_per_slot
 
         def build():
-            def kv_scatter(pages, bufs, base):
+            def kv_scatter(pages, bufs, where):
+                """``where`` = int32 [slot, real tokens]. A layer that
+                keeps the whole row takes the bucket's pages in order; a
+                ring takes, for each of its pages, the NEWEST page of the
+                prompt that maps to it (page j of the prompt lives in
+                ring page j mod ring), which for a prompt shorter than
+                the ring is page j."""
+                slot, newest = where[0], (where[1] - 1) // ps
                 out = []
-                for (kp, vp), c_new in zip(pages, bufs):
+                for (kp, vp), c_new, ring in zip(pages, bufs, rings):
                     new = []
                     for pg, key in ((kp, "k"), (vp, "v")):
-                        buf = c_new[key][0]              # [bucket, hk, D]
-                        tiles = _page_tiles(buf, ps)
+                        tiles = _page_tiles(c_new[key][0], ps)
+                        if ring is not None and n_pages > ring:
+                            at = jnp.arange(ring, dtype=jnp.int32)
+                            src = newest - (newest - at) % ring
+                            tiles = tiles[:, jnp.clip(src, 0, n_pages - 1)]
                         new.append(jax.lax.dynamic_update_slice(
-                            pg, tiles.astype(pg.dtype), (0, base, 0, 0)))
+                            pg, tiles.astype(pg.dtype),
+                            (0, slot * (ring or pps), 0, 0)))
                     out.append(tuple(new))
                 return out
 
@@ -2787,8 +2921,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             fn._state = None  # _memoized_step refresh hook (stateless)
             return fn
 
+        # pps is in the key: another engine over the same model (another
+        # max_len) strides its slots differently
         return _memoized_step(self.model, "_page_scatter_fns",
-                              (bucket, ps), build)
+                              (bucket, ps, pps, rings), build)
 
     # ---- prefix caching ------------------------------------------------------
     def _multimodal_merge_fn(self, ids_shape, px_shape):
@@ -3120,6 +3256,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 out = prefill(jnp.asarray(inputs),
                               jnp.asarray([S0], jnp.int32))
             self._count_prefill(S0, bucket, prefill.attention_impl)
+            if prefill.moe_counts is not None:
+                self._moe_prefills = (
+                    prefill.moe_counts if self._moe_prefills is None
+                    else self._moe_prefills + prefill.moe_counts)
             return out
 
         if req.pixel_values is not None:
@@ -3165,7 +3305,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 return self._prefill_with_prefix_latent(slot, req, src,
                                                         n_pref)
         last, caches, S0, bucket = self._bucketed_prefill(req)
-        self._scatter_prefill(slot, last, caches, bucket)
+        self._scatter_prefill(slot, last, caches, bucket, S0)
         self._lengths = self._lengths.at[slot].set(S0)
 
     def _prefill_into(self, slot: int, req: _Request):
@@ -3182,15 +3322,17 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             if n_pref > 0:
                 return self._prefill_with_prefix(slot, req, src, n_pref)
         last, caches, S0, bucket = self._bucketed_prefill(req)
-        self._scatter_prefill(slot, last, caches, bucket)
+        self._scatter_prefill(slot, last, caches, bucket, S0)
         self._lengths = self._lengths.at[slot].set(S0)
 
-    def _scatter_prefill(self, slot: int, last, caches, bucket: int):
+    def _scatter_prefill(self, slot: int, last, caches, bucket: int,
+                         length: int):
         """Scatter one bucketed prefill's caches into ``slot`` (pages or
         latent rows) and seed its last-logit row. Shared by monolithic
         admission and the FIRST chunk of a chunked admission — does NOT
         set _lengths (the caller publishes the slot when the whole
-        prompt is in)."""
+        prompt is in). ``length``: the real tokens among the bucket's, by
+        which a ring layer's scatter places its pages."""
         if self._latent_mode:
             bufs = [(c["c_kv"], c["k_pe"]) for c in self._caches]
             try:
@@ -3207,12 +3349,11 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             for c_eng, (ckv, kpe) in zip(self._caches, new_bufs):
                 c_eng["c_kv"], c_eng["k_pe"] = ckv, kpe
         else:
-            base = slot * self._pages_per_slot
             pages = [(c["k_pages"], c["v_pages"]) for c in self._caches]
             try:
                 with self._dispatch_span():
                     new_pages = self._scatter_fn(bucket)(
-                        pages, caches, jnp.asarray(base, jnp.int32))
+                        pages, caches, jnp.asarray([slot, length], jnp.int32))
             except Exception as e:
                 # the scatter DONATES the page pool: a mid-admission
                 # failure (device OOM etc.) may have invalidated it,

@@ -304,34 +304,42 @@ def test_naive_gate_default_capacity_finite():
     assert compute_capacity(128, 4, 2, 2.0) == 128
 
 
-def test_grouped_mlp_ragged_matches_batch():
-    """ragged_dot grouped GEMM == looped per-expert FFN on sorted tokens."""
-    from paddle_tpu.distributed.moe import GroupedMLP
-
-    paddle.seed(0)
-    E, M, H = 3, 8, 16
-    mlp = GroupedMLP(E, M, H, activation="gelu")
-    rng = np.random.RandomState(1)
-    sizes = np.array([4, 0, 6])  # includes an empty expert
-    x = rng.randn(int(sizes.sum()), M).astype("float32")
-    out = mlp.forward_ragged(paddle.to_tensor(x),
-                             paddle.to_tensor(sizes.astype("int32"))).numpy()
-
-    # reference: run each expert's slice through its own weights
-    w1 = mlp.w1.numpy(); b1 = mlp.b1.numpy()
-    w2 = mlp.w2.numpy(); b2 = mlp.b2.numpy()
+def test_dropless_ffn_matches_looped_experts():
+    """The dropless expert FFN (sort by expert, grouped matmul, combine)
+    == a loop over every (token, expert) pair, with biases, an expert no
+    token chose, and a held range that is a slice of the experts."""
     import jax
 
-    start = 0
-    for e, n in enumerate(sizes):
-        if n == 0:
-            continue
-        seg = x[start:start + n]
-        h = np.asarray(jax.nn.gelu(seg @ w1[e] + b1[e, 0], approximate=False))
-        ref = h @ w2[e] + b2[e, 0]
-        np.testing.assert_allclose(out[start:start + n], ref, rtol=2e-4,
-                                   atol=2e-5)
-        start += n
+    from paddle_tpu.distributed.moe import GroupedMLP, dropless_expert_ffn
+
+    paddle.seed(0)
+    E, M, H, T, K = 5, 8, 16, 11, 2
+    mlp = GroupedMLP(3, M, H, activation="gelu")        # holds experts 1..3
+    rng = np.random.RandomState(1)
+    for p_ in (mlp.b1, mlp.b2):
+        p_.set_value(paddle.to_tensor(
+            rng.standard_normal(p_.shape).astype("float32")))
+    x = rng.randn(T, M).astype("float32")
+    idx = np.stack([rng.permutation([0, 1, 3, 4])[:K] for _ in range(T)])
+    w = rng.rand(T, K).astype("float32")
+    w1 = mlp.w1.numpy(); b1 = mlp.b1.numpy()
+    w2 = mlp.w2.numpy(); b2 = mlp.b2.numpy()
+    out, counts = dropless_expert_ffn(
+        jax.numpy.asarray(x), jax.numpy.asarray(idx, "int32"),
+        jax.numpy.asarray(w), *(jax.numpy.asarray(a) for a in
+                                (w1, b1, w2, b2)), "gelu", held=(1, 4))
+    ref = np.zeros((T, M), "float32")
+    seen = np.zeros(3, int)
+    for t in range(T):
+        for j in range(K):
+            e = idx[t, j] - 1
+            if 0 <= e < 3:
+                seen[e] += 1
+                h = np.asarray(jax.nn.gelu(x[t] @ w1[e] + b1[e, 0],
+                                           approximate=False))
+                ref[t] += w[t, j] * (h @ w2[e] + b2[e, 0])
+    assert counts.tolist() == seen.tolist() and seen[1] == 0   # expert 2
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-5)
 
 
 def test_llama_moe_ep_sharded_flagship():
