@@ -26,6 +26,33 @@ inside, layer by layer, so that it fits beside the served model.
   ``ids[1:]`` given ``ids[:-1]``; only where the configuration has a
   ``train_job`` cell.
 
+**What a reference with a top-k router defines besides** (``cohere2_moe``;
+``decoder`` has no router and none of this). A top-k choice is a
+discontinuous function: where the k-th and the (k+1)-th score lie closer
+than the error of a bf16 pipeline, both choices are the model's answer,
+as both sides of a near-tied argmax are (which is why the comparison
+teacher-forces the engine's TOKENS). So such a reference is set-valued at
+its OWN near-ties, and only there; it is never shown the program's
+routing.
+
+- ``near_tie_alternatives(spec, state, ids, position) -> [{"forced":
+  ..., "swaps": [{"layer", "out", "in", "gap"}]}, ...]``: the alternate
+  routings of ONE position of ``ids``: ONE swap each across the top-k
+  boundary, in any layer, between a chosen and an unchosen expert whose
+  router LOGITS (before the activation) lie less than the module's
+  ``ROUTING_TIE_GAP`` apart and of which at least one is held here;
+  nearest tie first, a bounded few (every one offered is one more chance
+  that a faulty token is excused). The constant is MEASURED
+  (``tools/routing_diff.py`` on the chip; PERF.md section 2 has the
+  readings) and stated with the share of positions and of answered tokens
+  that have an alternative at all.
+- ``forward_logprobs(..., forced=alternative["forced"])``: the same pass
+  with the rows ``{(layer, position): experts}`` routed as told.
+
+``lib.serve.compare_logprobs`` looks for ``near_tie_alternatives`` and for
+nothing else: a module without it is compared on its one routing-free
+pass, as before.
+
 **What it may define**, for the per-layer readers of its configuration's
 cells. A reader asks through ``lib.common.reference_function``; where the
 cell's module lacks the function the reader returns None and notes which:

@@ -118,6 +118,29 @@ def test_every_per_layer_metric_has_a_reader_that_agrees(manifest):
                                                                        0.0)
 
 
+def test_a_reference_with_a_router_offers_its_near_ties(manifest):
+    """``compare_logprobs`` reads a reference's near-ties iff the module
+    defines ``near_tie_alternatives`` (then ``compared`` carries
+    ``logprob_err_nats_own_routing``, ``tokens_over_limit_own_routing``
+    and ``tokens_on_alternate_routing`` too): the sparse configuration's does, with a gap that was measured
+    and a ``forward_logprobs`` that can be told a routing; a dense one's
+    has no router and stays as it was."""
+    import inspect
+
+    routed = set()
+    for w in manifest["workloads"]:
+        ref = common.Cell(w["name"]).reference()
+        assert callable(ref.forward_logprobs), w["name"]
+        if hasattr(ref, "near_tie_alternatives"):
+            routed.add(w["name"])
+            assert 0.0 < ref.ROUTING_TIE_GAP < 0.1
+            assert "forced" in inspect.signature(
+                ref.forward_logprobs).parameters
+        else:
+            assert not hasattr(ref, "route"), w["name"]
+    assert routed == {"commandaplus_rag_batch"}
+
+
 def test_file_names_use_the_characters_of_a_name():
     for dirpath, dirnames, filenames in os.walk(common.BENCH):
         dirnames[:] = [d for d in dirnames

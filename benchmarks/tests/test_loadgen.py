@@ -66,7 +66,8 @@ def test_every_seed_offers_the_same_work():
              sum(r["max_tokens"] for r in reqs if r["t"] >= 0),
              sum(1 for r in reqs if r["t"] >= 0))
             for reqs in (s.open_loop(chat, seed, 50.0) for seed in range(5))}
-    assert len(work) == 1 and next(iter(work))[2] == 123
+    assert len(work) == 1
+    assert next(iter(work))[2] == round(chat["rate_per_s"] * 50.0) == 220
     docs = mix("docs_batch")
     for seed in (1, 2):
         stream = s.closed_loop_client(docs, seed, 0)

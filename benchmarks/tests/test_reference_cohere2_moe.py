@@ -1,15 +1,20 @@
 """``reference/cohere2_moe.py`` to the contract, as ``test_reference.py``
 does for ``decoder``: on the CPU at the configuration's own rehearsal
 sizes, float32 on both sides, so the program and the reference agree to
-rounding; a control (the comparison passes the program as it is) and two
-planted faults (a dropped shared expert, a window one short) that the
-harness's own comparison, with its own limit, must catch."""
+rounding; the program as it is (the comparison passes it), the CONTROL
+(the reference from fp8 or int8 weights in the program's place) and
+planted faults (a dropped shared expert, a window one short, a routed
+expert zeroed, one expert too few a token, a wrong routing that is no
+near-tie) that the harness's own comparison must catch, also with its
+limits drawn down to these widths, where the near-tie rule engages
+(``lib/planted.py``; ``tools/planted_faults.py`` reads the same at the
+cell's own size on the chip)."""
 import os
 
 import numpy as np
 import pytest
 
-from benchmarks.lib import build, common, serve
+from benchmarks.lib import build, common, planted, serve
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -77,36 +82,176 @@ def test_reference_logprobs_equal_the_models(cell):
     assert np.abs(got - want).max() < 2e-4
 
 
-def test_control_and_planted_faults(cell):
-    """``compare_logprobs`` on the engine's own answers: the program as it
-    is reads rounding (float32 on both sides here); a shared expert
-    dropped from the REFERENCE's weights, or a window of 31 for 32, reads
-    at least a thousand times that. (At these widths the head's logits
-    have a std of 0.2, so no fault reaches the harness's bf16 limit of
-    0.15 nats; what the test pins is that the comparison SEES each
-    fault, by orders of magnitude.)"""
+#: the harness's limit, drawn down to the rehearsal's widths (the head's
+#: logits have a std of 0.2 here, so no fault reaches 0.15 nats and the
+#: near-tie rule never engages): 20 times the sound program's rounding
+SCALED_TOL = 1e-4
+
+
+@pytest.fixture(scope="module")
+def served(cell):
+    """Four prompts, the engine's own answers, and what the program as
+    it is reads: rounding (float32 on both sides here), standing on no
+    alternate routing."""
     cfg, ref, spec, model = cell
     rng = np.random.RandomState(2)
     prompts = [rng.randint(1, cfg["vocab_size"], n).tolist()
                for n in (24, 42, 75, 100)]
     replies = answers(cfg, model, prompts)
+    ok, compared, rows = serve.compare_logprobs(
+        ref, spec, build.plain_state(model), prompts, replies)
+    sound = compared["logprob_err_nats"]["value"]
+    assert ok and sound < 5e-6 and len(rows) == 4
+    assert compared["tokens_on_alternate_routing"]["value"] == 0
+    assert compared["logprob_err_nats_own_routing"]["value"] == sound
+    return prompts, replies, sound
+
+
+@pytest.mark.parametrize("limits", ["as_they_are", "drawn_down_to_the_widths"])
+@pytest.mark.parametrize("what", ("none",) + planted.LOWER_PRECISIONS
+                         + planted.FAULTS)
+def test_control_and_planted_faults(cell, served, what, limits, monkeypatch):
+    """``compare_logprobs`` on the program as it is, on the contract's
+    CONTROL (the reference itself from fp8 / int8 weights, put in the
+    program's place on the same prompts and tokens) and on the engine's
+    own answers with each fault of ``lib/planted.py`` planted on the
+    reference's side: a window of 31 for 32, top-1 for top-2, the second
+    shared expert dropped, a routed held expert zeroed. The control and
+    every fault read at least a thousand times what the program does. (At
+    these widths none reaches the harness's bf16 limit of 0.15 nats; what
+    the first set of cases pins is that the comparison SEES each, by
+    orders of magnitude.) With the limit drawn down to the widths a
+    faulty token IS over it and is read again on its position's
+    near-ties: the rule excuses neither the control nor a fault, and the
+    program still passes on the reference's own routing alone."""
+    _, ref, spec, model = cell
+    prompts, replies, sound = served
+    if limits == "drawn_down_to_the_widths":
+        monkeypatch.setattr(serve, "LOGPROB_TOL", SCALED_TOL)
     state = build.plain_state(model)
-    ok, control, rows = serve.compare_logprobs(ref, spec, state, prompts,
-                                               replies)
-    assert ok and control < 5e-6 and len(rows) == 4
+    judge = planted.plant(what, spec, state) if what in planted.FAULTS \
+        else (spec, state)
+    if what in planted.LOWER_PRECISIONS:
+        replies = planted.control_answers(ref, spec, state, what, prompts,
+                                          replies)
+    ok, compared, _ = serve.compare_logprobs(ref, *judge, prompts, replies)
+    worst = compared["logprob_err_nats"]["value"]
+    if what == "none":
+        assert ok and worst == sound
+        assert compared["tokens_on_alternate_routing"]["value"] == 0
+    else:
+        assert worst > 1000 * sound and worst > 20 * SCALED_TOL
+        assert ok is (limits == "as_they_are")
+        assert compared["tokens_on_alternate_routing"]["value"] \
+            <= serve.ALT_TOKENS_MAX
+        if limits == "drawn_down_to_the_widths":
+            # what tells the control and a fault from a flip: MANY tokens
+            assert compared["tokens_over_limit_own_routing"]["value"] \
+                > serve.ALT_TOKENS_MAX
 
-    _, short_window, _ = serve.compare_logprobs(
-        ref, spec._replace(sliding_window=31), state, prompts, replies)
-    assert short_window > 1000 * control and short_window > 5e-3
 
-    f = spec.intermediate_size
-    dropped = dict(state)
-    for layer in range(spec.num_hidden_layers):
-        key = f"llama.layers.{layer}.mlp.shared_expert.down_proj.weight"
-        dropped[key] = state[key].at[f:].set(0.0)     # expert 1 of 2 gone
-    _, no_expert, _ = serve.compare_logprobs(ref, spec, dropped, prompts,
-                                             replies)
-    assert no_expert > 1000 * control and no_expert > 5e-3
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_controls_fp8_is_the_cast_it_stands_for(dtype):
+    """``planted.fp8_e4m3`` rounds by arithmetic, because the TPU compiler
+    drops a convert to a narrower float and back: it has to equal
+    ``ml_dtypes``' cast to float8_e4m3fn under the same scale, ties and
+    the subnormal range included, and move a weight by ~2.7% rms."""
+    import jax
+    import jax.numpy as jnp
+    import ml_dtypes
+
+    rng = np.random.RandomState(7)
+    for scale in (2e-2, 1e-3, 1.0, 37.0):
+        w = jnp.asarray(rng.standard_normal((48, 96)) * scale, dtype)
+        w = w.at[0, :8].set(jnp.asarray(
+            [136, 42, -272, 348, 1e-5, -3e-4, 0, 0.5], dtype) * scale / 90)
+        wide = np.asarray(w.astype(jnp.float32))
+        s = np.float32(2.0) ** np.frexp(np.abs(wide).max()
+                                        / np.float32(448))[1]
+        want = (wide / s).astype(ml_dtypes.float8_e4m3fn).astype(
+            np.float32) * s
+        got = np.asarray(jax.jit(planted.fp8_e4m3)(w).astype(jnp.float32))
+        assert got.dtype == np.float32 and np.array_equal(got, want)
+        moved = np.sqrt(((got - wide) ** 2).mean() / (wide ** 2).mean())
+        assert 0.02 < moved < 0.035
+
+
+def test_a_held_swap_passes_at_a_near_tie_and_fails_away_from_one(
+        cell, served, monkeypatch):
+    """The reference itself as a stand-in engine that routed ONE position
+    the other way: the last answered token of a prompt, read with one
+    held swap forced at its own position. Where that swap's logit gap is
+    under ``ROUTING_TIE_GAP`` the comparison finds it among the
+    alternatives and passes with one token on an alternate routing; the
+    same kind of swap at a gap over the constant is a wrong routing, and
+    fails. (The constant is set between the two gaps found here; the
+    limit is drawn down to the widths, or no flip would be over it.)"""
+    _, ref, spec, model = cell
+    prompts, replies, _ = served
+    state = build.plain_state(model)
+    monkeypatch.setattr(serve, "LOGPROB_TOL", SCALED_TOL)
+
+    def stand_in(i, swap):
+        """Prompt ``i``'s answer as an engine would give it that took
+        ``swap`` at the last answered token's position."""
+        toks = replies[i]["token_ids"]
+        ids = prompts[i] + toks[:-1]
+        _, chosen, _ = ref.forward_hidden(spec, state, ids)
+        told = ref.swapped(np.asarray(chosen[swap["layer"], len(ids) - 1]),
+                           swap)
+        lp = np.asarray(ref.forward_logprobs(
+            spec, state, ids, last=len(toks),
+            forced={(swap["layer"], len(ids) - 1): told}))
+        out = list(replies)
+        out[i] = {"token_ids": toks, "logprobs": [
+            float(v) for v in lp[np.arange(len(toks)), toks]]}
+        return out
+
+    def flip_size(i, swap):
+        was = np.asarray(replies[i]["logprobs"])
+        return float(np.abs(np.asarray(stand_in(i, swap)[i]["logprobs"])
+                            - was).max())
+
+    # a prompt whose last position has two held swaps that each move its
+    # token by more than the limit: the nearer is the near-tie
+    for i, prompt in enumerate(prompts):
+        ids = prompt + replies[i]["token_ids"][:-1]
+        _, chosen, logits = ref.forward_hidden(spec, state, ids)
+        swaps = [s for s in ref.near_tie_swaps(
+            spec, chosen[:, -1], logits[:, -1], tie_gap=np.inf)
+            if flip_size(i, s) > 10 * SCALED_TOL][:2]
+        if len(swaps) == 2 and swaps[0]["gap"] < swaps[1]["gap"]:
+            break
+    else:
+        pytest.fail("no prompt ends on two held swaps that are seen")
+    near, far = swaps
+    monkeypatch.setattr(ref, "ROUTING_TIE_GAP",
+                        (near["gap"] + far["gap"]) / 2.0)
+    # what is on offer there: ONE held swap each, nearest tie first, few
+    offered = ref.near_tie_alternatives(spec, state, ids, len(ids) - 1)
+    assert 1 <= len(offered) <= ref.ALTERNATIVES_MAX <= 3
+    assert all(len(a["swaps"]) == 1 == len(a["forced"]) for a in offered)
+    gaps = [a["swaps"][0]["gap"] for a in offered]
+    assert gaps == sorted(gaps) and offered[0]["swaps"] == [near]
+
+    ok, compared, rows = serve.compare_logprobs(ref, spec, state, prompts,
+                                                stand_in(i, near))
+    assert ok and compared["tokens_on_alternate_routing"]["value"] == 1
+    assert compared["logprob_err_nats"]["value"] < 5e-6
+    assert compared["logprob_err_nats_own_routing"]["value"] \
+        == pytest.approx(flip_size(i, near), abs=1e-6)
+    (token,) = rows[i]["alternate_routing"]
+    assert token["token"] == serve.CHECK_TOKENS - 1
+    assert token["tried"][-1]["swaps"] == [near] and token["err"] < 5e-6
+
+    ok, compared, rows = serve.compare_logprobs(ref, spec, state, prompts,
+                                                stand_in(i, far))
+    assert not ok and compared["tokens_on_alternate_routing"]["value"] == 0
+    assert compared["logprob_err_nats"]["value"] \
+        == pytest.approx(flip_size(i, far), abs=1e-6)
+    assert compared["prompts_failed"]["value"] == 1
+    assert all(far not in t["swaps"]
+               for t in rows[i]["alternate_routing"][0]["tried"])
 
 
 def test_flops_and_bytes_arithmetic():

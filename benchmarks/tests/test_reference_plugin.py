@@ -16,10 +16,14 @@ VOCAB = 50
 PROMPT_LENGTHS = (5, 9, 12, 17, 23, 31)
 
 
-def engine_and_stub(seed=0):
+def engine_and_stub(seed=0, alternatives=None):
     """Six prompts with an "engine's" answers, and a stub reference whose
     logprobs at each position are a seeded function of the token before
-    it (so it really is teacher-forced on the sequence it is given)."""
+    it (so it really is teacher-forced on the sequence it is given).
+    ``alternatives``: the stub has a router, and ``{(prompt, token): [d0,
+    d1 ...]}`` are the alternate routings it offers at that answered
+    token's own position: routing ``n`` moves the logprobs read there by
+    ``dn``."""
     table = np.log(np.random.RandomState(seed).dirichlet(
         np.ones(VOCAB), size=VOCAB)).astype(np.float32)
     rng = np.random.RandomState(seed + 1)
@@ -33,24 +37,49 @@ def engine_and_stub(seed=0):
             for j in range(len(toks))]})
     calls = []
 
-    def forward_logprobs(spec, state, ids, last):
-        calls.append((len(ids), last))
+    def forward_logprobs(spec, state, ids, last, forced=None):
+        calls.append((len(ids), last) if forced is None
+                     else (len(ids), last, forced))
         assert spec == "the spec" and state == {"w": 1}
-        return table[np.asarray(ids[-last:])]
+        out = table[np.asarray(ids[-last:])].copy()
+        for (layer, position), told in (forced or {}).items():
+            moves = offered[(len(ids), position)]
+            out[position - (len(ids) - last)] += moves[told[0]]
+        return out
 
     stub = types.SimpleNamespace(forward_logprobs=forward_logprobs)
+    if alternatives is not None:
+        offered = {(n + serve.CHECK_TOKENS - 1, n - 1 + j): moves
+                   for (n, j), moves in alternatives.items()}
+
+        def near_tie_alternatives(spec, state, ids, position):
+            return [{"forced": {(0, position): [k]},
+                     "swaps": [{"layer": 0, "out": 1, "in": 2, "gap": 0.01}]}
+                    for k in range(len(offered.get((len(ids), position),
+                                                   ())))]
+
+        stub.near_tie_alternatives = near_tie_alternatives
     return stub, prompts, answers, calls
 
 
 def compare(stub, prompts, answers):
-    return serve.compare_logprobs(stub, "the spec", {"w": 1}, prompts,
-                                  answers)
+    """(correct, the worst error after the rule, rows, ``compared``)."""
+    ok, compared, rows = serve.compare_logprobs(stub, "the spec", {"w": 1},
+                                                prompts, answers)
+    assert list(compared)[0] == "logprob_err_nats"
+    assert list(compared)[-1] == "prompts_failed"
+    assert compared["prompts_failed"] == {
+        "value": sum(not r["ok"] for r in rows), "limit": 0}
+    return ok, compared["logprob_err_nats"]["value"], rows, compared
 
 
 def test_comparison_passes_on_the_stubs_own_logprobs():
     stub, prompts, answers, calls = engine_and_stub()
-    ok, worst, rows = compare(stub, prompts, answers)
+    ok, worst, rows, compared = compare(stub, prompts, answers)
     assert ok and worst < 1e-6
+    # a reference without a router: the two numbers it always had
+    assert set(compared) == {"logprob_err_nats", "prompts_failed"}
+    assert compared["logprob_err_nats"]["limit"] == serve.LOGPROB_TOL
     # four prompts spread over the six, each with all but the last token
     assert [r["prompt_tokens"] for r in rows] == [5, 12, 17, 31]
     assert calls == [(n + serve.CHECK_TOKENS - 1, serve.CHECK_TOKENS)
@@ -69,7 +98,7 @@ def test_comparison_fails(fault):
                                              ans["logprobs"][:-1])
     else:
         ans["logprobs"][0] = float("nan")
-    ok, worst, rows = compare(stub, prompts, answers)
+    ok, worst, rows, _ = compare(stub, prompts, answers)
     assert not ok
     assert [r["ok"] for r in rows] == [True, True, False, True]
     if fault == "one_logprob_off_by_0.2":
@@ -83,8 +112,71 @@ def test_comparison_fails(fault):
 
 def test_fewer_than_five_prompts_are_all_compared():
     stub, prompts, answers, _ = engine_and_stub()
-    ok, _, rows = compare(stub, prompts[:3], answers[:3])
+    ok, _, rows, _ = compare(stub, prompts[:3], answers[:3])
     assert ok and len(rows) == 3
+
+
+# ---- a reference with a router: set-valued at its own near-ties ------------
+
+#: case -> ({(prompt length, token): how far the engine is off there},
+#: {(prompt length, token): the moves of the alternate routings offered},
+#: correct?, tokens standing on an alternate routing, passes of the
+#: reference beyond the four first ones, the worst error after the rule)
+NEAR_TIES = {
+    "stands_on_the_second_alternative": (
+        {(17, 5): 0.25}, {(17, 5): [0.6, 0.24, 0.25]}, True, 1, 2, 0.01),
+    "no_alternative_at_its_position": (
+        {(17, 5): 0.25}, {(17, 4): [0.25]}, False, 0, 0, 0.25),
+    "matches_none_of_them": (
+        {(17, 5): 0.25}, {(17, 5): [0.6, -0.25, 0.05]}, False, 0, 3, 0.25),
+    "over_the_flip_cap_is_not_read_again": (
+        {(17, 5): 0.6}, {(17, 5): [0.6]}, False, 0, 0, 0.6),
+    "two_tokens_may_stand_so": (
+        {(5, 0): 0.2, (31, 7): -0.3}, {(5, 0): [0.2], (31, 7): [-0.3]},
+        True, 2, 2, 0.0),
+    "with_a_third_token_over_the_limit_none_is_read_again": (
+        {(5, 0): 0.2, (12, 3): 0.2, (31, 7): -0.3},
+        {(5, 0): [0.2], (12, 3): [0.2], (31, 7): [-0.3]}, False, 0, 0, 0.3),
+    "nothing_is_read_again_once_the_run_has_failed": (
+        {(5, 0): 0.2, (31, 7): -0.3}, {(31, 7): [-0.3]}, False, 0, 0, 0.3),
+    "a_token_within_the_limit_is_never_read_again": (
+        {(17, 5): 0.14}, {(17, 5): [0.0]}, True, 0, 0, 0.14),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NEAR_TIES))
+def test_routed_reference_reads_both_sides_of_its_own_near_tie(case):
+    off, offered, correct, standing, passes, left = NEAR_TIES[case]
+    stub, prompts, answers, calls = engine_and_stub(seed=4,
+                                                    alternatives=offered)
+    for (n, j), delta in off.items():
+        answers[PROMPT_LENGTHS.index(n)]["logprobs"][j] += delta
+    ok, worst, rows, compared = compare(stub, prompts, answers)
+    assert ok is correct
+    assert list(compared) == ["logprob_err_nats",
+                              "logprob_err_nats_own_routing",
+                              "tokens_over_limit_own_routing",
+                              "tokens_on_alternate_routing",
+                              "prompts_failed"]
+    assert compared["tokens_over_limit_own_routing"] == {
+        "value": sum(abs(d) > serve.LOGPROB_TOL for d in off.values()),
+        "limit": serve.ALT_TOKENS_MAX}
+    assert compared["tokens_on_alternate_routing"] == {
+        "value": standing, "limit": serve.ALT_TOKENS_MAX}
+    own = compared["logprob_err_nats_own_routing"]
+    assert own["limit"] == serve.ROUTING_FLIP_CAP
+    assert own["value"] == pytest.approx(max(abs(d) for d in off.values()),
+                                         abs=1e-5)
+    assert worst == pytest.approx(left, abs=1e-5)
+    assert len(calls) - 4 == passes
+    read_again = [t for r in rows for t in r["alternate_routing"]]
+    assert sum(t["err"] is not None for t in read_again) == standing
+    for t in read_again:
+        if t["err"] is not None:
+            assert t["tried"][-1]["swaps"][0] == {
+                "layer": 0, "out": 1, "in": 2, "gap": 0.01}
+            assert abs(t["tried"][-1]["logprob"]
+                       - t["engine_logprob"]) == pytest.approx(t["err"])
 
 
 # ---- readers and the reference module's arithmetic ------------------------

@@ -6,6 +6,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from benchmarks.lib import common
 
 RUN = [sys.executable, os.path.join(common.BENCH, "run.py")]
@@ -37,10 +39,31 @@ def test_directory_without_the_program_no_result(tmp_path):
     assert proc.returncode != 0 and not proc.stdout.strip()
 
 
-def test_rehearsal_runs_end_to_end_and_cannot_pass():
-    proc = subprocess.run(RUN + ["--workload", "olmo2_1b_pretrain_1chip",
+#: cell -> (--trace, the names under ``compared``, in the line's order:
+#: a reference with a router also reports how its near-tie rule stood)
+REHEARSED = {
+    "olmo2_1b_pretrain_1chip": (1, ["first_loss_abs_err"]),
+    "mistral7b_chat_steady": (0, ["logprob_err_nats", "prompts_failed"]),
+    "mistral7b_docs_batch": (0, ["logprob_err_nats", "prompts_failed"]),
+    "commandaplus_rag_batch": (0, ["logprob_err_nats",
+                                   "logprob_err_nats_own_routing",
+                                   "tokens_over_limit_own_routing",
+                                   "tokens_on_alternate_routing",
+                                   "prompts_failed"]),
+}
+
+
+def test_every_cell_is_rehearsed():
+    manifest = common.load_json(os.path.join(common.ROOT, "BENCHMARK.json"))
+    assert {w["name"] for w in manifest["workloads"]} == set(REHEARSED)
+
+
+@pytest.mark.parametrize("workload", sorted(REHEARSED))
+def test_rehearsal_runs_end_to_end_and_cannot_pass(workload):
+    trace, names = REHEARSED[workload]
+    proc = subprocess.run(RUN + ["--workload", workload,
                                  "--seed", "0", "--seconds", "2",
-                                 "--trace", "1", "--rehearse"],
+                                 "--trace", str(trace), "--rehearse"],
                           cwd=common.ROOT, env=ENV, capture_output=True,
                           text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-800:]
@@ -55,7 +78,22 @@ def test_rehearsal_runs_end_to_end_and_cannot_pass():
     assert note["would_be_correct"] is True
     assert note["compiles_in_window"] == 0
     assert list(last)[-1] == "compared"
-    err = last["compared"]["first_loss_abs_err"]
-    assert err["limit"] == 0.01 and 0.0 <= err["value"] < 1e-3
-    assert proc.stderr.splitlines()[-1] == (
-        f"compared first_loss_abs_err: {err['value']} (limit 0.01)")
+    assert list(last["compared"]) == names
+    # each number compared beside its limit: the last lines of stderr too
+    assert proc.stderr.splitlines()[-len(names):] == [
+        f"compared {name}: {pair['value']} (limit {pair['limit']})"
+        for name, pair in last["compared"].items()]
+    if workload == "olmo2_1b_pretrain_1chip":
+        err = last["compared"]["first_loss_abs_err"]
+        assert err["limit"] == 0.01 and 0.0 <= err["value"] < 1e-3
+    else:
+        err = last["compared"]["logprob_err_nats"]
+        assert err["limit"] == 0.15 and 0.0 <= err["value"] < 1e-3
+        assert last["compared"]["prompts_failed"] == {"value": 0, "limit": 0}
+    if "tokens_on_alternate_routing" in names:
+        # float32 on both sides: no token needs the reference's near-ties
+        assert last["compared"]["tokens_on_alternate_routing"]["value"] == 0
+        assert last["compared"]["tokens_over_limit_own_routing"] == {
+            "value": 0, "limit": 2}
+        assert (last["compared"]["logprob_err_nats_own_routing"]["value"]
+                == err["value"])
