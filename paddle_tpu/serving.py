@@ -2449,22 +2449,29 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 return s
         return -1
 
-    #: a prompt longer than twice this pads to the next multiple of it
-    _BUCKET_STEP = 1024
+    #: a prompt longer than this pads to half of its octave, in steps of
+    #: at most this many tokens
+    _HALF_OCTAVES_ABOVE = 1024
 
     def _bucket(self, n: int) -> int:
-        """Prompt-length bucket, page-aligned: the next power of two up to
-        ``2 * _BUCKET_STEP``, beyond that the next multiple of
-        ``_BUCKET_STEP``. A padded token costs what a real one costs, and
-        between two powers of two a long prompt's cost would hang on
-        which side of the lower one its length fell (4100 tokens in the
-        program of 8192: PERF.md, PR 35). The prefill programs are O(log)
-        short ones and ``max_len / _BUCKET_STEP`` long ones, each built
-        when a prompt first needs it."""
-        step = -(-self._BUCKET_STEP // self.page_size) * self.page_size
-        if n > 2 * step:
+        """Prompt-length bucket, page-aligned and capped at ``max_len``:
+        up to ``_HALF_OCTAVES_ABOVE`` (T) tokens the next power of two;
+        beyond it, for the power of two ``octave < n <= 2 * octave``, the
+        next multiple of ``min(octave // 2, T)``: 1536, 2048, then steps
+        of 1024. A padded token costs what a real one costs, and a prompt
+        over T pads under a third (the next power of two: up to half).
+        Finer steps pad less but each bucket is one more prefill and
+        scatter program in set-up: quarter octaves cost the docs cell 8%
+        of ``setup_s`` (PERF.md, PR 38). The programs are O(log) short
+        ones, 1536, 2048 and ``max_len / T`` long ones, each built when a
+        prompt first needs it."""
+        page = self.page_size
+        limit = self._HALF_OCTAVES_ABOVE
+        if n > limit:
+            octave = 1 << ((n - 1).bit_length() - 1)
+            step = -(-min(octave // 2, limit) // page) * page
             return min(-(-n // step) * step, self.max_len)
-        b = self.page_size
+        b = page
         while b < n:
             b *= 2
         return min(b, self.max_len)

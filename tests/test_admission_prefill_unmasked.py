@@ -160,13 +160,13 @@ def interpreted_flash(monkeypatch):
     monkeypatch.setattr(generation, "cached_attention", interpreted)
 
 
-def _wide_head_llama():
+def _wide_head_llama(**config):
     """The smallest trunk the splash gate takes: head width 128."""
     paddle.seed(5)
     return LlamaForCausalLM(LlamaConfig.tiny(
         num_hidden_layers=2, hidden_size=256, num_attention_heads=2,
         num_key_value_heads=1, intermediate_size=256,
-        use_flash_attention=True))
+        use_flash_attention=True, **config))
 
 
 @pytest.mark.parametrize("S0", [70, 100, 127])
@@ -184,6 +184,24 @@ def test_padded_admission_on_the_flash_kernel(interpreted_flash, S0):
     # S0 is no multiple of 128: the unpadded prefill is the dense einsum
     exact = _unpadded(model, ids, 256)
     _assert_same_prefill((last, caches), exact, ("k", "v"), S0, tol=2e-4)
+
+
+def test_a_half_octave_bucket_on_the_flash_kernel(interpreted_flash,
+                                                  monkeypatch):
+    """A bucket that is a multiple of 128 and no power of two (the
+    threshold lowered to 512: 600 tokens pad to 768, not 1024) still runs
+    the splash kernel and gives the unpadded prefill."""
+    monkeypatch.setattr(ContinuousBatchEngine, "_HALF_OCTAVES_ABOVE", 512)
+    model = _wide_head_llama(max_position_embeddings=768)
+    eng = ContinuousBatchEngine(model, max_batch=2, max_len=768,
+                                page_size=16)
+    ids = _prompt(model, 600)
+    before = _attention_counts()
+    last, caches, _, bucket = eng._bucketed_prefill(_Request(-1, ids, 0))
+    assert bucket == 768
+    assert _attention_delta(before) == {"flash": 1, "append": 0, "xla": 0}
+    _assert_same_prefill((last, caches), _unpadded(model, ids, 768),
+                         ("k", "v"), 600, tol=2e-4)
 
 
 def test_flash_admission_continues_as_solo_generate(interpreted_flash):

@@ -277,10 +277,11 @@ def _score_tensors(hlo):
     return sorted(found)
 
 
-@pytest.mark.parametrize("S", [128, 512, 1024, 2048])
+@pytest.mark.parametrize("S", [128, 512, 1024, 1536, 2048])
 def test_admission_prefill_attention_is_one_splash_call(chip, S):
-    """No pad mask, so every bucket the kernel tiles takes it: one splash
-    call, and no O(S^2) f32 score tensor anywhere in the program."""
+    """No pad mask, so every bucket the kernel tiles takes it, the half
+    octave 1536 among them: one splash call, and no O(S^2) f32 score
+    tensor anywhere in the program."""
     call, specs = _admission_attention(S, masked=False)
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
             for shape, dt in specs]

@@ -9,6 +9,7 @@ every tolerance below is 2e-4 nats or 2e-5 absolute, two orders above the
 ~5e-7 read and three below what any planted fault shows (0.002-0.04)."""
 import json
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -95,29 +96,55 @@ def test_engine_matches_reference_through_the_ring(served, n_prompt, n_new):
     assert np.abs(want - lps).max() < TOL
 
 
+# what ``_bucket`` reads of an engine: pages of 16, 8192 positions
+PAGED_8K = SimpleNamespace(
+    page_size=16, max_len=8192,
+    _HALF_OCTAVES_ABOVE=ContinuousBatchEngine._HALF_OCTAVES_ABOVE)
+
+
 @pytest.mark.parametrize("n,bucket", [
-    (1, 16), (100, 128), (1500, 2048), (2048, 2048),     # powers of two
-    (2049, 3072), (3072, 3072), (4054, 4096), (4245, 5120), (6144, 6144),
-    (7000, 7168), (8000, 8192)])
+    (1, 16), (100, 128), (1024, 1024),                   # powers of two
+    (1500, 1536), (2048, 2048), (2049, 3072), (3072, 3072), (4054, 4096),
+    (4245, 5120), (6144, 6144), (7000, 7168), (8000, 8192),
+    (1057, 1536), (1454, 1536), (1652, 2048), (2000, 2048),  # docs grid
+    (3529, 4096)])                                           # rag grid
 def test_long_prompts_pad_to_a_step_not_to_a_power_of_two(n, bucket):
-    """Up to 2048 tokens the next power of two (the dense cells' programs
-    are what they were), beyond it the next multiple of 1024: the cell's
-    prompts of 3072-6144 run in 4 programs and pad ~10%, not a third."""
-    from types import SimpleNamespace
-
-    engine = SimpleNamespace(page_size=16, max_len=8192,
-                             _BUCKET_STEP=ContinuousBatchEngine._BUCKET_STEP)
-    assert ContinuousBatchEngine._bucket(engine, n) == bucket
+    """Up to 1024 tokens the next power of two (the chat cell's short
+    programs are what they were), beyond it the next multiple of half the
+    prompt's octave, at most 1024: the docs cell's prompts of 1057-1454
+    tokens run in 1536, not in 2048; the rag cell's in its 3072-6144."""
+    assert ContinuousBatchEngine._bucket(PAGED_8K, n) == bucket
 
 
-@pytest.mark.parametrize("n_prompt,bucket", [(37, 48), (70, 80), (90, 96)])
+def test_every_bucket_pads_a_long_prompt_under_a_third():
+    """Every n of 1..8192 at pages of 16: the bucket holds n, is whole
+    pages, stays under max_len; above the threshold it is a multiple of
+    512 (splash tiles it) with under a third of pad; an octave has two
+    buckets, or one every 1024 tokens; over 2048 tokens the bucket is the
+    next multiple of 1024, as before this rule."""
+    limit = ContinuousBatchEngine._HALF_OCTAVES_ABOVE
+    octaves = {}
+    for n in range(1, 8193):
+        b = ContinuousBatchEngine._bucket(PAGED_8K, n)
+        assert n <= b <= 8192 and b % 16 == 0
+        if n > limit:
+            assert b % 512 == 0 and (b - n) / b < 1 / 3
+            octaves.setdefault(1 << ((n - 1).bit_length() - 1), set()).add(b)
+        if n > 2 * limit:
+            assert b == -(-n // limit) * limit
+    assert {o: len(bs) for o, bs in octaves.items()} == {
+        1024: 2, 2048: 2, 4096: 4}
+
+
+@pytest.mark.parametrize("n_prompt,bucket", [(37, 48), (70, 80), (90, 96),
+                                             (100, 112)])
 def test_a_stepped_bucket_scatters_into_the_ring(served, monkeypatch,
                                                  n_prompt, bucket):
-    """A bucket that is no power of two (the step shrunk to the rehearsal's
-    sizes): 6, 10 and 12 pages of prompt into rings of 5, and the decode
-    that follows, against the reference."""
+    """A bucket that is no power of two (the threshold shrunk to the
+    rehearsal's sizes): 6, 10, 12 and 14 pages of prompt into rings of 5,
+    and the decode that follows, against the reference."""
     cfg, model, ref, spec, state = served
-    monkeypatch.setattr(ContinuousBatchEngine, "_BUCKET_STEP", 16)
+    monkeypatch.setattr(ContinuousBatchEngine, "_HALF_OCTAVES_ABOVE", 16)
     ids = np.random.RandomState(n_prompt).randint(1, cfg["vocab_size"],
                                                   n_prompt)
     engine, [(prompt, toks, lps)] = run_engine(model, cfg, [(ids, 12)])
