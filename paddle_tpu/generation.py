@@ -12,8 +12,8 @@ dense [B, max_len, kv_heads, head_dim] or paged (block tables) — updated
 with dynamic_update_slice/scatter, and the whole decode step (embed →
 layers → lm head → cache update) is ONE jitted computation with the cache
 buffers donated, so each generated token is a single device dispatch and
-the buffers are updated in place. The paged layout matches JAX's bundled
-Pallas paged_attention kernel, which is used on TPU (jnp gather reference
+the buffers are updated in place. Paged decode attention runs the Pallas
+kernel of ``ops/pallas/paged_decode.py`` on TPU (jnp gather reference
 elsewhere).
 """
 from __future__ import annotations
@@ -30,6 +30,7 @@ from .tensor_class import Tensor, unwrap, wrap
 from .ops.registry import apply
 from .ops.pallas import backend as _pallas_backend
 from .ops.pallas import kv_page_write as _kv_page_write
+from .ops.pallas import paged_decode as _paged_decode
 from .autograd import tape as _tape
 from .framework import random as _random
 from .nn.layer import functional_weights as _functional_weights
@@ -273,21 +274,16 @@ def _paged_chunk_attention(q, k_pages, v_pages, lengths, page_indices,
 
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
-                           pages_per_compute_block=None, window=None,
-                           softcap=None, ring=False):
-    """Decode attention over a paged cache: JAX's bundled Pallas kernel on
-    TPU, a jnp gather reference (identical semantics) elsewhere.
+                           window=None, softcap=None, ring=False):
+    """Decode attention over a paged cache: the Pallas kernel of
+    ``ops/pallas/paged_decode.py`` on TPU, a jnp gather reference
+    (identical semantics) elsewhere.
 
     ``window`` (Mistral sliding-window serving): only the last ``window``
-    positions attend. The bundled Pallas kernel has no lower-bound
-    masking, so windowed rows take the O(window) page-gather path
-    (_paged_window_attention: only the <= ceil(window/page_size)+1 pages
-    the band intersects are read — HBM cost scales with the window, not
-    the cache capacity).
-
-    ``pages_per_compute_block`` defaults to the largest divisor of
-    pages-per-sequence <= 8: bigger blocks amortize the kernel's grid
-    overhead across more of the KV stream (HBM-bandwidth-bound op).
+    positions attend. The kernel has no lower-bound masking, so windowed
+    rows take the O(window) page-gather path (_paged_window_attention:
+    only the <= ceil(window/page_size)+1 pages the band intersects are
+    read — HBM cost scales with the window, not the cache capacity).
 
     ``ring``: the pool holds each row's last ``page_indices.shape[1]``
     pages as a ring (``_paged_ring_attention``)."""
@@ -310,33 +306,16 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
         # cache capacity) — fall through to the fused Pallas kernel,
         # e.g. Mistral-7B's 4096 window served at max_len <= 4096
     if softcap is not None:
-        # the bundled Pallas kernel computes uncapped scores; the exact
-        # gather reference (O(cache) reads) keeps softcapped models
-        # (Gemma2) servable through the paged engine
+        # the kernel computes uncapped scores; the exact gather reference
+        # (O(cache) reads) keeps softcapped models (Gemma2) servable
+        # through the paged engine
         _pallas_backend.took("paged_attention", _pallas_backend.XLA,
                              "attention softcap")
         return _paged_attention_ref(q, k_pages, v_pages, lengths,
                                     page_indices, softcap=softcap)
-    # the bundled kernel has no interpret-mode entry: TPU only
-    if _pallas_backend.gate("paged_attention", interpret=False):
-        # the package re-exports the FUNCTION under the kernel module's
-        # old name, so ``import paged_attention as pa; pa.paged_attention``
-        # is an AttributeError on this jax
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention)
-
-        if pages_per_compute_block is None:
-            pages_per_seq = page_indices.shape[1]
-            pages_per_compute_block = next(
-                b for b in (8, 4, 2, 1) if pages_per_seq % b == 0)
-        # the bundled kernel applies NO softmax scale (the maxtext
-        # convention, like splash): q goes in pre-scaled. Unscaled, the
-        # chip's decode disagreed with every reference from the second
-        # token on — a fault only a chip run could show.
-        scale = jnp.asarray(1.0 / math.sqrt(q.shape[-1]), q.dtype)
-        return paged_attention(
-            q * scale, k_pages, v_pages, lengths, page_indices,
-            pages_per_compute_block=pages_per_compute_block)
+    if _paged_decode.supported(q, k_pages):
+        return _paged_decode.paged_decode_attention(
+            q, k_pages, v_pages, lengths, page_indices)
     return _paged_attention_ref(q, k_pages, v_pages, lengths, page_indices)
 
 

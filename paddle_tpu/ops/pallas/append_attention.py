@@ -6,7 +6,7 @@ speculative-decode verify chunk.
 Role anchor: the multi-token branch of the reference's
 block_multi_head_attention serving kernel family
 (paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu);
-the single-token case rides JAX's bundled paged_attention kernel and the
+the single-token case rides ``paged_decode`` and the
 pos=0 full prefill rides splash flash — this kernel covers the middle:
 0 < pos, S > 1.
 

@@ -5,8 +5,7 @@ Role anchor: the cache-write half of the reference's
 block_multi_head_attention serving kernel
 (paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu),
 which stores the step's K/V at ``block_tables[b, pos // block_size]``
-before it attends; the attention half rides JAX's bundled
-``paged_attention``.
+before it attends; the attention half is ``paged_decode``.
 
 Why a kernel: the pool ``[hk, n_pages, page_size, D]`` has to stay in
 the default layout from the decode program's parameter through

@@ -4,8 +4,8 @@ latent cache (DeepSeek multi-head latent attention, models/deepseek.py).
 Role anchor: the single-token decode branch of the reference's
 block_multi_head_attention serving kernel family
 (paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu) for
-the MLA cache layout this build adds; the GQA layout rides JAX's bundled
-paged_attention kernel instead.
+the MLA cache layout this build adds; the GQA layout rides
+``paged_decode`` instead.
 
 Why a kernel: the absorbed decode step reads the latent buffer TWICE in
 the XLA einsum formulation — once for scores (``q_lat · c_kv``) and once

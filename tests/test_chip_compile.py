@@ -417,14 +417,15 @@ def test_constraint_inside_a_trace_emits_a_sharding_constraint():
     assert spec[0] is PartitionSpec.UNCONSTRAINED
 
 
-def test_bundled_paged_kernel_gets_a_prescaled_query(monkeypatch):
-    """jax's paged_attention kernel applies no softmax scale; the
-    reference path divides the scores by sqrt(D). Found on the chip: the
-    first decoded token onwards disagreed with every reference."""
-    import jax.experimental.pallas.ops.tpu.paged_attention as bundled
-
-    from paddle_tpu.generation import (_paged_attention_ref,
-                                       paged_decode_attention)
+def test_bundled_paged_kernel_gets_a_prescaled_query():
+    """The softmax scale of the paged decode kernel. JAX's bundled kernel,
+    which ran here before ``ops/pallas/paged_decode``, applied none and
+    took q pre-scaled; unscaled, the chip's decode disagreed with every
+    reference from the first decoded token on. The kernel now applies
+    1/sqrt(D) itself, in float32 to the scores: q goes in as it is, and
+    the output is the reference's, not that of unscaled scores."""
+    from paddle_tpu.generation import _paged_attention_ref
+    from paddle_tpu.ops.pallas import paged_decode
 
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(2, 4, 128), F32)
@@ -432,18 +433,51 @@ def test_bundled_paged_kernel_gets_a_prescaled_query(monkeypatch):
     vp = jnp.asarray(rng.randn(2, 8, 16, 128), F32)
     lengths = jnp.asarray([40, 17], I32)
     pages = jnp.arange(8, dtype=I32).reshape(2, 4)
+    got = np.asarray(paged_decode.paged_decode_attention(
+        q, kp, vp, lengths, pages, interpret=True))
+    want = np.asarray(_paged_attention_ref(q, kp, vp, lengths, pages))
+    unscaled = np.asarray(_paged_attention_ref(q * np.sqrt(128.0), kp, vp,
+                                               lengths, pages))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    assert np.abs(got - unscaled).max() > 0.1
 
-    def unscaled_kernel(q_, k_, v_, lengths_, pages_, **kw):
-        # what the bundled kernel computes: softmax(q.k) with NO scale
-        return _paged_attention_ref(q_ * np.sqrt(128.0), k_, v_, lengths_,
-                                    pages_)
 
-    monkeypatch.setattr(bundled, "paged_attention", unscaled_kernel)
-    with backend.lowering_target("tpu"):
-        got = paged_decode_attention(q, kp, vp, lengths, pages)
-    want = _paged_attention_ref(q, kp, vp, lengths, pages)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-5)
+# the paged decode attention of three serving cells (benchmarks/configs):
+# rows, KV heads, query heads a KV head, pages of 16 a row; and the pages
+# a block takes by the kernel's plan (K + V of every head within 2 MiB)
+CELL_DECODE = {
+    "olmohybrid_chat_batch": (32, 30, 1, 128, 8),
+    "mistral7b_docs_batch": (16, 8, 4, 128, 32),
+    "commandaplus_rag_batch": (32, 8, 16, 512, 32),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DECODE))
+def test_paged_decode_kernel_at_the_cells_widths(chip, cell):
+    """``paged_cached_attention`` at S == 1 with both pools donated, at a
+    cell's own decode shape: the kernel compiles within VMEM, is ONE
+    ``paged_attention`` custom call, and no copy of a pool stands around
+    it or the page writes."""
+    from paddle_tpu.generation import paged_cached_attention
+    from paddle_tpu.ops.pallas import paged_decode
+
+    rows, hk, g, pps, block = CELL_DECODE[cell]
+    assert paged_decode.pages_per_block(hk, PAGE, D, 2, pps) == block
+    pool = (hk, rows * pps, PAGE, D)
+    specs = [((rows, 1, hk * g, D), BF16), ((rows, 1, hk, D), BF16),
+             ((rows, 1, hk, D), BF16), ((pps * PAGE, D), F32),
+             ((pps * PAGE, D), F32), (pool, BF16), (pool, BF16),
+             ((rows, pps), I32), ((rows,), I32)]
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+            for shape, dt in specs]
+    hlo = _lower_for_chip(
+        lambda *a: paged_cached_attention(*a, PAGE), args, donate=(5, 6))
+    assert _pool_copies(hlo, pool) == []
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and "= " in line]
+    assert sum("%paged_attention" in c.split("=")[0] for c in calls) == 1
+    assert backend.paths() == {"kv_page_write": {backend.PALLAS: 2},
+                               "paged_attention": {backend.PALLAS: 1}}
 
 
 # the olmohybrid_chat_batch cell (benchmarks/configs/olmo-hybrid-7b-d16):
