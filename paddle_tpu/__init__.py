@@ -510,3 +510,9 @@ def __dir__():
 
 
 _finalize_schema()
+
+# every program build of the process, by stage, program and cause, from
+# here on (the weights' initializers included): docs/SERVING.md "Set-up"
+from .jit import build_events as _build_events  # noqa: E402
+
+_build_events.install()

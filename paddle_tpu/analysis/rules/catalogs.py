@@ -39,8 +39,9 @@ _DOCS = os.path.join("docs", "SERVING.md")
 
 # catalog rows look like: | `name` | kind | labels | meaning |
 _METRIC_ROW = re.compile(r"^\|\s*`([a-z0-9_]+)`\s*\|\s*([a-z]+)\s*\|\s*([^|]*)\|")
-# span rows look like: | `serving.request` | parent | meaning |
-_SPAN_ROW = re.compile(r"^\|\s*`([a-z0-9_.]+)`\s*\|")
+# span rows look like: | `serving.request` | parent | meaning | (or
+# `jit/trace`: a span name may hold a slash)
+_SPAN_ROW = re.compile(r"^\|\s*`([a-z0-9_./]+)`\s*\|")
 
 
 # ---- pure comparison cores --------------------------------------------------

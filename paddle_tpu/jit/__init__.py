@@ -395,39 +395,6 @@ def is_tracing() -> bool:
     return not jax.core.trace_ctx.is_top_level()
 
 
-# ---- flight-recorder compile events -----------------------------------------
-
-_COMPILE_EVENTS_INSTALLED = False
-
-
-def install_compile_events() -> bool:
-    """Hook ``jax.monitoring`` so every XLA backend compile lands in the
-    flight recorder as a ``jit.compile`` event (event name + duration) —
-    the black-box answer to "the engine stalled because a cold
-    prompt-length bucket compiled mid-traffic". Installed once per
-    process (FlightRecorder.enable() calls this); the listener is itself
-    guarded on the recorder flag, so a disabled recorder pays one
-    predicate per compile, not per dispatch. Raises ImportError on a jax
-    without ``monitoring`` — the caller treats that as "no compile
-    events", not a fault."""
-    global _COMPILE_EVENTS_INSTALLED
-    if _COMPILE_EVENTS_INSTALLED:
-        return True
-    from jax import monitoring as _monitoring
-
-    from ..observability import flightrecorder as _frec
-
-    def _on_event_duration(name: str, duration: float, **kw):
-        rec = _frec.RECORDER
-        if rec.enabled and name.endswith("backend_compile_duration"):
-            rec.record(_frec.EV_COMPILE, event=name,
-                       seconds=float(duration))
-
-    _monitoring.register_event_duration_secs_listener(_on_event_duration)
-    _COMPILE_EVENTS_INSTALLED = True
-    return True
-
-
 _SOT_CODE_LEVEL = 0
 _SOT_VERBOSITY = 0
 

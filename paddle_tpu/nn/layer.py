@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 import jax
 import numpy as np
 
+from ..jit import build_events as _build_events
 from ..tensor_class import Tensor, Parameter, wrap, unwrap
 from ..framework import dtype as _dtype_mod
 from .initializer_core import _resolve_initializer, ParamAttr
@@ -143,7 +144,8 @@ class Layer:
         dtype = _dtype_mod.convert_dtype(dtype) if dtype is not None else self._dtype
         attr = ParamAttr._to_attr(attr)
         init = _resolve_initializer(attr, default_initializer, is_bias)
-        arr = init(tuple(int(s) for s in shape), dtype)
+        with _build_events.param_init():
+            arr = init(tuple(int(s) for s in shape), dtype)
         p = Parameter(arr, trainable=attr.trainable if attr else True,
                       name=attr.name if attr else None)
         return p

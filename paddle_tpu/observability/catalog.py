@@ -392,3 +392,30 @@ DEVICE_MEM_PEAK = _R.gauge(
     "Peak device bytes (framework.device.memory_stats "
     "peak_bytes_in_use)",
     labels=())
+
+# ---- set-up: program builds and parameter initializers (jit/build_events) --
+
+JIT_BUILD_SECONDS = _R.counter(
+    "jit_build_seconds_total",
+    "Seconds JAX spent building programs, by stage (trace|lower|compile|"
+    "load: a load is a backend compile answered by the persistent "
+    "cache), program (decode_step|prefill|prefill_embeds|kv_scatter|"
+    "train_step|other) and cause (param_init|engine/admit|"
+    "engine/dispatch|engine/retire|none: what the building thread was "
+    "doing); a span nested in one of its own stage is counted once, and "
+    "one nested in another stage is taken out of that one",
+    labels=("stage", "program", "cause"))
+
+JIT_BUILDS = _R.counter(
+    "jit_builds_total",
+    "Program builds by stage, program and cause (the labels of "
+    "jit_build_seconds_total); compile + load is the programs a process "
+    "built, compile alone those the persistent cache did not answer",
+    labels=("stage", "program", "cause"))
+
+NN_PARAM_INIT_SECONDS = _R.counter(
+    "nn_param_init_seconds_total",
+    "Host seconds inside parameter initializers (Layer.create_parameter): "
+    "dispatch plus the builds under cause param_init; the device's work "
+    "is asynchronous and not in it",
+    labels=())

@@ -93,9 +93,10 @@ EV_HTTP_REQUEST = _register(
     "inbound POST on the serving front-end (method, path)")
 EV_COMPILE = _register(
     "jit.compile",
-    "one XLA backend compile (event, seconds) — recorded via the "
-    "jax.monitoring hook installed by paddle_tpu.jit when the recorder "
-    "enables; start = mono_ns - seconds")
+    "one program built by the backend (stage=compile|load: a load is "
+    "answered by the persistent cache; program, seconds) — recorded by "
+    "the build listener paddle_tpu.jit.build_events; start = mono_ns - "
+    "seconds")
 EV_COLLECTIVE_BEGIN = _register(
     "collective.begin",
     "host-side collective entered (op, multiprocess) — an unmatched "
@@ -301,19 +302,9 @@ class FlightRecorder:
 
     # ---- lifecycle -----------------------------------------------------
     def enable(self) -> "FlightRecorder":
-        """Turn recording on and install the jax compile-event hook (a
-        jax.monitoring listener owned by paddle_tpu.jit — idempotent,
-        and itself guarded on this flag)."""
+        """Turn recording on (``jit.compile`` events come from the build
+        listener that ``paddle_tpu`` installs at import)."""
         self.enabled = True
-        try:
-            from .. import jit as _jit
-
-            _jit.install_compile_events()
-        except Exception as e:
-            # recording must work without the compile hook (old jax):
-            # say what went missing instead of silently thinner rings
-            _logger().warning("flight recorder: jit compile events "
-                              "unavailable (%s: %s)", type(e).__name__, e)
         return self
 
     def disable(self) -> "FlightRecorder":

@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional
 
 import jax
 
+from ..jit import build_events as _build_events
 from . import catalog as _cat
 
 __all__ = ["PHASES", "PhaseClock", "StepProfiler", "get_profiler",
@@ -89,7 +90,9 @@ class PhaseClock:
     ``engine/step`` (a ``StepTraceAnnotation``) per step and one
     ``engine/<phase>`` per opened phase, so a device trace shows on its
     own clock what the host was doing in every gap. With no profiler
-    session an annotation is a flag test."""
+    session an annotation is a flag test. An open phase is also the
+    engine thread's build cause (``paddle_tpu.jit.build_events``): a
+    program built inside it is counted under ``engine/<phase>``."""
 
     __slots__ = ("t0", "_last", "phases", "_phase", "_span", "_step")
 
@@ -128,6 +131,7 @@ class PhaseClock:
         self._phase = phase
         self._span = _enter(jax.profiler.TraceAnnotation(
             SPAN_PREFIX + phase))
+        _build_events.set_cause(SPAN_PREFIX + phase)
 
     def close(self) -> None:
         """Close the open phase: its annotation ends and the time since
@@ -137,6 +141,7 @@ class PhaseClock:
             return
         self._phase = self._span = None
         span.__exit__(None, None, None)
+        _build_events.set_cause(None)
         self.lap(phase)
 
     def span(self, name: str):

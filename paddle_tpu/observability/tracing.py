@@ -109,6 +109,21 @@ SPAN_TRAIN_EPOCH = _register(
     "train.epoch",
     "one train epoch (hapi StepTimer callback); parents that epoch's "
     "train.step spans")
+SPAN_JIT_TRACE = _register(
+    "jit/trace",
+    "one program traced to a jaxpr (paddle_tpu.jit.build_events; attrs "
+    "program, cause, start_s/end_s: jax's own time.time() stamps, "
+    "seconds: what the counters took); child of the thread's current span")
+SPAN_JIT_LOWER = _register(
+    "jit/lower",
+    "one jaxpr lowered to a module (attrs as jit/trace)")
+SPAN_JIT_COMPILE = _register(
+    "jit/compile",
+    "one module compiled by the backend (attrs as jit/trace)")
+SPAN_JIT_LOAD = _register(
+    "jit/load",
+    "one compiled program loaded from the persistent compilation cache "
+    "(attrs as jit/trace)")
 
 
 # ---- ids / W3C trace context ------------------------------------------------

@@ -38,6 +38,7 @@ from .observability import sentinel as _sentinel
 from .observability import tracing as _tracing
 from .tensor_class import Tensor, unwrap
 from .framework import random as _random
+from .jit import build_events as _build_events
 from .generation import (_get_prefill_step, _get_select_decode,
                          _get_select_decode_rows, _get_spec_decode,
                          _memoized_step, traced_attention_impl)
@@ -2608,6 +2609,7 @@ class ContinuousBatchEngine(_RequestBookkeeping):
                 "where": "oom", "error": msg, "miss_ms": None,
                 "retry_after": self._retry_after_estimate()})
 
+    @_build_events.cause("engine/admit")
     def _admit(self):
         if self._poisoned and self._queue:
             raise RuntimeError(
@@ -3544,6 +3546,7 @@ class Seq2SeqBatchEngine(_RequestBookkeeping):
         return done
 
     # ---- admission -----------------------------------------------------
+    @_build_events.cause("engine/admit")
     def _admit(self):
         from .autograd import tape as _tape
         from .tensor_class import wrap

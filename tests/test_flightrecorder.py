@@ -663,7 +663,7 @@ def test_jit_compile_events_recorded():
     import jax.numpy as jnp
 
     rec = fr.get_recorder()
-    rec.enable()                  # installs the jax.monitoring listener
+    rec.enable()                  # paddle_tpu's build listener records
     rec.clear()
     # a constant nobody else bakes, so this HLO misses every compile
     # cache (in-memory and the persistent one conftest configures) and a
@@ -673,6 +673,10 @@ def test_jit_compile_events_recorded():
     compiles = rec.events(kind=fr.EV_COMPILE)
     assert compiles, "backend compile should land in the ring"
     assert all(c["seconds"] > 0 for c in compiles)
+    # a load from the persistent cache is told from a compile
+    assert all(c["stage"] in ("compile", "load") for c in compiles)
+    assert any(c["stage"] == "compile" and c["program"].startswith("jit(")
+               for c in compiles)
 
 
 # ---------------------------------------------------------------------------
