@@ -156,6 +156,14 @@ SERVING_GDN_STATE_UPDATES = _R.counter(
     "float32 state of one layer)",
     labels=("engine",))
 
+SERVING_GDN_PREFILL_CHUNKS = _R.counter(
+    "serving_gdn_prefill_chunks_total",
+    "Chunks of 64 tokens the chunked gated delta prefill kernel was handed "
+    "at admission, times linear-attention layers: kind=run the chunks "
+    "that hold a real prompt token (ceil(prompt / 64)), kind=skipped the "
+    "rest of the bucket, all pads, which the kernel does no math for",
+    labels=("engine", "kind"))
+
 SERVING_PREFILL_TOKENS = _R.counter(
     "serving_prefill_tokens_total",
     "Tokens through the admission prefill programs: kind=prompt the real "

@@ -1276,6 +1276,10 @@ class ContinuousBatchEngine(_RequestBookkeeping):
             _metrics.SERVING_DECODE_ROWS_OVER_WINDOW.labels(engine="decoder"))
         self._m_gdn_updates = _metrics.SERVING_GDN_STATE_UPDATES.labels(
             engine="decoder")
+        self._m_gdn_chunks = {
+            kind: _metrics.SERVING_GDN_PREFILL_CHUNKS.labels(engine="decoder",
+                                                             kind=kind)
+            for kind in ("run", "skipped")}
         self._m_moe_tokens = _metrics.SERVING_MOE_TOKENS.labels(
             engine="decoder")
         self._m_moe_pairs = _metrics.SERVING_MOE_HELD_PAIRS.labels(
@@ -2234,10 +2238,20 @@ class ContinuousBatchEngine(_RequestBookkeeping):
     def _count_prefill(self, n_tokens: int, bucket: int, impl: str):
         """One prefill program enqueued: the real tokens it computes, the
         bucket it pads them to, and the implementation its attention took
-        when the program was traced (``traced_attention_impl``)."""
+        when the program was traced (``traced_attention_impl``). On a
+        model with linear-attention layers, also the chunks the gated
+        delta prefill kernel runs and skips in each of them."""
         self._m_prefill_prompt.inc(n_tokens)
         self._m_prefill_bucket.inc(bucket)
         self._m_prefill_attention[impl].inc()
+        if self._state_layers:
+            from .ops.pallas.gated_delta import CHUNK
+
+            run = -(-n_tokens // CHUNK)
+            layers = len(self._state_layers)
+            self._m_gdn_chunks["run"].inc(run * layers)
+            self._m_gdn_chunks["skipped"].inc(
+                (-(-bucket // CHUNK) - run) * layers)
 
     def _dispatch_span(self):
         """Annotation-only ``engine/<phase>/prefill_dispatch`` around the

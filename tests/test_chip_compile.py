@@ -485,20 +485,42 @@ def test_paged_decode_kernel_at_the_cells_widths(chip, cell):
 GD_H, GD_DK, GD_DV, GD_B = 30, 96, 192, 32
 
 
-@pytest.mark.parametrize("bucket", [128, 1024])
-def test_gated_delta_prefill_compiles_for_v5e(chip, bucket):
-    """The chunked kernel at the cell's widths: whole-head blocks whose
-    trailing dims are not whole lanes (96, 192) still tile."""
-    from paddle_tpu.ops.pallas import gated_delta
-
+def _gated_delta_prefill_args(chip, bucket):
     specs = [((1, bucket, GD_H, GD_DK), F32), ((1, bucket, GD_H, GD_DK), F32),
              ((1, bucket, GD_H, GD_DV), F32), ((1, bucket, GD_H), F32),
              ((1, bucket, GD_H), F32), ((1, GD_H, GD_DV, GD_DK), F32),
              ((1,), I32)]
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in specs]
-    hlo = _lower_for_chip(gated_delta.gated_delta_prefill, args)
+    return [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in specs]
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_gated_delta_prefill_compiles_for_v5e(chip, bucket):
+    """The chunked kernel at the cell's widths: whole-head blocks whose
+    trailing dims are not whole lanes (96, 192) still tile, several heads
+    a grid step, in ONE Pallas call that asks for no VMEM limit of its
+    own: its blocks and scratch fit the compiler's default scoped VMEM
+    (which it enforces: the test below)."""
+    from paddle_tpu.ops.pallas import gated_delta
+
+    assert gated_delta.prefill_heads_per_block(GD_H, GD_DK, GD_DV) > 1
+    hlo = _lower_for_chip(gated_delta.gated_delta_prefill,
+                          _gated_delta_prefill_args(chip, bucket))
     assert "gated_delta_prefill" in hlo
+    assert "vmem_limit_bytes" not in hlo
     assert backend.paths() == {"gated_delta_prefill": {backend.PALLAS: 1}}
+
+
+def test_gated_delta_prefill_heads_past_the_budget_overflow_vmem(
+        chip, monkeypatch):
+    """What the block budget guards: 15 heads a grid step (twice the
+    budget's 6) run out of the default scoped VMEM in the compiler."""
+    from paddle_tpu.ops.pallas import gated_delta
+
+    monkeypatch.setattr(gated_delta, "prefill_heads_per_block",
+                        lambda *a, **kw: 15)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _lower_for_chip(gated_delta.gated_delta_prefill,
+                        _gated_delta_prefill_args(chip, 1024))
 
 
 def test_gated_delta_decode_updates_the_state_in_place(chip):

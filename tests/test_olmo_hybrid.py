@@ -74,6 +74,42 @@ def test_chunked_form_matches_the_recurrence(n, bucket, interpret):
 
 @pytest.mark.parametrize("interpret", [True, False],
                          ids=["kernel", "composite"])
+@pytest.mark.parametrize("heads", [3, 6])
+def test_rows_of_a_batch_stop_at_their_own_lengths(heads, interpret):
+    """Three rows of one 256 bucket, lengths 1, 64 and 200: each against
+    the recurrence over its own tokens, o exactly 0 from its length on
+    (whole chunks of pads are skipped), the state the one at its length.
+    At 6 heads the kernel takes several heads a grid step."""
+    lengths = (1, 64, 200)
+    rows = [random_inputs(256, H=heads, seed=10 + r) for r in range(3)]
+    q, k, v, g, beta, h0 = (np.concatenate(x) for x in zip(*rows))
+    o, S = gd.gated_delta_prefill(q, k, v, g, beta, h0,
+                                  jnp.asarray(lengths), interpret=interpret)
+    o, S = np.asarray(o), np.asarray(S)
+    assert np.isfinite(o).all()
+    for r, n in enumerate(lengths):
+        ro, rS = recurrence(q[r], k[r], v[r], g[r], beta[r], h0[r], n)
+        np.testing.assert_allclose(o[r, :n], ro, atol=KERNEL_TOL)
+        np.testing.assert_allclose(S[r], rS, atol=KERNEL_TOL)
+        assert (o[r, n:] == 0.0).all()
+
+
+@pytest.mark.parametrize("h,dk,dv,want", [(6, 16, 32, 6), (30, 96, 192, 10),
+                                          (3, 16, 32, 3)])
+def test_heads_per_block_divide_the_heads_within_the_budget(h, dk, dv, want):
+    """The blocks a grid step holds, double-buffered and padded to whole
+    lanes (96 -> 128), fit the budget: at the cell's widths 0.79 MB a
+    head, so 10 of its 30 (15 would not fit in 8 MiB)."""
+    hb = gd.prefill_heads_per_block(h, dk, dv)
+    assert hb == want and h % hb == 0
+    lanes = lambda d: -(-d // 128) * 128
+    per_head = 8 * (gd.CHUNK * (2 * lanes(dk) + 2 * lanes(dv))
+                    + 8 * lanes(gd.CHUNK) + 2 * dv * lanes(dk))
+    assert hb * per_head <= gd._PREFILL_BLOCK_BYTES
+
+
+@pytest.mark.parametrize("interpret", [True, False],
+                         ids=["kernel", "composite"])
 def test_negative_eigenvalues(interpret):
     """beta near 2: (I - beta k k^T) reflects, 1 - beta near -1. Over 200
     tokens an error that grows with each reflection would show."""
@@ -229,6 +265,44 @@ def test_the_gauge_and_counter(served):
                                                layer_type="linear_state")
     per_slot = 3 * (4 * 16 * 8 * 4 + 3 * (2 * 4 * 8 + 4 * 16) * 4)
     assert gauge == 2 * per_slot
+
+
+def test_the_prefill_chunks_counter(served):
+    """Admission counts the kernel's chunks per linear layer: a prompt of
+    100 in the 128 bucket runs 2 and skips none, one of 130 in the 256
+    bucket runs 3 and skips the chunk of pads after it (the rehearsal
+    holds 256 positions)."""
+    from paddle_tpu.observability import catalog
+
+    model, _, _, _ = served
+    eng = ContinuousBatchEngine(model, max_batch=2, max_len=256,
+                                page_size=8)
+    assert [eng._bucket(n) for n in (100, 130)] == [128, 256]
+    count = lambda kind: catalog.SERVING_GDN_PREFILL_CHUNKS.value(
+        engine="decoder", kind=kind)
+    before = count("run"), count("skipped")
+    for p in prompts((100, 130)):
+        eng.add_request(np.asarray(p), max_new_tokens=1)
+    eng.run_until_done()
+    assert (count("run") - before[0], count("skipped") - before[1]) == \
+        ((2 + 3) * 3, (0 + 1) * 3)
+
+
+def test_the_skipped_share_reader():
+    """The benchmark's reader of that counter: the window's skipped over
+    all, and nothing where the program has no such series."""
+    import importlib
+
+    reader = importlib.import_module(
+        "benchmarks.layer_metrics.gated_delta_prefill_skipped_share")
+    series = 'serving_gdn_prefill_chunks_total{engine="decoder",kind="%s"}'
+    ctx = lambda before, after: {"before": {"metrics": before},
+                                 "after": {"metrics": after}}
+    got = reader.read(ctx({series % "run": 10.0, series % "skipped": 2.0},
+                          {series % "run": 10.0 + 84,
+                           series % "skipped": 2.0 + 16}))
+    assert got == pytest.approx(16.0)
+    assert reader.read(ctx({}, {})) is None
 
 
 @pytest.mark.parametrize("feature", [
